@@ -15,19 +15,10 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".."))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
-import jax.numpy as jnp
 import optax
 
 from torchsnapshot_tpu import Snapshot, StateDict
-from torchsnapshot_tpu.models import (
-    LlamaConfig,
-    init_params,
-    shard_train_state,
-)
+from torchsnapshot_tpu.models import LlamaConfig, init_train_state
 from torchsnapshot_tpu.parallel import factor_mesh, make_mesh
 
 
@@ -51,13 +42,7 @@ def main() -> None:
     data, fsdp, model = factor_mesh(n)
     mesh = make_mesh(data=data, fsdp=fsdp, model=model)
     opt = optax.adamw(1e-3)
-    params = init_params(jax.random.key(0), cfg)
-    train_state = {
-        "params": params,
-        "opt_state": opt.init(params),
-        "step": jnp.zeros((), jnp.int32),
-    }
-    train_state = shard_train_state(train_state, mesh, cfg)
+    train_state = init_train_state(jax.random.key(0), cfg, opt, mesh)
     jax.block_until_ready(train_state["params"])
     nbytes = sum(
         x.size * x.dtype.itemsize for x in jax.tree.leaves(train_state)
@@ -73,15 +58,7 @@ def main() -> None:
     save_s = time.monotonic() - begin
     print(f"save: {save_s:.2f}s = {gb / save_s:.2f} GB/s")
 
-    target = shard_train_state(
-        {
-            "params": init_params(jax.random.key(1), cfg),
-            "opt_state": opt.init(init_params(jax.random.key(1), cfg)),
-            "step": jnp.zeros((), jnp.int32),
-        },
-        mesh,
-        cfg,
-    )
+    target = init_train_state(None, cfg, opt, mesh)
     begin = time.monotonic()
     dst = {"train": StateDict(target)}
     snapshot.restore(dst)

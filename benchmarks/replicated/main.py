@@ -70,6 +70,9 @@ def main() -> None:
     shutil.rmtree(args.work_dir, ignore_errors=True)
     os.makedirs(args.work_dir, exist_ok=True)
     with tempfile.TemporaryDirectory() as store_path:
+        # CPU-only by contract: the workers are forked after ``import jax``
+        # and stay numpy-only.  A chip belongs to one process; this bench
+        # never runs from one that holds it.
         ctx = mp.get_context("fork")
         procs = [
             ctx.Process(

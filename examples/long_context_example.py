@@ -16,9 +16,6 @@ import tempfile
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
 import jax
-
-jax.config.update("jax_platforms", os.environ.get("JAX_PLATFORMS", "cpu"))
-
 import jax.numpy as jnp
 import numpy as np
 import optax

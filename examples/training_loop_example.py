@@ -13,13 +13,12 @@ Run:
 """
 
 import os
+import sys
 import tempfile
 
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+
 import jax
-
-if not os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 import numpy as np
 import optax

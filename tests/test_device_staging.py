@@ -288,7 +288,7 @@ def test_async_roundtrip_with_donation(tmp_path, mode):
     with knobs.override_async_staging(mode):
         pending = Snapshot.async_take(str(tmp_path / f"snap_{mode}"), app_state)
         # Donate the original buffer immediately after return — the
-        # VERDICT-prescribed adversarial step for device-side staging.
+        # adversarial step for device-side staging.
         step = jax.jit(lambda a: a * 0 - 1.0, donate_argnums=(0,))
         jax.block_until_ready(step(x))
         snapshot = pending.wait()
@@ -507,7 +507,7 @@ def test_h2d_batcher_paces_inflight_window():
         np.testing.assert_array_equal(np.asarray(f.obj), np.full(16, float(i)))
 
 
-def test_h2d_batcher_bad_item_fails_alone():
+def test_h2d_batcher_bad_item_fails_alone(caplog):
     """One bad item must not sink the batch: good arrays restore, the bad
     one's error surfaces with correct attribution (advisor r4 finding)."""
     from torchsnapshot_tpu.io_preparers.array import H2DBatcher
@@ -529,9 +529,15 @@ def test_h2d_batcher_bad_item_fails_alone():
     b.submit(np.ones(8, dtype=np.float32), jnp.zeros(8, jnp.float32), f_plain)
     b.submit(np.ones((8, 4), dtype=np.float32), good_sharded, f_sharded)
     b.submit(np.ones(7, dtype=np.float32), _Bad(), f_bad)
-    with pytest.raises(Exception):
-        b.flush()
-    # The plain group and the retried good sharded item both restored.
+    with caplog.at_level("WARNING", logger="torchsnapshot_tpu"):
+        with pytest.raises(Exception):
+            b.flush()
+    # The failed batch's first exception is in the log before the per-item
+    # retry runs (an HBM OOM would otherwise vanish with a retry that
+    # succeeds); chip_smoke.py fails on any library warning.
+    failed = [r for r in caplog.records if "batched device_put" in r.getMessage()]
+    assert len(failed) == 1 and failed[0].exc_info is not None
+    # The retried good items both restored.
     np.testing.assert_array_equal(np.asarray(f_plain.obj), np.ones(8))
     np.testing.assert_array_equal(np.asarray(f_sharded.obj), np.ones((8, 4)))
     assert f_bad.obj is None

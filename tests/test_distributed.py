@@ -125,7 +125,7 @@ class _CountingStore:
 
 def test_barrier_is_o1_store_ops(tmp_path):
     """The barrier must cost O(1) store ops per rank (counter arrive + one
-    blocking sentinel GET), not O(polls) — ADVICE/VERDICT round-1 item."""
+    blocking sentinel GET), not O(polls) — ADVICE round-1 item."""
     import threading
 
     from torchsnapshot_tpu.dist_store import FileStore

@@ -29,11 +29,9 @@ def test_bench_py_produces_json_line():
     proc = _run(
         [sys.executable, "bench.py"],
         {
-            "BENCH_NO_RERUN": "1",
             "BENCH_TARGET_BYTES": str(16 << 20),
             "BENCH_SAVE_ATTEMPTS": "1",
             "BENCH_MAX_S": "200",
-            "BENCH_DEVICE_TIMEOUT_S": "5",
         },
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -43,6 +41,10 @@ def test_bench_py_produces_json_line():
     assert result["value"] > 0
     assert result["unit"] == "GB/s"
     assert "vs_baseline" in result
+    # Every result names the device it ran on; here that is the CPU, because
+    # _run asked for it.
+    assert result["platform"] == "cpu"
+    assert result["device_kind"] and result["device_count"] >= 1
     aux = result["aux"]
     for key in (
         "save_phases",
@@ -52,6 +54,20 @@ def test_bench_py_produces_json_line():
         "save_phase_cpu_sum_s",
     ):
         assert key in aux, key
+
+
+def test_bench_py_refuses_cpu_it_was_not_asked_for():
+    """No accelerator is an error, not a quiet CPU run: with JAX_PLATFORMS
+    naming anything but exactly ``cpu`` and only the CPU to be found, bench.py
+    must exit non-zero and print no result."""
+    proc = _run(
+        [sys.executable, "bench.py"],
+        {"JAX_PLATFORMS": "", "BENCH_TARGET_BYTES": str(16 << 20)},
+        timeout=120,
+    )
+    assert proc.returncode != 0
+    assert "no accelerator" in proc.stderr
+    assert proc.stdout.strip() == ""
 
 
 def test_huge_bench_tiny_run():

@@ -9,7 +9,10 @@ import pytest
 
 
 @pytest.mark.parametrize("n", [1, 6])
-def test_dryrun_multichip_shapes(n):
+def test_dryrun_multichip_shapes(n, monkeypatch, tmp_path):
+    # The entry point places the compile cache; with the variable set it sets
+    # nothing, so this process (the whole test session) keeps none.
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     sys.path.insert(0, "/root/repo")
     try:
         import __graft_entry__ as g

@@ -47,9 +47,8 @@ def _worker(rank: int, world: int, coord_port: int, store_path: str, conn) -> No
         from torchsnapshot_tpu.dist_store import FileStore
         from torchsnapshot_tpu.models import (
             LlamaConfig,
-            init_params,
+            init_train_state,
             make_train_step,
-            shard_train_state,
         )
         from torchsnapshot_tpu.pg_wrapper import PGWrapper
 
@@ -62,13 +61,7 @@ def _worker(rank: int, world: int, coord_port: int, store_path: str, conn) -> No
             vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=128
         )
         opt = optax.adamw(1e-3)
-        params = init_params(jax.random.key(0), cfg)
-        train_state = {
-            "params": params,
-            "opt_state": opt.init(params),
-            "step": jnp.zeros((), jnp.int32),
-        }
-        train_state = shard_train_state(train_state, mesh, cfg)
+        train_state = init_train_state(jax.random.key(0), cfg, opt, mesh)
 
         with mesh:
             step_fn = jax.jit(make_train_step(cfg, opt))
@@ -87,16 +80,7 @@ def _worker(rank: int, world: int, coord_port: int, store_path: str, conn) -> No
         snapshot = Snapshot.take(SNAP_PATH, {"train": StateDict(train_state)}, pg=pg)
 
         # fresh differently-seeded target, same shardings
-        params2 = init_params(jax.random.key(9), cfg)
-        target = shard_train_state(
-            {
-                "params": params2,
-                "opt_state": opt.init(params2),
-                "step": jnp.zeros((), jnp.int32),
-            },
-            mesh,
-            cfg,
-        )
+        target = init_train_state(jax.random.key(9), cfg, opt, mesh)
         dst = {"train": StateDict(target)}
         snapshot.restore(dst)
         restored = dst["train"]

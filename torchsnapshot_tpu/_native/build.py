@@ -112,6 +112,17 @@ def _build(extra_flags=None, out: Optional[str] = None) -> None:
     raise RuntimeError(f"native build failed: {last_error}")
 
 
+def rebuild_native_lib() -> str:
+    """Compile ``tpustore.cc`` now and replace whatever ``libtpusnap.so``
+    is there; raises when the build cannot run.  For callers that must not
+    run against a library of unknown origin: the mtime test below trusts a
+    copied tree's timestamps, and ``get_native_lib_path`` degrades where
+    this fails (chip_smoke.py)."""
+    with _LOCK:
+        _build()
+    return _LIB
+
+
 def lib_is_stale() -> bool:
     """Whether ``tpustore.cc`` is newer than the built ``libtpusnap.so``
     (or the library is missing entirely)."""

@@ -22,26 +22,22 @@ from .dist_store import KVStore
 
 
 def _get_jax_client():
-    try:
-        from jax._src import distributed
+    # jax keeps the coordination client in a private module; an import or
+    # attribute error here means the installed jax moved it, and is repaired
+    # here rather than read as "not distributed".
+    from jax._src import distributed
 
-        state = distributed.global_state
-        return state.client
-    except Exception:
-        return None
+    return distributed.global_state.client
 
 
 def jax_process_info() -> Optional[tuple]:
     """(rank, world_size) if jax.distributed is initialized, else None."""
-    try:
-        from jax._src import distributed
+    from jax._src import distributed
 
-        state = distributed.global_state
-        if state.client is None:
-            return None
-        return state.process_id, state.num_processes
-    except Exception:
+    state = distributed.global_state
+    if state.client is None:
         return None
+    return state.process_id, state.num_processes
 
 
 class JaxCoordinationStore(KVStore):

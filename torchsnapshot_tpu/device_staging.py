@@ -89,10 +89,18 @@ def _device_resident_arrays(flattened: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def _supports_pinned_host(arr: Any) -> bool:
+    dev = next(iter(arr.sharding.device_set))
     try:
-        dev = next(iter(arr.sharding.device_set))
         return "pinned_host" in {m.kind for m in dev.addressable_memories()}
     except Exception:
+        # Every backend of the installed jax answers this; one that raises
+        # is read as "no pinned_host", and says so.
+        logger.warning(
+            "%s.addressable_memories() raised; treating the backend as "
+            "having no pinned_host memory space",
+            dev,
+            exc_info=True,
+        )
         return False
 
 
@@ -101,11 +109,7 @@ def _hbm_headroom_fits(arrays: Dict[str, Any]) -> bool:
     Backends without memory_stats (CPU) always fit — host RAM is the pool."""
     need_per_device: Dict[Any, int] = {}
     for arr in arrays.values():
-        try:
-            shards = arr.addressable_shards
-        except Exception:
-            continue
-        for shard in shards:
+        for shard in arr.addressable_shards:
             nbytes = int(np.prod(shard.data.shape)) * np.dtype(arr.dtype).itemsize
             need_per_device[shard.device] = (
                 need_per_device.get(shard.device, 0) + nbytes
@@ -114,6 +118,14 @@ def _hbm_headroom_fits(arrays: Dict[str, Any]) -> bool:
         try:
             stats = device.memory_stats()
         except Exception:
+            # The CPU backend returns None; no installed backend raises.
+            # One that does is read as "fits", and says so.
+            logger.warning(
+                "%s.memory_stats() raised; HBM headroom for device-copy "
+                "staging is unchecked",
+                device,
+                exc_info=True,
+            )
             stats = None
         if not stats:
             continue
@@ -173,7 +185,7 @@ def _local_staging_signals(
             "device-copy staging"
         )
         if emit_events:
-            _log_downgrade_event(
+            staging.log_staging_downgrade(
                 "pinned_host", "device", "no healthy pinned_host memory space"
             )
         mode = "device"
@@ -185,7 +197,7 @@ def _local_staging_signals(
             "falling back to host staging"
         )
         if emit_events:
-            _log_downgrade_event(
+            staging.log_staging_downgrade(
                 "device", "host", "insufficient HBM headroom for device copy"
             )
         return {"mode": "host", "device_fits": False}
@@ -252,7 +264,7 @@ def resolve_mode(
             # stall-time regression the event stream must carry — but only
             # when this resolution feeds an actual staging.
             if emit_events:
-                _log_downgrade_event(
+                staging.log_staging_downgrade(
                     mode, agreed, f"cross-rank agreement (gathered: {modes})"
                 )
         mode = agreed
@@ -412,7 +424,7 @@ def stage_app_state(
                 "pinned_host staging failed (%s); using device-copy staging",
                 type(e).__name__,
             )
-            _log_downgrade_event("pinned_host", "device", downgrade_reason)
+            staging.log_staging_downgrade("pinned_host", "device", downgrade_reason)
             mode = "device"
             copies = _device_copy_batch([arrays[p] for p in paths])
     elif mode == "device":
@@ -451,28 +463,6 @@ def stage_app_state(
         stats["downgraded_from"] = downgraded_from
         stats["downgrade_reason"] = downgrade_reason
     return out, stats
-
-
-def _log_downgrade_event(from_mode: str, to_mode: str, reason: str) -> None:
-    """Every staging downgrade is an operator-visible event, not just a log
-    line: a fleet alerting on stall regressions needs the signal without
-    scraping logs (r4 verdict item 5)."""
-    try:
-        from .event import Event
-        from .event_handlers import log_event
-
-        log_event(
-            Event(
-                name="async_take.staging_downgrade",
-                metadata={
-                    "from_mode": from_mode,
-                    "to_mode": to_mode,
-                    "reason": reason,
-                },
-            )
-        )
-    except Exception:  # pragma: no cover - telemetry must never break a save
-        logger.debug("failed to emit staging_downgrade event", exc_info=True)
 
 
 def _is_prepare_time_safe(obj: Any) -> bool:

@@ -444,7 +444,7 @@ class _ShardedRestore:
                 with phase_stats.timed(
                     "h2d_dispatch", sum(b.nbytes for b in bufs)
                 ):
-                    per_device = staging.device_put_fast_batch(bufs, targets)
+                    per_device = jax.device_put(bufs, targets)
                 self.fut.obj = jax.make_array_from_single_device_arrays(
                     tuple(self.entry.shape), obj_out.sharding, per_device
                 )

@@ -56,8 +56,6 @@ NATIVE_BATCH_ENV_VAR = _ENV_PREFIX + "NATIVE_BATCH"
 DIRECT_IO_ENV_VAR = _ENV_PREFIX + "DIRECT_IO"
 CHECKSUM_ENV_VAR = _ENV_PREFIX + "CHECKSUM"
 CHECKSUM_ON_SAVE_ENV_VAR = _ENV_PREFIX + "CHECKSUM_ON_SAVE"
-D2H_BITCAST_ENV_VAR = _ENV_PREFIX + "D2H_BITCAST"
-H2D_BITCAST_ENV_VAR = _ENV_PREFIX + "H2D_BITCAST"
 GCS_ENDPOINT_ENV_VAR = _ENV_PREFIX + "GCS_ENDPOINT"
 S3_ENDPOINT_ENV_VAR = _ENV_PREFIX + "S3_ENDPOINT"
 S3_MULTIPART_THRESHOLD_ENV_VAR = _ENV_PREFIX + "S3_MULTIPART_THRESHOLD_BYTES"
@@ -855,26 +853,6 @@ def checksum_on_save_enabled() -> bool:
         "false",
         "",
     )
-
-
-def _get_tristate_env(name: str) -> Optional[bool]:
-    """None when unset (caller decides), else the usual falsy spellings."""
-    val = os.environ.get(name)
-    if val is None:
-        return None
-    return val not in ("0", "false", "")
-
-
-def d2h_bitcast_flag() -> Optional[bool]:
-    """Forced on/off for sub-word d2h bitcast staging, or None — the
-    staging layer then decides per array (staging.py)."""
-    return _get_tristate_env(D2H_BITCAST_ENV_VAR)
-
-
-def h2d_bitcast_flag() -> Optional[bool]:
-    """Forced on/off for sub-word h2d bitcast upload, or None — falls back
-    to the d2h flag, then the per-device heuristic (staging.py)."""
-    return _get_tristate_env(H2D_BITCAST_ENV_VAR)
 
 
 def get_gcs_endpoint() -> Optional[str]:

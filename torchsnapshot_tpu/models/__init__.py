@@ -2,10 +2,10 @@ from .llama import (
     LlamaConfig,
     forward,
     init_params,
+    init_train_state,
     loss_fn,
     make_train_step,
     param_partition_specs,
-    shard_train_state,
     state_partition_specs,
 )
 from .ring_attention import ring_attention

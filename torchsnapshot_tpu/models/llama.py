@@ -127,7 +127,7 @@ def param_partition_specs(
     standard GQA-TP layout.  Callers on a TP mesh must pass the same
     ``model_axis_size`` everywhere (placement AND any spec-derived
     metadata): with the default ``None`` the KV output dim stays
-    model-sharded, which disagrees with what ``shard_train_state`` applied
+    model-sharded, which disagrees with what ``init_train_state`` applied
     on an indivisible mesh.
     """
     kv_out = "model"
@@ -365,19 +365,39 @@ def make_train_step(
     return train_step
 
 
-def shard_train_state(
-    train_state: Dict[str, Any], mesh: Mesh, cfg: LlamaConfig
+def init_train_state(
+    key: Optional[jax.Array], cfg: LlamaConfig, optimizer: Any, mesh: Mesh
 ) -> Dict[str, Any]:
-    """Place an (unsharded) train state onto the mesh per the partition
-    rules; optimizer moments inherit their param's spec."""
+    """A {params, opt_state, step} train state born sharded on ``mesh`` per
+    the partition rules (optimizer moments inherit their param's spec): the
+    init runs under ``jit`` with ``out_shardings``, so no device ever holds
+    more than its share.  ``key=None`` gives the all-zeros state of the same
+    structure and shardings: a restore target."""
+
+    def build(k: jax.Array) -> Dict[str, Any]:
+        params = init_params(k, cfg)
+        return {
+            "params": params,
+            "opt_state": optimizer.init(params),
+            "step": jnp.zeros((), jnp.int32),
+        }
+
+    abstract = jax.eval_shape(build, jax.random.key(0))
     specs = state_partition_specs(
-        train_state, cfg, model_axis_size=mesh.shape.get("model")
+        abstract, cfg, model_axis_size=mesh.shape.get("model")
     )
     shardings = jax.tree_util.tree_map(
         lambda spec: NamedSharding(mesh, spec), specs,
         is_leaf=lambda x: isinstance(x, P),
     )
-    return jax.device_put(train_state, shardings)
+    if key is None:
+        return jax.jit(
+            lambda: jax.tree_util.tree_map(
+                lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), abstract
+            ),
+            out_shardings=shardings,
+        )()
+    return jax.jit(build, out_shardings=shardings)(key)
 
 
 def state_partition_specs(

@@ -787,6 +787,11 @@ class NativeFileIO:
                 cls._instance = cls()
             except Exception:
                 cls._failed = True
+                logger.warning(
+                    "native data plane unavailable; every save and restore "
+                    "of this process takes the pure-Python path",
+                    exc_info=True,
+                )
                 return None
         return cls._instance
 

@@ -438,7 +438,7 @@ class Snapshot:
                         "host staging (stage-before-return)",
                         exc_info=True,
                     )
-                    device_staging._log_downgrade_event(
+                    staging.log_staging_downgrade(
                         staging_mode,
                         "host",
                         f"{type(staging_exc).__name__}: {staging_exc}",

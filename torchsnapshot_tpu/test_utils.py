@@ -131,7 +131,12 @@ def run_with_procs(nproc: int) -> Callable:
     """Decorator: re-execute the test body in ``nproc`` local processes
     (reference run_with_pet, test_utils.py:232-255).  The body calls
     ``make_test_pg()`` for its process group.  Uses fork start method (fast,
-    and jax CPU backend tolerates it before first backend use in children)."""
+    and jax CPU backend tolerates it before first backend use in children).
+
+    CPU-only by contract: the children are forked after ``import jax`` and
+    must stay numpy-only.  A chip belongs to one process, so a parent that
+    holds one cannot hand it to a forked child; never call this from a
+    process that has touched an accelerator backend."""
 
     def decorator(fn: Callable) -> Callable:
         @functools.wraps(fn)
