@@ -28,6 +28,21 @@ from torchsnapshot_tpu.manifest import SnapshotMetadata
 from torchsnapshot_tpu.storage_plugin import url_to_storage_plugin
 
 
+@pytest.fixture(autouse=True)
+def _knob_env_as_found():
+    """The tenants run as threads, and each enters ``knobs.override_*``
+    scopes of its own: two threads that interleave save and restore each
+    other's values, and ``TPUSNAP_STORE`` and ``TPUSNAP_CAS`` outlive the
+    test.  Whatever file the worker ran next then wrote into this test's
+    store.  Put the environment back as it was found."""
+    saved = {k: v for k, v in os.environ.items() if k.startswith("TPUSNAP_")}
+    yield
+    for key in [k for k in os.environ if k.startswith("TPUSNAP_")]:
+        if key not in saved:
+            del os.environ[key]
+    os.environ.update(saved)
+
+
 def _state(v):
     return {
         "m": StateDict(

@@ -422,6 +422,8 @@ class H2DBatcher:
             self.flush()
 
     def flush(self) -> None:
+        from .. import phase_stats
+
         with self._lock:
             items, self._items, self._bytes = self._items, [], 0
         if not items:
@@ -435,15 +437,22 @@ class H2DBatcher:
         # older batches started the moment they were dispatched), and a full
         # window stalling the producer is the point — reads must not run
         # unboundedly ahead of a slow H2D link.
+        # The wait is h2d_window_wait when it lasted: it can hold a consumer
+        # on the read pipeline's loop thread, and with it the pipeline.
+        window_wait = None
         with self._cond:
             self._raise_lander_error()
             while (
                 self._unlanded_bytes > 0
                 and self._unlanded_bytes + batch_bytes > self._inflight_cap
             ):
+                if window_wait is None:
+                    window_wait = phase_stats.open_interval("h2d_window_wait")
                 self._cond.wait(timeout=1.0)
                 self._raise_lander_error()
             self._unlanded_bytes += batch_bytes  # reserved
+        if window_wait is not None:
+            window_wait.close(min_s=0.001)
         try:
             outs, failed = self._dispatch(items, batch_bytes)
         except BaseException:
@@ -562,8 +571,6 @@ class H2DBatcher:
         where ``outs[i]`` is None for items that did not dispatch and
         ``failed`` lists exactly those items for the caller's per-item
         retry."""
-        import time as _time
-
         import jax
 
         from .. import phase_stats
@@ -591,11 +598,12 @@ class H2DBatcher:
         # Manual phase accounting, recorded only for DISPATCHED bytes:
         # timed() commits in its finally, so a failed batch would charge its
         # bytes to h2d_dispatch and the per-item retry would charge again.
-        begin = _time.monotonic()
+        dispatch = phase_stats.open_interval("h2d_dispatch")
         try:
             for i, out in zip(idx, jax.device_put(bufs, shardings)):
                 outs[i] = out
         except Exception:
+            dispatch.drop()
             # An HBM OOM looks like this: the per-item retry may well
             # succeed, so the first failure must not vanish with it.
             logger.warning(
@@ -608,11 +616,7 @@ class H2DBatcher:
             outs = [None] * len(items)
             failed.extend(items[i] for i in idx)
         else:
-            phase_stats.add(
-                "h2d_dispatch",
-                _time.monotonic() - begin,
-                sum(b.nbytes for b in bufs),
-            )
+            dispatch.close(sum(b.nbytes for b in bufs))
         return outs, failed
 
     def _dispatch_per_item(

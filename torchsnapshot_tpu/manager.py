@@ -31,6 +31,7 @@ from typing import Any, Dict, List, Optional, Set, Tuple, Union
 from . import cas as cas_mod
 from . import journal as journal_mod
 from . import knobs
+from . import phase_stats
 from . import retry
 from . import store as store_mod
 from .event import Event
@@ -1193,7 +1194,10 @@ class SnapshotManager:
         restore is collective — ranks must fail identically (shared
         storage) for the fallback to stay coherent; per-rank divergent
         corruption surfaces as a collective error instead."""
-        points = self.restore_points()
+        # Listing is part of what a resume pays: the same phase as the
+        # opening half of Snapshot.restore.
+        with phase_stats.timed("restore_open"):
+            points = self.restore_points()
         first_error: Optional[BaseException] = None
         for fallbacks, (step, kind) in enumerate(reversed(points)):
             label = ("step_" if kind == "full" else "seg_") + str(step)

@@ -10,8 +10,19 @@ thread-seconds of d2h inside a 40 s save, and reporting only the former
 misled round 3's bench record).  Overhead is one clock pair + dict update
 per payload; payload counts are small.
 
-Consumers: ``bench.py`` (resets around each benchmark attempt, reports the
-deltas in its JSON aux) and the scheduler's end-of-pipeline log line.
+One clock with the device: every ``timed()`` block (and every
+``open_interval``, the same thing for a site whose end is not a block's
+end) also opens a ``jax.profiler.TraceAnnotation`` named after the phase,
+so under any ``jax.profiler`` session the phases are host events of the
+``.xplane.pb``, on the trace's clock, from their beginning and on their own
+thread.  With no session the annotation is a flag test.
+
+Consumers: ``chipbench`` (``snapshot``/``delta`` around a cell's window,
+``set_trace_hook`` in a traced run; its per-layer readers divide these
+walls by the window's restores), ``Snapshot.restore``'s ``restore.end``
+event (``walls_between`` and ``attributed_wall_s`` over the one call: the
+per-phase wall and what no phase covers), the span tracer's leaf spans, and
+the scheduler's end-of-pipeline log line.
 """
 
 from __future__ import annotations
@@ -20,6 +31,8 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Dict, Generator, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 _lock = threading.Lock()
 _stats: Dict[str, Dict[str, float]] = {}
@@ -275,9 +288,23 @@ def add(
             pass  # telemetry must never break the pipeline
 
 
+def add_counter(name: str, seconds: float) -> None:
+    """Record seconds that belong to no interval (``restore_unattributed``:
+    what is left of a call once every phase's interval is taken out).  The
+    entry has ``s`` and ``n`` and no ``wall``, and reaches neither hook, so
+    it can name no gap of a trace; ``delta()`` differences it like any
+    other."""
+    with _lock:
+        slot = _stats.setdefault(name, {"s": 0.0, "bytes": 0, "n": 0})
+        slot["s"] += seconds
+        slot["n"] += 1
+
+
 @contextmanager
 def timed(phase: str, nbytes: int = 0) -> Generator[None, None, None]:
     begin = time.monotonic()
+    annotation = TraceAnnotation(phase)
+    annotation.__enter__()
     token = object()
     with _lock:
         _active_begins.setdefault(phase, {})[token] = begin
@@ -286,8 +313,52 @@ def timed(phase: str, nbytes: int = 0) -> Generator[None, None, None]:
         yield
     finally:
         _pop_thread_phase()
+        annotation.__exit__(None, None, None)
         end = time.monotonic()
         add(phase, end - begin, nbytes, end=end, _release_token=token)
+
+
+def annotation(phase: str) -> TraceAnnotation:
+    """The annotation alone, for a block whose interval another site
+    records with ``add()`` once it knows the phase's name
+    (``fs._blocking_read``)."""
+    return TraceAnnotation(phase)
+
+
+class open_interval:
+    """``timed()`` for a site whose interval does not end where a block
+    ends: a wait that is recorded only when it lasted (``io_slot_wait``), a
+    stretch that runs across turns of an event loop (``read_starved``), a
+    dispatch recorded only when it succeeded.  Reads the clock and opens the
+    phase's ``TraceAnnotation`` now; ``close()`` ends the annotation and
+    records through ``add()``; one dropped unclosed (an error on the way)
+    records nothing, and its annotation ends with it.  It sets no thread
+    tag: on a loop thread such intervals interleave with other coroutines'
+    blocks."""
+
+    __slots__ = ("phase", "begin", "_annotation")
+
+    def __init__(self, phase: str) -> None:
+        self.phase = phase
+        self.begin = time.monotonic()
+        self._annotation: Optional[TraceAnnotation] = TraceAnnotation(phase)
+        self._annotation.__enter__()
+
+    def close(self, nbytes: int = 0, min_s: float = 0.0) -> None:
+        """Idempotent.  An interval shorter than ``min_s`` is not recorded
+        (its annotation still is: a trace shows the site was passed)."""
+        annotation, self._annotation = self._annotation, None
+        if annotation is None:
+            return
+        annotation.__exit__(None, None, None)
+        end = time.monotonic()
+        if end - self.begin >= min_s:
+            add(self.phase, end - self.begin, nbytes, end=end)
+
+    def drop(self) -> None:
+        """End the annotation and record nothing: the work failed and is
+        done again under another interval."""
+        self.close(min_s=float("inf"))
 
 
 def _merge(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -314,17 +385,45 @@ def snapshot() -> Dict[str, Dict[str, float]]:
     return out
 
 
-def attributed_wall_s() -> float:
-    """Union of EVERY phase's active intervals: the share of elapsed time
-    that at least one phase accounts for.  A bench attempt's coverage is
-    this over its wall time — the r4 verdict's blind spot was 159 s of
-    restore wall no phase could see (coverage 0.23).  Retired wall bases
-    are excluded (they cannot be unioned across phases); the bench resets
-    per attempt, far below the compaction threshold, so its coverage is
-    exact."""
+def _clipped(
+    intervals: List[Tuple[float, float]], begin: float, end: float
+) -> List[Tuple[float, float]]:
+    return [
+        (max(b, begin), min(e, end))
+        for b, e in intervals
+        if e > begin and b < end
+    ]
+
+
+def attributed_wall_s(
+    begin: float = float("-inf"), end: float = float("inf")
+) -> float:
+    """Union of EVERY phase's active intervals, clipped to ``[begin,
+    end]``: the share of that time that at least one phase accounts for.
+    A call's coverage is this over its wall time — the r4 verdict's blind
+    spot was 159 s of restore wall no phase could see (coverage 0.23).
+    Retired wall bases are excluded (they cannot be unioned across
+    phases): exact while no one phase has left more than the compaction
+    threshold's worth of DISJOINT intervals inside the window, an
+    under-count after that."""
     with _lock:
         ivs = [iv for lst in _intervals.values() for iv in lst]
-    return _union_s(ivs)
+    return _union_s(_clipped(ivs, begin, end))
+
+
+def walls_between(begin: float, end: float) -> Dict[str, float]:
+    """Each phase's wall-union clipped to ``[begin, end]``, for the phases
+    that were active in it: one call's own account, where ``delta()``
+    differences process-wide totals.  Same exactness as
+    ``attributed_wall_s``."""
+    with _lock:
+        live = {phase: list(ivs) for phase, ivs in _intervals.items()}
+    out: Dict[str, float] = {}
+    for phase, ivs in live.items():
+        clipped = _clipped(ivs, begin, end)
+        if clipped:
+            out[phase] = _union_s(clipped)
+    return out
 
 
 def reset() -> None:

@@ -897,11 +897,9 @@ async def execute_read_reqs(
         attempt = 0
         while True:
             try:
-                slot_wait_begin = time.monotonic()
+                slot_wait = phase_stats.open_interval("io_slot_wait")
                 async with io_semaphore:
-                    slot_wait_s = time.monotonic() - slot_wait_begin
-                    if slot_wait_s > 0.001:
-                        phase_stats.add("io_slot_wait", slot_wait_s)
+                    slot_wait.close(min_s=0.001)
                     return await pipeline.read_buffer()
             except asyncio.CancelledError:
                 raise
@@ -948,6 +946,20 @@ async def execute_read_reqs(
             else:
                 break
 
+    # read_starved: one interval per stretch in which the pipeline is alive
+    # (a consume is pending) and no read is in flight, so storage is not
+    # being driven.  The tail after the last read is always one.
+    starved: Optional[phase_stats.open_interval] = None
+
+    def track_starved() -> None:
+        nonlocal starved
+        if consume_tasks and not io_tasks:
+            if starved is None:
+                starved = phase_stats.open_interval("read_starved")
+        elif starved is not None:
+            starved.close()
+            starved = None
+
     read_span = ttrace.span("read_pipeline", cat="scheduler", n_reqs=len(read_reqs))
     read_span.__enter__()
     try:
@@ -958,16 +970,16 @@ async def execute_read_reqs(
             # inadmissible WHILE read slots sit idle — a head queued
             # behind saturated storage is storage-bound, not budget-bound.
             budget_bound = bool(ready_for_io) and len(io_tasks) < io_cap
-            blocked_begin = time.monotonic() if budget_bound else None
+            blocked = (
+                phase_stats.open_interval("budget_wait") if budget_bound else None
+            )
             done, _ = await asyncio.wait(
                 io_tasks | consume_tasks,
                 timeout=reporter._interval_s or None,
                 return_when=asyncio.FIRST_COMPLETED,
             )
-            if blocked_begin is not None:
-                phase_stats.add(
-                    "budget_wait", time.monotonic() - blocked_begin
-                )
+            if blocked is not None:
+                blocked.close()
             for task in done:
                 if task in io_tasks:
                     io_tasks.discard(task)
@@ -988,6 +1000,7 @@ async def execute_read_reqs(
                     reporter.bytes_done += pipeline.consuming_cost
                     tmetrics.record_io_bytes("read", pipeline.consuming_cost)
             dispatch_io()
+            track_starved()
             reporter.maybe_report(
                 budget,
                 pending=len(ready_for_io),
@@ -998,6 +1011,8 @@ async def execute_read_reqs(
     except BaseException:
         import sys
 
+        if starved is not None:
+            starved.close()
         read_span.__exit__(*sys.exc_info())
         # Mirror the write path: cancel-and-drain outstanding reads/consumes
         # before re-raising, releasing buffers and re-crediting the budget.

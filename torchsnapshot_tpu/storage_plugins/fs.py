@@ -343,13 +343,18 @@ class FSStoragePlugin(StoragePlugin):
         call replaces the per-chunk Python loop.  With ``want_hash`` the
         per-stripe digests are fused with the reads (the "xxh64s"
         verify-while-reading path)."""
+        from .. import phase_stats
+
         offset = byte_range[0] if byte_range is not None else 0
-        hashes = self._native.read_ranges_into(
-            path,
-            [(offset, offset + view.nbytes)],
-            [view],
-            want_hash=want_hash,
-        )
+        # _blocking_read records the phase once _read_impl has said which
+        # it was; here the name is known from the start.
+        with phase_stats.annotation("native_read"):
+            hashes = self._native.read_ranges_into(
+                path,
+                [(offset, offset + view.nbytes)],
+                [view],
+                want_hash=want_hash,
+            )
         return hashes[0] if hashes else None
 
     def _read_impl(self, path: str, byte_range, into, want_hash, hash_algo):

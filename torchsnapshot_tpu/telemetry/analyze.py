@@ -17,8 +17,9 @@ computes, per rank and across ranks:
   attributable;
 - **the limiting resource** — ``memory_budget`` when the scheduler's
   ``budget_wait`` attribution dominates, ``io_concurrency`` when
-  ``io_slot_wait`` does, else the dominant of the d2h / serialize /
-  storage_io / h2d phase groups;
+  ``io_slot_wait`` does (likewise ``h2d_wait`` and ``read_starved`` on a
+  restore), else the dominant of the d2h / serialize / storage_io / h2d /
+  driver phase groups;
 - **cross-rank skew** — p50/p99/max op duration, the straggler rank, and
   the slowest rank per phase.
 
@@ -58,8 +59,23 @@ PHASE_GROUPS: Dict[str, frozenset] = {
         }
     ),
     "h2d": frozenset({"h2d_dispatch", "h2d_land"}),
+    # What the thread that drives a restore does between storage reads
+    # (snapshot.py, manager.restore_latest): opening the snapshot, planning
+    # a stateful's reads, handing the restored values to the stateful.
+    # Work, and leaves: none encloses a read.  The same group as the
+    # profiler's <kind>_drive tags.  (plan_read would suffix-match _read;
+    # the explicit entry comes first.)
+    "driver": frozenset({"restore_open", "plan_read", "load_state"}),
     "memory_budget": frozenset({"budget_wait"}),
     "io_concurrency": frozenset({"io_slot_wait"}),
+    # Waits of the restore path on H2D: a consumer held because the
+    # batcher's unlanded window is full, and the driver's wait for the
+    # tail to land once the reads are over.  The work under them is
+    # h2d_land, so they do not inflate the h2d group.
+    "h2d_wait": frozenset({"h2d_window_wait", "h2d_drain"}),
+    # The read pipeline alive with no read in flight (scheduler.py):
+    # storage is not being driven.
+    "read_starved": frozenset({"read_starved"}),
     # Waits, not work: barrier_wait is wall parked in LinearBarrier
     # arrive/depart (commit-barrier skew — the straggler's peers burn it),
     # cache_wait is wall parked on a sibling's in-flight cache populate
@@ -96,7 +112,14 @@ _STORAGE_SUFFIXES = ("_write", "_read")
 # Groups that are time spent WAITING on a resource rather than doing
 # work; the limiting-resource classifier treats them specially and the
 # dominant-phase ranking excludes them.
-WAIT_GROUPS = ("memory_budget", "io_concurrency", "barrier", "cache_wait")
+WAIT_GROUPS = (
+    "memory_budget",
+    "io_concurrency",
+    "barrier",
+    "cache_wait",
+    "h2d_wait",
+    "read_starved",
+)
 # A wait group only names the limiting resource when it covers at least
 # this share of the op (below that it's contention noise, and the real
 # answer is the dominant work group).
