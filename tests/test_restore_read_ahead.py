@@ -1,0 +1,466 @@
+"""Read-ahead across statefuls: one read pipeline serves the whole restore
+(``scheduler.ReadAhead``).  On a slow fake storage plug-in: the next
+stateful is read while the one before it loads; nothing of it is consumed or
+sent to the device before that load has returned; the look-ahead is one
+stateful; user code stays on the calling thread, in key order; a failure
+ahead leaves what was loaded loaded and no thread behind."""
+
+import asyncio
+import threading
+import time
+import types
+import weakref
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from torchsnapshot_tpu import RNGState, Snapshot, StateDict, knobs, phase_stats
+from torchsnapshot_tpu import scheduler as scheduler_mod
+from torchsnapshot_tpu import snapshot as snapshot_mod
+from torchsnapshot_tpu.event_handlers import (
+    register_event_handler,
+    unregister_event_handler,
+)
+from torchsnapshot_tpu.io_preparers.array import H2DBatcher
+from torchsnapshot_tpu.io_types import BufferConsumer, ReadReq, StoragePlugin
+from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
+
+KEYS = ("s0", "s1", "s2", "s3")
+LEAVES = 3
+SHAPE = (512, 1024)  # 2 MiB of float32: over the size from which a read lands in place
+READ_S = 0.03  # one storage read
+LOAD_S = 0.15  # one load_state_dict: several reads long, so the gate binds
+PIPELINE_THREADS = ("tpusnap-read-pipeline", "tpusnap-h2d-lander")
+
+
+class Log:
+    """``(what, key, monotonic, thread name)`` from every thread."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.rows = []
+
+    def add(self, what, key):
+        with self._lock:
+            self.rows.append(
+                (what, key, time.monotonic(), threading.current_thread().name)
+            )
+
+    def times(self, what, key=None):
+        return [t for w, k, t, _ in self.rows if w == what and key in (None, k)]
+
+    def first(self, what, key):
+        return min(self.times(what, key))
+
+    def last(self, what, key):
+        return max(self.times(what, key))
+
+
+class Recorder(StateDict):
+    """A stateful whose load lasts, and says when and on which thread."""
+
+    def __init__(self, key, data, log, load_s=LOAD_S):
+        super().__init__(data)
+        self._key, self._log, self._load_s = key, log, load_s
+
+    def state_dict(self):
+        self._log.add("state_dict", self._key)
+        return super().state_dict()
+
+    def load_state_dict(self, state_dict):
+        self._log.add("load_begin", self._key)
+        time.sleep(self._load_s)
+        super().load_state_dict(state_dict)
+        self._log.add("load_end", self._key)
+
+
+def stateful_of(path):
+    """``0/<key>/<leaf>``: with batching off a payload's path names its stateful."""
+    parts = path.split("/")
+    return parts[1] if len(parts) >= 3 and parts[1] in KEYS else None
+
+
+class SlowStorage(StoragePlugin):
+    """The memory plug-in with reads that take ``READ_S`` and can fail."""
+
+    def __init__(self, inner, log, fail_key=None, fail_after=None, on_read=None):
+        self._inner, self._log = inner, log
+        self._fail_key, self._fail_after, self._on_read = fail_key, fail_after, on_read
+
+    async def read(self, read_io):
+        key = stateful_of(read_io.path)
+        if key is None:
+            return await self._inner.read(read_io)
+        self._log.add("read_begin", key)
+        if self._on_read is not None:
+            self._on_read(key)
+        await asyncio.sleep(READ_S)
+        if key == self._fail_key:
+            # terminal, not transient: no retry; and only once the stateful
+            # before it is inside its load
+            while not self._log.times(*self._fail_after):
+                await asyncio.sleep(0.005)
+            raise ValueError(f"injected read failure in {key}")
+        await self._inner.read(read_io)
+        self._log.add("read_end", key)
+
+    async def write(self, write_io):
+        await self._inner.write(write_io)
+
+    async def delete(self, path):
+        await self._inner.delete(path)
+
+    async def delete_dir(self, path):
+        await self._inner.delete_dir(path)
+
+    async def close(self):
+        await self._inner.close()
+
+
+def make_app(log, keys=KEYS, zero=False, dtype=jnp.float32, seed=0, load_s=LOAD_S):
+    rng = np.random.RandomState(seed)
+    app = {}
+    for key in keys:
+        data = {
+            f"w{i}": jnp.zeros(SHAPE, dtype)
+            if zero
+            else jnp.asarray(rng.rand(*SHAPE), dtype)
+            for i in range(LEAVES)
+        }
+        app[key] = Recorder(key, data, log, load_s)
+    return app
+
+
+@pytest.fixture
+def world(monkeypatch):
+    """A snapshot of ``KEYS`` in memory, and every seam recorded: reads
+    (slow), plans (weak references to the host buffers each stateful's
+    reads land in), consumes, H2D submits and dispatches, each with its
+    stateful."""
+    MemoryStoragePlugin.reset()
+    log = Log()
+    url = f"memory://read_ahead_{time.monotonic_ns()}"
+    saved = make_app(Log())
+    with knobs.override_batching_disabled(True):
+        Snapshot.take(url, saved)
+
+    storage_args = {}
+    real_plugin = snapshot_mod.url_to_storage_plugin
+    monkeypatch.setattr(
+        snapshot_mod,
+        "url_to_storage_plugin",
+        lambda path, options=None: SlowStorage(
+            real_plugin(path, options), log, **storage_args
+        ),
+    )
+
+    plans = {}  # key -> (weak references to its host buffers, id of its batcher)
+    real_plan = Snapshot._plan_stateful_reads
+
+    def recording_plan(key, stateful, metadata, rank):
+        plan = real_plan(key, stateful, metadata, rank)
+        # the view a read lands through lives as long as the request's hold
+        # on the buffer (the buffer itself the CPU backend may alias into
+        # the restored array)
+        buffers = [
+            weakref.ref(rr.into.obj) for rr in plan.read_reqs if rr.into is not None
+        ]
+        assert len(buffers) == (LEAVES if key in KEYS else 0)
+        plans[key] = (buffers, id(plan.h2d_batch))
+        return plan
+
+    monkeypatch.setattr(Snapshot, "_plan_stateful_reads", staticmethod(recording_plan))
+
+    def key_of_batcher(batcher):
+        return next(k for k, (_, ident) in plans.items() if ident == id(batcher))
+
+    real_consume = scheduler_mod._ReadPipeline.consume_buffer
+
+    async def recording_consume(self, executor):
+        log.add("consume_begin", stateful_of(self.read_req.path))
+        return await real_consume(self, executor)
+
+    monkeypatch.setattr(scheduler_mod._ReadPipeline, "consume_buffer", recording_consume)
+    real_submit, real_dispatch = H2DBatcher.submit, H2DBatcher._dispatch
+
+    def recording_submit(self, host, like, fut):
+        log.add("h2d_submit", key_of_batcher(self))
+        return real_submit(self, host, like, fut)
+
+    def recording_dispatch(self, items, batch_bytes):
+        log.add("h2d_dispatch", key_of_batcher(self))
+        return real_dispatch(self, items, batch_bytes)
+
+    monkeypatch.setattr(H2DBatcher, "submit", recording_submit)
+    monkeypatch.setattr(H2DBatcher, "_dispatch", recording_dispatch)
+
+    return types.SimpleNamespace(
+        log=log, url=url, saved=saved, plans=plans, storage_args=storage_args
+    )
+
+
+def restore(world, target):
+    ends = []
+
+    def on_event(event):
+        if event.name == "restore.end":
+            ends.append(dict(event.metadata))
+
+    before = phase_stats.snapshot()
+    register_event_handler(on_event)
+    try:
+        Snapshot(world.url).restore(target)
+    finally:
+        unregister_event_handler(on_event)
+    return phase_stats.delta(before), ends
+
+
+def assert_equal_bits(target, saved, keys=KEYS):
+    for key in keys:
+        for name, want in saved[key].state_dict().items():
+            got = target[key].state_dict()[name]
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(
+                np.asarray(got).view(np.uint8), np.asarray(want).view(np.uint8)
+            )
+
+
+def no_pipeline_thread_alive():
+    deadline = time.monotonic() + 5.0
+    while time.monotonic() < deadline:
+        alive = [t.name for t in threading.enumerate() if t.name in PIPELINE_THREADS]
+        if not alive:
+            return True
+        time.sleep(0.01)
+    return False
+
+
+def test_the_next_stateful_is_read_while_this_one_loads(world):
+    target = make_app(world.log, zero=True)
+    delta, (end,) = restore(world, target)
+    log = world.log
+    for this, ahead in zip(KEYS, KEYS[1:]):
+        assert log.first("read_begin", ahead) < log.last("load_end", this), (this, ahead)
+        # ... and not beside this one's reads, whose rate it would share: the
+        # read-ahead begins where the tail begins
+        assert log.first("read_begin", ahead) >= log.last("read_end", this), (this, ahead)
+        assert log.first("read_begin", ahead) < log.first("load_begin", this), (this, ahead)
+    # ... on a thread that is not the one that loads
+    readers = {name for what, _, _, name in log.rows if what == "read_begin"}
+    loaders = {name for what, _, _, name in log.rows if what == "load_begin"}
+    assert readers == {"tpusnap-read-pipeline"} and readers.isdisjoint(loaders)
+    counter = delta["read_ahead"]
+    assert counter["n"] == 1 and counter["s"] > 0 and counter["bytes"] > 0
+    assert "wall" not in counter
+    assert end["read_ahead_s"] == pytest.approx(counter["s"])
+    assert end["read_ahead_bytes"] == counter["bytes"]
+    # three statefuls are read ahead, and none of them twice
+    one = LEAVES * SHAPE[0] * SHAPE[1] * 4
+    assert 0 < counter["bytes"] <= (len(KEYS) - 1) * one
+    assert_equal_bits(target, world.saved)
+    assert no_pipeline_thread_alive()
+
+
+def test_nothing_is_consumed_or_sent_to_the_device_ahead_of_the_load(world):
+    target = make_app(world.log, zero=True)
+    restore(world, target)
+    log = world.log
+    for this, ahead in zip(KEYS, KEYS[1:]):
+        loaded = log.last("load_end", this)
+        # the gate binds: the reads of the next one were over before then
+        assert log.last("read_end", ahead) < loaded, (this, ahead)
+        for what in ("consume_begin", "h2d_submit", "h2d_dispatch"):
+            assert log.times(what, ahead), (what, ahead)
+            assert log.first(what, ahead) >= loaded, (what, this, ahead)
+
+
+def test_the_look_ahead_is_one_stateful(world):
+    violations = []
+
+    def on_read(key):
+        k = KEYS.index(key)
+        if k < 2:
+            return
+        released = KEYS[k - 2]
+        if not world.log.times("load_end", released):
+            violations.append((key, released, "not loaded"))
+        alive = [ref for ref in world.plans[released][0] if ref() is not None]
+        if alive:
+            violations.append((key, released, f"{len(alive)} host buffers alive"))
+
+    world.storage_args["on_read"] = on_read
+    target = make_app(world.log, zero=True)
+    restore(world, target)
+    assert not violations, violations
+    assert len(world.log.times("read_begin")) == len(KEYS) * LEAVES
+    # and it is a look-ahead: k+1 was being read before k's buffers went
+    for this, ahead in zip(KEYS, KEYS[1:]):
+        assert world.log.first("read_begin", ahead) < world.log.last("load_end", this)
+    assert all(ref() is None for refs, _ in world.plans.values() for ref in refs)
+
+
+def test_user_code_runs_on_the_calling_thread_in_key_order_rng_last(world):
+    target = make_app(world.log, zero=True)
+    target["rng"] = RNGState()
+    order = []
+    real_load = RNGState.load_state_dict
+
+    def rng_load(self, state_dict):
+        order.append(("rng", threading.current_thread().name))
+        return real_load(self, state_dict)
+
+    saved = dict(world.saved)
+    saved["rng"] = RNGState()
+    MemoryStoragePlugin.reset()
+    with knobs.override_batching_disabled(True):
+        Snapshot.take(world.url, saved)
+    RNGState.load_state_dict = rng_load
+    try:
+        restore(world, target)
+    finally:
+        RNGState.load_state_dict = real_load
+    me = threading.current_thread().name
+    rows = [r for r in world.log.rows if r[0] in ("state_dict", "load_begin", "load_end")]
+    assert {name for _, _, _, name in rows} == {me}
+    assert [k for what, k, _, _ in rows if what == "state_dict"] == list(KEYS)
+    loads = [(what, k) for what, k, _, _ in rows if what != "state_dict"]
+    assert loads == [(what, k) for k in KEYS for what in ("load_begin", "load_end")]
+    # every plan is made before the first load, RNG state's too, and it loads last
+    assert max(world.log.times("state_dict")) < min(world.log.times("load_begin"))
+    assert order == [("rng", me)]
+
+
+def test_a_read_failure_ahead_leaves_the_loaded_loaded_and_no_thread(world):
+    # s2's reads fail, and not before s1 is inside its load_state_dict
+    world.storage_args.update(fail_key="s2", fail_after=("load_begin", "s1"))
+    target = make_app(world.log, zero=True)
+    before = {k: dict(target[k].state_dict()) for k in KEYS}
+    with pytest.raises(ValueError, match="injected read failure in s2"):
+        restore(world, target)
+    assert no_pipeline_thread_alive()
+    assert_equal_bits(target, world.saved, keys=("s0", "s1"))
+    loaded = [k for what, k, _, _ in world.log.rows if what == "load_end"]
+    assert loaded == ["s0", "s1"]
+    for key in ("s2", "s3"):
+        assert not world.log.times("load_begin", key)
+        for name, was in before[key].items():
+            assert target[key].state_dict()[name] is was
+    # nothing of the failed stateful or the one after it reached the device
+    assert not world.log.times("h2d_dispatch", "s2")
+    assert not world.log.times("read_begin", "s3")
+
+
+def test_a_load_that_raises_stops_the_pipeline(world):
+    target = make_app(world.log, zero=True)
+
+    def boom(state_dict):
+        raise RuntimeError("user code failed")
+
+    target["s1"].load_state_dict = boom
+    with pytest.raises(RuntimeError, match="user code failed"):
+        restore(world, target)
+    assert no_pipeline_thread_alive()
+    assert_equal_bits(target, world.saved, keys=("s0",))
+    assert not world.log.times("load_begin", "s2")
+
+
+def test_one_stateful_counts_no_read_ahead(world):
+    target = make_app(world.log, keys=("s1",), zero=True)
+    delta, (end,) = restore(world, target)
+    assert delta["read_ahead"] == {"s": 0.0, "bytes": 0, "n": 1}
+    assert end["read_ahead_s"] == 0.0 and end["read_ahead_bytes"] == 0
+    assert delta["read_starved"]["n"] >= 1
+    assert_equal_bits(target, world.saved, keys=("s1",))
+
+
+@pytest.mark.parametrize("case", ["float32", "bfloat16", "chunked"])
+def test_a_restore_that_reads_ahead_is_bit_equal(tmp_path, case):
+    dtype = jnp.bfloat16 if case == "bfloat16" else jnp.float32
+    chunk = 64 << 10 if case == "chunked" else 512 << 20
+    rng = np.random.RandomState(7)
+
+    def state(zero):
+        return {
+            key: StateDict(
+                {
+                    f"w{i}": jnp.zeros((256, 256 + 64 * i), dtype)
+                    if zero
+                    else jnp.asarray(rng.randn(256, 256 + 64 * i), dtype)
+                    for i in range(4)
+                }
+            )
+            for key in ("adam_mu", "adam_nu", "params", "progress")
+        }
+
+    saved, target = state(zero=False), state(zero=True)
+    # the fourth stateful has nothing to read, as chipbench's has
+    saved["progress"], target["progress"] = StateDict({"step": 7}), StateDict({"step": 0})
+    before = phase_stats.snapshot()
+    with knobs.override_max_chunk_size_bytes(chunk):
+        snapshot = Snapshot.take(str(tmp_path / "snap"), saved)
+        if case == "chunked":
+            kinds = {type(e).__name__ for e in snapshot.get_manifest().values()}
+            assert "ChunkedTensorEntry" in kinds
+        snapshot.restore(target)
+    assert phase_stats.delta(before)["read_ahead"]["n"] == 1
+    assert_equal_bits(target, saved, keys=("adam_mu", "adam_nu", "params"))
+    assert target["progress"]["step"] == 7
+
+
+# ------------------------------------------------ the scheduler, on its own
+
+
+class _Consumer(BufferConsumer):
+    def __init__(self, sink, key, cost):
+        self.sink, self.key, self.cost = sink, key, cost
+
+    async def consume_buffer(self, buf, executor=None):
+        self.sink.append((self.key, time.monotonic(), bytes(buf)))
+
+    def get_consuming_cost_bytes(self):
+        return self.cost
+
+
+@pytest.mark.parametrize("budget", [1, 250, 1 << 20])
+def test_groups_complete_in_order_under_any_budget(budget):
+    """A budget under one request, one that parked reads exhaust, and one
+    that never binds: no order of events leaves the pipeline waiting on
+    itself, and a group is consumed only once the one before is loaded."""
+    MemoryStoragePlugin.reset()
+    storage = MemoryStoragePlugin(root=f"groups_{budget}")
+    payloads = {f"g{g}/p{i}": bytes([g * 16 + i]) * 100 for g in range(4) for i in range(5)}
+    for path, data in payloads.items():
+        storage._files[path] = data
+    sink = []
+    groups = [
+        [ReadReq(path=p, buffer_consumer=_Consumer(sink, p, 100)) for p in payloads if p.startswith(f"g{g}/")]
+        for g in range(4)
+    ]
+    groups.insert(2, [])  # a stateful with nothing to read
+    began = time.monotonic()
+    pipeline = scheduler_mod.ReadAhead(groups, storage, budget, rank=0)
+    loaded_at = []
+    try:
+        for group in range(len(groups)):
+            waiter = threading.Thread(target=pipeline.wait_consumed, args=(group,))
+            waiter.start()
+            waiter.join(timeout=10)
+            assert not waiter.is_alive(), f"group {group} never consumed"
+            time.sleep(0.02)
+            loaded_at.append(time.monotonic())
+            pipeline.mark_loaded(group)
+    finally:
+        pipeline.close()
+    assert {k: v for k, _, v in sink} == payloads
+    order = [int(k[1]) for k, _, _ in sink]
+    assert order == sorted(order)
+    for key, at, _ in sink:
+        g = int(key[1])
+        if g:
+            before = g - 1 if g < 2 else g  # g2 and g3 sit behind the empty group
+            assert at >= loaded_at[before], (key, g)
+    assert not pipeline._thread.is_alive()
+    # no wake-up was lost: the pipeline's own timeout is 5 s
+    assert time.monotonic() - began < 3.0
+    assert pipeline.read_ahead_s >= 0 and pipeline.read_ahead_bytes <= 1500

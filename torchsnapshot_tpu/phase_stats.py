@@ -288,15 +288,18 @@ def add(
             pass  # telemetry must never break the pipeline
 
 
-def add_counter(name: str, seconds: float) -> None:
-    """Record seconds that belong to no interval (``restore_unattributed``:
-    what is left of a call once every phase's interval is taken out).  The
-    entry has ``s`` and ``n`` and no ``wall``, and reaches neither hook, so
-    it can name no gap of a trace; ``delta()`` differences it like any
-    other."""
+def add_counter(name: str, seconds: float, nbytes: int = 0) -> None:
+    """Record seconds (and bytes) that belong to no interval
+    (``restore_unattributed``: what is left of a call once every phase's
+    interval is taken out; ``read_ahead``: what a restore's pipeline read
+    before the loader was ready for it, a sum over stretches that the reads'
+    own phases already draw).  The entry has ``s``, ``bytes`` and ``n`` and
+    no ``wall``, and reaches neither hook, so it can name no gap of a trace;
+    ``delta()`` differences it like any other."""
     with _lock:
         slot = _stats.setdefault(name, {"s": 0.0, "bytes": 0, "n": 0})
         slot["s"] += seconds
+        slot["bytes"] += nbytes
         slot["n"] += 1
 
 
@@ -373,7 +376,8 @@ def _merge(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
     return merged
 
 
-def _union_s(intervals: List[Tuple[float, float]]) -> float:
+def union_s(intervals: List[Tuple[float, float]]) -> float:
+    """Seconds covered by at least one of ``intervals``."""
     return sum(end - begin for begin, end in _merge(intervals))
 
 
@@ -381,7 +385,7 @@ def snapshot() -> Dict[str, Dict[str, float]]:
     with _lock:
         out = {k: dict(v) for k, v in _stats.items()}
         for phase, ivs in _intervals.items():
-            out[phase]["wall"] = _wall_base.get(phase, 0.0) + _union_s(ivs)
+            out[phase]["wall"] = _wall_base.get(phase, 0.0) + union_s(ivs)
     return out
 
 
@@ -408,7 +412,7 @@ def attributed_wall_s(
     under-count after that."""
     with _lock:
         ivs = [iv for lst in _intervals.values() for iv in lst]
-    return _union_s(_clipped(ivs, begin, end))
+    return union_s(_clipped(ivs, begin, end))
 
 
 def walls_between(begin: float, end: float) -> Dict[str, float]:
@@ -422,7 +426,7 @@ def walls_between(begin: float, end: float) -> Dict[str, float]:
     for phase, ivs in live.items():
         clipped = _clipped(ivs, begin, end)
         if clipped:
-            out[phase] = _union_s(clipped)
+            out[phase] = union_s(clipped)
     return out
 
 

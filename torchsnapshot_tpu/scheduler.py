@@ -24,24 +24,30 @@ may resume (and donate/overwrite device buffers) because all bytes are in
 host memory.
 
 Read path mirrors it: io → consuming, with budget-gated read admission
-(reference scheduler.py:386-447).
+(reference scheduler.py:386-447), over ordered groups of requests: a restore
+hands in one group a stateful and loads them on its own thread while the
+pipeline, on a thread of its own (``ReadAhead``), reads the next group ahead
+and consumes nothing ahead (``execute_read_reqs``).
 
 Unlike the reference we never monkey-patch a nested event loop
 (asyncio_utils.py:13-153): pipelines run on a dedicated loop owned by the
-caller thread, and ``PendingIOWork.sync_complete`` may be driven from a
+caller thread (a restore's read pipeline: by the thread ``ReadAhead``
+starts), and ``PendingIOWork.sync_complete`` may be driven from a
 background thread (no collectives there — store-based barriers only).
 """
 
 from __future__ import annotations
 
 import asyncio
+import contextlib
+import contextvars
 import logging
 import socket
 import threading
 import time
 from collections import deque
 from concurrent.futures import Executor, ThreadPoolExecutor
-from typing import Awaitable, Callable, List, Optional
+from typing import Awaitable, Callable, List, Optional, Sequence, Tuple
 
 import psutil
 
@@ -799,9 +805,12 @@ async def _run_with_loop(
 class _ReadPipeline:
     """(reference scheduler.py:359-384)"""
 
-    def __init__(self, read_req: ReadReq, storage: StoragePlugin) -> None:
+    def __init__(
+        self, read_req: ReadReq, storage: StoragePlugin, group: int = 0
+    ) -> None:
         self.read_req = read_req
         self.storage = storage
+        self.group = group
         self.consuming_cost = read_req.buffer_consumer.get_consuming_cost_bytes()
         self.buf: Optional[bytearray] = None
         self.hash64: Optional[int] = None
@@ -846,24 +855,213 @@ class _ReadPipeline:
         return self
 
 
+class ReadAhead:
+    """One read pipeline for a whole restore, on a thread of its own, and
+    what it and the thread that loads tell each other.
+
+    The groups are the statefuls' read requests in the order they are
+    loaded.  The thread that makes this object (the one that called
+    ``restore``) is the loader: for each group k it calls
+    ``wait_consumed(k)``, does what only it may do (the H2D drain, user
+    code, the release of k's host buffers) and then ``mark_loaded(k)``.
+    The pipeline's thread runs ``execute_read_reqs`` over every group on a
+    loop of its own and is meanwhile reading group k+1.  ``close()``
+    (always, from a ``finally``) cancels what is still in flight and joins
+    the thread.
+
+    After ``close()``, ``read_ahead_s`` and ``read_ahead_bytes`` say how
+    much was read ahead: see ``_read_ahead``."""
+
+    def __init__(
+        self,
+        read_groups: Sequence[List[ReadReq]],
+        storage: StoragePlugin,
+        memory_budget_bytes: int,
+        rank: int,
+    ) -> None:
+        self._cond = threading.Condition()
+        self._consumed = 0  # groups whose last consume has finished
+        self._loaded_at: List[float] = []  # when each group's load returned
+        self._error: Optional[BaseException] = None
+        self._finished = False
+        self._cancelled = False
+        self._loop = asyncio.new_event_loop()
+        # Of the pipeline, while it runs: its task, for close(), and the
+        # event mark_loaded() sets.  Both touched under _cond only, so
+        # neither is used once the loop has closed.
+        self._task: Optional["asyncio.Task"] = None
+        self._wake: Optional[asyncio.Event] = None
+        self.read_ahead_s = 0.0
+        self.read_ahead_bytes = 0
+        # The coroutine, not the groups, goes to the thread: it drops its
+        # own reference to the requests once they are queued, so nothing
+        # here keeps a loaded group's host buffers alive.
+        coro = execute_read_reqs(
+            read_groups, storage, memory_budget_bytes, rank, loader=self
+        )
+        # The loader's phase tag (the op's driver tag), so that executor
+        # workers started from the pipeline's thread sample as the op's.
+        tag = phase_stats.current_phase() or phase_stats.thread_phases().get(
+            threading.get_ident()
+        )
+        self._thread = threading.Thread(
+            # The loader's context: spans opened on the pipeline's thread
+            # hang off the span that is open here.
+            target=contextvars.copy_context().run,
+            args=(self._run, coro, tag),
+            name="tpusnap-read-pipeline",
+            daemon=True,
+        )
+        self._thread.start()
+
+    # ------------------------------------------------- the loader's thread
+
+    def wait_consumed(self, group: int) -> None:
+        """Block until the last consume of ``group`` has finished; raise
+        the pipeline's error if it ended before that."""
+        with self._cond:
+            while self._consumed <= group and not self._finished:
+                self._cond.wait()
+            if self._consumed <= group:
+                raise self._error or RuntimeError(
+                    f"read pipeline ended before group {group} was consumed"
+                )
+
+    def mark_loaded(self, group: int) -> None:
+        """``group`` is loaded and its host buffers are released: its
+        successor may be consumed, and the group after that read."""
+        with self._cond:
+            assert group == len(self._loaded_at), (group, len(self._loaded_at))
+            self._loaded_at.append(time.monotonic())
+            if self._wake is not None:
+                self._loop.call_soon_threadsafe(self._wake.set)
+
+    def close(self) -> None:
+        """Cancel the pipeline if it is still running, and join its thread
+        (no-op after a pipeline that ran to its end)."""
+        with self._cond:
+            self._cancelled = True
+            if self._task is not None:
+                self._loop.call_soon_threadsafe(self._task.cancel)
+        self._thread.join()
+
+    # ----------------------------------------------- the pipeline's thread
+
+    def _run(self, coro: Awaitable[None], tag: Optional[str]) -> None:
+        try:
+            with phase_stats.tagged(tag) if tag else contextlib.nullcontext():
+                self._loop.run_until_complete(coro)
+        except BaseException as e:  # noqa: BLE001
+            # Raised again on the loader's thread (wait_consumed); a
+            # cancellation is close()'s own.
+            with self._cond:
+                self._error = e
+        finally:
+            with self._cond:
+                self._task = self._wake = None
+                self._finished = True
+                self._cond.notify_all()
+            self._loop.close()
+
+    def _attach(self) -> asyncio.Event:
+        with self._cond:
+            if self._cancelled:
+                raise asyncio.CancelledError()
+            self._task = asyncio.current_task()
+            self._wake = asyncio.Event()
+            return self._wake
+
+    def _group_consumed(self, consumed: int) -> None:
+        with self._cond:
+            self._consumed = consumed
+            self._cond.notify_all()
+
+    def _loaded(self) -> List[float]:
+        with self._cond:
+            return list(self._loaded_at)
+
+
+def _read_ahead(
+    reads: List[List[Tuple[float, float, int]]], loaded_at: List[float]
+) -> Tuple[float, int]:
+    """What was read ahead: of each group's reads ``(begin, end, bytes)``,
+    the part that ran before the load of the group before it returned, as
+    seconds (the union of the reads' intervals) and bytes (a read still
+    running then counts the share of its bytes that its time gives), summed
+    over the groups."""
+    seconds, nbytes = 0.0, 0.0
+    for group, until in zip(reads[1:], loaded_at):
+        ahead = [(b, e, n) for b, e, n in group if b < until]
+        seconds += phase_stats.union_s([(b, min(e, until)) for b, e, _ in ahead])
+        nbytes += sum(
+            n if e <= until else n * (until - b) / (e - b) for b, e, n in ahead
+        )
+    return seconds, int(nbytes)
+
+
 async def execute_read_reqs(
-    read_reqs: List[ReadReq],
+    read_groups: Sequence[List[ReadReq]],
     storage: StoragePlugin,
     memory_budget_bytes: int,
     rank: int,
+    loader: Optional[ReadAhead] = None,
 ) -> None:
-    """Budget-gated read → consume pipeline (reference scheduler.py:386-447)."""
+    """Budget-gated read → consume pipeline (reference scheduler.py:386-447)
+    over ordered groups of requests: one loop, one executor, one memory
+    budget and one set of io slots for all of them.
+
+    A group is what one stateful of a restore reads; ``read_object`` and
+    the like pass one.  Reads are dispatched in group order.  With a
+    ``loader`` (``ReadAhead``: the thread that called ``restore``) the
+    pipeline reads ahead of it by one group and consumes ahead of it by
+    none:
+
+    - the reads of group k+1 start when the last read of group k has
+      finished, so storage is driven through k's tail (its last consumes,
+      the loader's H2D drain, ``load_state_dict`` and the release of its
+      host buffers).  Reading ahead of the loader is safe: a committed
+      snapshot is immutable.  They start no sooner, because reads in
+      flight share the storage's rate: read beside k, group k+1 only
+      delays k's last read and so its own H2D.
+    - no consume of group k+1 (checksum, H2D submit, sharded
+      ``device_put``) starts before the loader has loaded group k.  Until
+      then k's restore target is alive on the device, and k+1's arrays
+      landing beside it would raise the restore's HBM peak.  A read that
+      finishes early is parked with its bytes debited from the budget.
+    - no read of group k+2 starts before group k is loaded and its host
+      buffers released, so at most two groups' buffers are resident.
+
+    With no loader a group counts as loaded once it is consumed.  An error
+    in any read or consume cancels everything in flight and is raised."""
+    all_reqs = [rr for group in read_groups for rr in group]
     executor = _PhaseInheritingExecutor(
-        max_workers=_read_executor_workers(read_reqs)
+        max_workers=_read_executor_workers(all_reqs)
     )
-    _count_dispatched("read", len(read_reqs))
+    _count_dispatched("read", len(all_reqs))
     budget = _BudgetTracker(memory_budget_bytes)
-    ready_for_io: deque[_ReadPipeline] = deque(
-        sorted(
-            (_ReadPipeline(rr, storage) for rr in read_reqs),
-            key=lambda p: p.consuming_cost,
+    ready_for_io: List[deque[_ReadPipeline]] = [
+        deque(
+            sorted(
+                (_ReadPipeline(rr, storage, k) for rr in group),
+                key=lambda p: p.consuming_cost,
+            )
         )
-    )
+        for k, group in enumerate(read_groups)
+    ]
+    n_groups = len(ready_for_io)
+    n_reqs = len(all_reqs)
+    # From here the queues own the requests: a loaded group's host buffers
+    # die with the loader's own references (Snapshot._load_stateful).
+    del read_groups, all_reqs
+    unread = [len(queue) for queue in ready_for_io]
+    unconsumed = list(unread)
+    # (begin, end, bytes) of each finished read, by group: the read-ahead
+    # counter's source.
+    reads: List[List[Tuple[float, float, int]]] = [[] for _ in range(n_groups)]
+    # Read, and waiting for the group before theirs to be loaded.
+    parked: List[_ReadPipeline] = []
+    consumed = 0  # leading groups whose last consume has finished
+    loaded = 0  # leading groups the loader has loaded and released
     io_cap = knobs.get_max_per_rank_io_concurrency()
     io_semaphore = asyncio.Semaphore(io_cap)
     io_tasks: set = set()
@@ -871,11 +1069,11 @@ async def execute_read_reqs(
     # task -> pipeline, for re-crediting un-consumed pipelines on failure
     pipelines: dict = {}
     reporter = _ProgressReporter(
-        rank=rank, total=len(read_reqs), verb="read", budget=budget
+        rank=rank, total=n_reqs, verb="read", budget=budget
     )
     reporter.debug_refs = {
         "ready_for_io": lambda: [
-            p.read_req.path for p in list(ready_for_io)
+            p.read_req.path for queue in list(ready_for_io) for p in list(queue)
         ],
         "inflight": lambda: [
             p.read_req.path
@@ -900,7 +1098,12 @@ async def execute_read_reqs(
                 slot_wait = phase_stats.open_interval("io_slot_wait")
                 async with io_semaphore:
                     slot_wait.close(min_s=0.001)
-                    return await pipeline.read_buffer()
+                    begin = time.monotonic()
+                    await pipeline.read_buffer()
+                    reads[pipeline.group].append(
+                        (begin, time.monotonic(), _buf_nbytes(pipeline.buf))
+                    )
+                    return pipeline
             except asyncio.CancelledError:
                 raise
             except Exception as e:  # noqa: BLE001
@@ -931,13 +1134,28 @@ async def execute_read_reqs(
                 )
                 await asyncio.sleep(retry_policy.backoff_s(attempt))
 
+    def next_for_io() -> Optional["deque[_ReadPipeline]"]:
+        # Group order, and a look-ahead of one group: the group being
+        # loaded next, and the one after it once the last read of the first
+        # has finished.  Not sooner: reads in flight share the storage's
+        # rate, so a group read beside the one before it delays that one's
+        # last read, with it its load, and with that (nothing is consumed
+        # ahead) its own H2D: the two then finish together and leave one
+        # tail twice as long where there were two.
+        for k in range(loaded, min(loaded + 2, n_groups)):
+            if ready_for_io[k]:
+                return ready_for_io[k]
+            if unread[k]:
+                return None
+        return None
+
     def dispatch_io() -> None:
-        while ready_for_io:
-            pipeline = ready_for_io[0]
+        while (queue := next_for_io()) is not None:
+            pipeline = queue[0]
             if pipeline.consuming_cost <= budget.remaining or (
                 budget.inflight == 0 and not io_tasks and not consume_tasks
             ):
-                ready_for_io.popleft()
+                queue.popleft()
                 budget.remaining -= pipeline.consuming_cost
                 budget.inflight += 1
                 task = asyncio.ensure_future(_read(pipeline))
@@ -946,66 +1164,112 @@ async def execute_read_reqs(
             else:
                 break
 
+    def consume(pipeline: _ReadPipeline) -> None:
+        task = asyncio.ensure_future(pipeline.consume_buffer(executor))
+        consume_tasks.add(task)
+        pipelines[task] = pipeline
+
+    def note_progress() -> None:
+        """Groups consumed are told to the loader; groups it has loaded
+        let the parked reads of the next one be consumed."""
+        nonlocal consumed, loaded
+        told = consumed
+        while consumed < n_groups and unconsumed[consumed] == 0:
+            consumed += 1
+        if loader is None:
+            loaded = consumed
+        else:
+            if consumed != told:
+                loader._group_consumed(consumed)
+            loaded = len(loader._loaded())
+        for pipeline in [p for p in parked if p.group <= loaded]:
+            parked.remove(pipeline)
+            consume(pipeline)
+
     # read_starved: one interval per stretch in which the pipeline is alive
-    # (a consume is pending) and no read is in flight, so storage is not
-    # being driven.  The tail after the last read is always one.
+    # (a consume is pending: running, or parked behind the loader) and no
+    # read is in flight, so storage is not being driven.  With groups read
+    # ahead it opens only where the next group has nothing left to read;
+    # the tail after the last read is always one.
     starved: Optional[phase_stats.open_interval] = None
 
     def track_starved() -> None:
         nonlocal starved
-        if consume_tasks and not io_tasks:
+        if (consume_tasks or parked) and not io_tasks:
             if starved is None:
                 starved = phase_stats.open_interval("read_starved")
         elif starved is not None:
             starved.close()
             starved = None
 
-    read_span = ttrace.span("read_pipeline", cat="scheduler", n_reqs=len(read_reqs))
+    read_span = ttrace.span(
+        "read_pipeline", cat="scheduler", n_reqs=n_reqs, n_groups=n_groups
+    )
     read_span.__enter__()
+    wake = loader._attach() if loader is not None else None
+    wake_task: Optional["asyncio.Task"] = None
     try:
+        note_progress()  # leading groups with nothing to read
         dispatch_io()
-        while io_tasks or consume_tasks:
+        while consumed < n_groups:
+            if wake is not None and wake_task is None:
+                wake_task = asyncio.ensure_future(wake.wait())
             # Mirror of the write path's budget_wait attribution: the
             # consuming budget is binding only when the queue head is
             # inadmissible WHILE read slots sit idle — a head queued
             # behind saturated storage is storage-bound, not budget-bound.
-            budget_bound = bool(ready_for_io) and len(io_tasks) < io_cap
+            budget_bound = next_for_io() is not None and len(io_tasks) < io_cap
             blocked = (
                 phase_stats.open_interval("budget_wait") if budget_bound else None
             )
+            waiting = io_tasks | consume_tasks
+            if wake_task is not None:
+                waiting.add(wake_task)
             done, _ = await asyncio.wait(
-                io_tasks | consume_tasks,
+                waiting,
                 timeout=reporter._interval_s or None,
                 return_when=asyncio.FIRST_COMPLETED,
             )
             if blocked is not None:
                 blocked.close()
             for task in done:
-                if task in io_tasks:
+                if task is wake_task:
+                    wake.clear()
+                    wake_task = None
+                elif task in io_tasks:
                     io_tasks.discard(task)
                     pipeline = task.result()  # raises on storage failure
                     pipelines.pop(task)
-                    consume_task = asyncio.ensure_future(
-                        pipeline.consume_buffer(executor)
-                    )
-                    consume_tasks.add(consume_task)
-                    pipelines[consume_task] = pipeline
+                    unread[pipeline.group] -= 1
+                    if pipeline.group <= loaded:
+                        consume(pipeline)
+                    else:
+                        parked.append(pipeline)
                 else:
                     consume_tasks.discard(task)
                     pipeline = task.result()  # raises on consume failure
                     pipelines.pop(task)
                     budget.remaining += pipeline.consuming_cost
                     budget.inflight -= 1
+                    unconsumed[pipeline.group] -= 1
                     reporter.io_done += 1
                     reporter.bytes_done += pipeline.consuming_cost
                     tmetrics.record_io_bytes("read", pipeline.consuming_cost)
+            # A consumed request is the loader's alone from here: no local
+            # of this frame keeps it (and its host buffer) past the turn.
+            done = waiting = task = pipeline = None
+            note_progress()
             dispatch_io()
             track_starved()
             reporter.maybe_report(
                 budget,
-                pending=len(ready_for_io),
+                pending=sum(len(queue) for queue in ready_for_io),
                 staging=len(io_tasks),
-                inflight_io=len(consume_tasks),
+                inflight_io=len(consume_tasks) + len(parked),
+            )
+        if loader is not None:
+            loader.read_ahead_s, loader.read_ahead_bytes = _read_ahead(
+                reads, loader._loaded()
             )
         read_span.__exit__(None, None, None)
     except BaseException:
@@ -1023,12 +1287,14 @@ async def execute_read_reqs(
             await asyncio.gather(
                 *io_tasks, *consume_tasks, return_exceptions=True
             )
-        for pipeline in pipelines.values():
+        for pipeline in [*pipelines.values(), *parked]:
             pipeline.buf = None
             budget.remaining += pipeline.consuming_cost
             budget.inflight -= 1
         raise
     finally:
+        if wake_task is not None:
+            wake_task.cancel()
         executor.shutdown()
         # Success or error, the read pipeline is over: zero its gauges.
         tmetrics.record_scheduler_idle("read")
@@ -1040,7 +1306,8 @@ def sync_execute_read_reqs(
     memory_budget_bytes: int,
     rank: int,
 ) -> None:
-    """(reference scheduler.py:449-463)"""
+    """The read pipeline over one group, on the calling thread (reference
+    scheduler.py:449-463)."""
     from .utils.loops import call_outside_loop
 
     call_outside_loop(
@@ -1057,7 +1324,7 @@ def _sync_execute_read_reqs_impl(
     loop = asyncio.new_event_loop()
     try:
         loop.run_until_complete(
-            execute_read_reqs(read_reqs, storage, memory_budget_bytes, rank)
+            execute_read_reqs([read_reqs], storage, memory_budget_bytes, rank)
         )
     finally:
         loop.close()
@@ -1104,9 +1371,15 @@ class _ProgressReporter:
         self._interval_s = knobs.get_progress_interval_s()
         self._last = time.monotonic()
         self._begin = self._last
+        # One handle for the pipeline's life: a psutil.Process() reads the
+        # process's stat file anew each time it is made, and with one read
+        # pipeline a restore a reporter now lives long enough to report
+        # from the pipeline's own thread.
         try:
-            self._rss_base = psutil.Process().memory_info().rss
+            self._process: Optional[psutil.Process] = psutil.Process()
+            self._rss_base = self._process.memory_info().rss
         except Exception:
+            self._process = None
             self._rss_base = None
         tmonitor.attach_reporter(self)
 
@@ -1144,9 +1417,11 @@ class _ProgressReporter:
         if now - self._last < self._interval_s:
             return
         self._last = now
-        if self._rss_base is not None:
+        if not logger.isEnabledFor(logging.INFO):
+            return
+        if self._process is not None:
             try:
-                rss_delta = psutil.Process().memory_info().rss - self._rss_base
+                rss_delta = self._process.memory_info().rss - self._rss_base
                 rss_str = f"{rss_delta / 1e6:+.0f}MB"
             except Exception:
                 rss_str = "?"

@@ -25,6 +25,7 @@ over the KV-store coordination layer (pg_wrapper) instead of c10d.
 
 from __future__ import annotations
 
+import dataclasses
 import fnmatch
 import logging
 import random
@@ -66,6 +67,7 @@ from .rng_state import RNGState
 from .scheduler import (
     DeferredIOWork,
     PendingIOWork,
+    ReadAhead,
     get_process_memory_budget_bytes,
     sync_execute_read_reqs,
     sync_execute_write_reqs,
@@ -549,6 +551,30 @@ class Snapshot:
         ``load_state_dict`` accepts it (reference :775-778) — useful for
         partial restores into modules with extra/missing keys.
 
+        One read pipeline serves the whole call (``scheduler.ReadAhead``).
+        Every stateful is planned first, in the order they are loaded
+        (``state_dict()`` of each, on this thread), and the pipeline, on a
+        thread of its own, reads them in that order under one memory budget
+        and one set of io slots.  This thread loads: for each stateful it
+        waits for the last consume, drains the H2D batcher, calls
+        ``load_state_dict``, releases the stateful's host buffers and passes
+        the per-key barrier, RNG state last, as ever.
+
+        What is **read ahead**: the next stateful's storage reads start when
+        the last read of this one has finished, and run while its last
+        consumes, its H2D drain, its load and the release of its buffers go
+        on, so storage is driven through every stateful's tail but the last.
+        Reading ahead of a barrier is safe: a committed snapshot is
+        immutable.  What is **never consumed ahead**: no checksum, H2D
+        submit or sharded ``device_put`` of stateful k+1 starts before k is
+        loaded, because until ``load_state_dict`` of k has returned k's
+        restore target is alive on the device and the restore's HBM peak
+        (1.333 x state with the state split four ways) would rise; and no
+        read of k+2 starts before k's host buffers are released, so host
+        memory holds two statefuls' bytes at the most.  An in-place numpy
+        target of k+1 is filled as its reads arrive, as within a stateful;
+        no user code runs ahead.
+
         On-device contract: dense and chunked array uploads are drained
         before return (H2DBatcher.drain — their bytes are ON DEVICE, with
         the landing wall attributed to ``h2d_land``).  **Sharded-array
@@ -558,9 +584,10 @@ class Snapshot:
         before proceeding should ``jax.block_until_ready`` it (the usual
         first collective does this implicitly)."""
         # restore_open, first half: from entry to the metadata read.  The
-        # driver phases (restore_open here; plan_read, h2d_drain and
+        # driver phases (restore_open and plan_read here; h2d_drain and
         # load_state in _load_stateful) are leaves: none encloses a storage
-        # read, which stays the name of the time it takes.
+        # read of its own stateful, which stays the name of the time it
+        # takes; the next stateful's reads run under them by design.
         opening = phase_stats.open_interval("restore_open")
         begin = opening.begin
         self._validate_app_state(app_state)
@@ -622,30 +649,46 @@ class Snapshot:
                 # Coverage of global_keys was verified symmetrically by
                 # _gather_keys — a rank-local missing-key raise inside
                 # this barrier loop would deadlock peers mid-iteration.
-                for key in global_keys:
-                    with ttrace.span("load_stateful", key=key):
-                        self._load_stateful(
-                            stateful_key=key,
-                            stateful=app_state[key],
-                            metadata=metadata,
-                            storage=storage,
-                            memory_budget_bytes=memory_budget_bytes,
-                            pg=pg,
-                            strict=strict,
-                        )
-                    pg.barrier()
                 # RNG restored last so nothing later perturbs it (reference
-                # :371-381).
+                # :371-381), and behind no barrier of its own.
+                keyed = [(key, app_state[key]) for key in global_keys]
                 if rng_state_item is not None:
-                    key, stateful = rng_state_item
-                    self._load_stateful(
-                        stateful_key=key,
-                        stateful=stateful,
-                        metadata=metadata,
-                        storage=storage,
-                        memory_budget_bytes=memory_budget_bytes,
-                        pg=pg,
+                    keyed.append(rng_state_item)
+                plans: List[Optional[_StatefulPlan]] = []
+                try:
+                    for key, stateful in keyed:
+                        with phase_stats.timed("plan_read"):
+                            plans.append(
+                                self._plan_stateful_reads(
+                                    key, stateful, metadata, rank
+                                )
+                            )
+                    pipeline = ReadAhead(
+                        [plan.read_reqs if plan else [] for plan in plans],
+                        storage,
+                        memory_budget_bytes,
+                        rank,
                     )
+                    try:
+                        for group, plan in enumerate(plans):
+                            if plan is None:
+                                pipeline.mark_loaded(group)
+                            else:
+                                with ttrace.span("load_stateful", key=plan.key):
+                                    self._load_stateful(
+                                        group, plan, pipeline, strict
+                                    )
+                            if group < len(global_keys):
+                                pg.barrier()
+                    finally:
+                        pipeline.close()
+                finally:
+                    # Idempotent after a drain; on an abort it stops each
+                    # lander thread (a long-lived trainer must not leak one
+                    # parked thread per failed restore).
+                    for plan in plans:
+                        if plan is not None:
+                            plan.h2d_batch.shutdown()
                 phases_delta = phase_stats.delta(phases_before)
                 if tsidecar.enabled():
                     extra = {
@@ -678,9 +721,16 @@ class Snapshot:
                 0.0, end - begin - phase_stats.attributed_wall_s(begin, end)
             )
             phase_stats.add_counter("restore_unattributed", unattributed_s)
+            # What the pipeline read ahead of this thread: a counter too,
+            # since the reads' own phases already draw those stretches.
+            phase_stats.add_counter(
+                "read_ahead", pipeline.read_ahead_s, pipeline.read_ahead_bytes
+            )
             event_metadata["duration_s"] = end - begin
             event_metadata["phases"] = phase_stats.walls_between(begin, end)
             event_metadata["unattributed_s"] = unattributed_s
+            event_metadata["read_ahead_s"] = pipeline.read_ahead_s
+            event_metadata["read_ahead_bytes"] = pipeline.read_ahead_bytes
             event_metadata["bytes"] = int(
                 max(
                     (v.get("bytes", 0) for v in phases_delta.values()),
@@ -701,52 +751,31 @@ class Snapshot:
         finally:
             release_op_lease(lease)
 
+    @staticmethod
     def _load_stateful(
-        self,
-        stateful_key: str,
-        stateful: Stateful,
-        metadata: SnapshotMetadata,
-        storage: StoragePlugin,
-        memory_budget_bytes: int,
-        pg: PGWrapper,
-        strict: bool = True,
+        group: int, plan: "_StatefulPlan", pipeline: ReadAhead, strict: bool
     ) -> None:
-        rank = pg.get_rank()
-        with phase_stats.timed("plan_read"):
-            plan = self._plan_stateful_reads(stateful_key, stateful, metadata, rank)
-        if plan is None:
-            return
-        read_reqs, futures, container_entries, h2d_batch = plan
-        del plan
-        try:
-            sync_execute_read_reqs(
-                read_reqs=read_reqs,
-                storage=storage,
-                memory_budget_bytes=memory_budget_bytes,
-                rank=rank,
-            )
-            # Flush the tail AND wait for every H2D transfer to land:
-            # restore's contract is "dense/chunked state is on device when
-            # we return", and the landing time belongs to restore's own
-            # phase record (h2d_land), not to whatever the caller happens
-            # to block on next (r04 verdict: 159 s of restore wall
-            # invisible to every phase).  Sharded-array uploads do NOT go
-            # through this batcher (io_preparer.prepare_read) and stay in
-            # flight by design — see restore()'s docstring.  h2d_drain is
-            # the driver's wait for that tail: what of H2D no read hides.
-            with phase_stats.timed("h2d_drain"):
-                h2d_batch.drain()
-        finally:
-            # Idempotent after drain; on a pipeline abort it stops the
-            # lander thread (a long-lived trainer must not leak one parked
-            # thread per failed restore).
-            h2d_batch.shutdown()
-
+        """The loader's part of one stateful, on the thread that called
+        ``restore``: everything after its last consume."""
+        pipeline.wait_consumed(group)
+        # Flush the tail AND wait for every H2D transfer to land:
+        # restore's contract is "dense/chunked state is on device when
+        # we return", and the landing time belongs to restore's own
+        # phase record (h2d_land), not to whatever the caller happens
+        # to block on next (r04 verdict: 159 s of restore wall
+        # invisible to every phase).  Sharded-array uploads do NOT go
+        # through this batcher (io_preparer.prepare_read) and stay in
+        # flight by design — see restore()'s docstring.  h2d_drain is
+        # this thread's wait for that tail; the next stateful's reads run
+        # under it.
+        with phase_stats.timed("h2d_drain"):
+            plan.h2d_batch.drain()
         with phase_stats.timed("load_state"):
-            resolved = {path: fut.obj for path, fut in futures.items()}
+            resolved = {path: fut.obj for path, fut in plan.futures.items()}
             restored_state_dict = inflate(
-                container_entries, resolved, prefix=stateful_key
+                plan.container_entries, resolved, prefix=plan.key
             )
+            stateful = plan.stateful
             if not strict and _accepts_strict(stateful):
                 stateful.load_state_dict(restored_state_dict, strict=False)  # type: ignore[call-arg]
             else:
@@ -755,7 +784,13 @@ class Snapshot:
             # and not in the return after it: the read requests own the
             # host buffers, as many bytes as were restored, and unmapping
             # them is the larger part of this phase (PERF.md section 5).
-            del read_reqs, futures, resolved, restored_state_dict
+            # The pipeline dropped its references as each was consumed.
+            plan.read_reqs.clear()
+            plan.futures.clear()
+            del resolved, restored_state_dict
+        # Only now may the next stateful's arrays land on the device, and
+        # the one after it be read.
+        pipeline.mark_loaded(group)
 
     @staticmethod
     def _plan_stateful_reads(
@@ -763,11 +798,9 @@ class Snapshot:
         stateful: Stateful,
         metadata: SnapshotMetadata,
         rank: int,
-    ) -> Optional[Tuple[List[ReadReq], Dict[str, Future], Manifest, Any]]:
-        """The ``plan_read`` phase of one stateful: its read requests (host
-        buffers included), the future of every entry, its container
-        entries and the H2D batcher the requests feed; None where the
-        snapshot holds nothing for it."""
+    ) -> Optional["_StatefulPlan"]:
+        """The ``plan_read`` phase of one stateful; None where the snapshot
+        holds nothing for it."""
         local_manifest, merged_entries = get_manifest_for_rank(metadata, rank)
 
         # Current state dict provides in-place restore targets, avoiding 2x
@@ -825,7 +858,9 @@ class Snapshot:
             h2d_batch.shutdown()
             raise
         tmetrics.record_entries("restore", len(sub_manifest))
-        return read_reqs, futures, container_entries, h2d_batch
+        return _StatefulPlan(
+            stateful_key, stateful, read_reqs, futures, container_entries, h2d_batch
+        )
 
     # ----------------------------------------------------------- read_object
 
@@ -1234,6 +1269,21 @@ class Snapshot:
             obj_list[0] = global_manifest
         pg.broadcast_object_list(obj_list, src=0)
         return obj_list[0]
+
+
+@dataclasses.dataclass
+class _StatefulPlan:
+    """What ``plan_read`` makes of one stateful: its read requests (host
+    buffers included; one group of the restore's read pipeline), the future
+    of every entry, its container entries and the H2D batcher the requests
+    feed."""
+
+    key: str
+    stateful: Stateful
+    read_reqs: List[ReadReq]
+    futures: Dict[str, Future]
+    container_entries: Manifest
+    h2d_batch: Any
 
 
 class _ManifestFinalizer:
