@@ -451,6 +451,7 @@ def test_h2d_batcher_incremental_flush():
     b.flush()
     np.testing.assert_array_equal(np.asarray(f1.obj), np.arange(16))
     np.testing.assert_array_equal(np.asarray(f2.obj), np.arange(16) * 2)
+    b.shutdown()  # no parked lander left for the tests that count threads
 
 
 def test_h2d_batcher_dtype_cast():
@@ -464,6 +465,7 @@ def test_h2d_batcher_dtype_cast():
     b.flush()
     assert f.obj.dtype == jnp.bfloat16
     np.testing.assert_array_equal(np.asarray(f.obj, dtype=np.float32), np.arange(8))
+    b.shutdown()  # no parked lander left for the tests that count threads
 
 
 def test_h2d_batcher_drain_lands_and_attributes():
@@ -593,3 +595,4 @@ def test_h2d_batcher_mixed_targets():
     np.testing.assert_array_equal(np.asarray(f1.obj), np.ones((8, 4)))
     assert f1.obj.sharding.is_equivalent_to(sharded_like.sharding, 2)
     np.testing.assert_array_equal(np.asarray(f2.obj), np.full(8, 3.0))
+    b.shutdown()  # no parked lander left for the tests that count threads

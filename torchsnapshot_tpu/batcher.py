@@ -30,7 +30,7 @@ from collections import defaultdict
 from concurrent.futures import Executor
 from typing import Dict, List, Optional, Tuple
 
-from . import knobs, serialization
+from . import knobs, phase_stats, serialization
 from .compression import is_framed
 from .telemetry import trace as ttrace
 from .io_preparers.array import ArrayBufferStager
@@ -51,6 +51,9 @@ from .manifest import (
 from .serialization import Serializer
 
 logger = logging.getLogger(__name__)
+
+# Where slab files live, relative to the snapshot's root.
+_SLAB_PREFIX = "batched/"
 
 
 def _index_tensor_entries(entries: Manifest) -> Dict[str, TensorEntry]:
@@ -140,7 +143,7 @@ def _batch_write_requests_impl(
         # payload under the slab threshold.  Member sets are disjoint
         # within one snapshot, so names cannot collide.
         member_key = "|".join(wr.path for wr, _, _ in slab).encode()
-        location = f"batched/{hashlib.sha1(member_key).hexdigest()[:24]}"
+        location = f"{_SLAB_PREFIX}{hashlib.sha1(member_key).hexdigest()[:24]}"
         offset = 0
         members: List[Tuple[BufferStager, int, int]] = []
         for wr, entry, nbytes in slab:
@@ -208,6 +211,9 @@ class BatchedBufferStager(BufferStager):
         member_sinks = [
             getattr(s, "hash_sinks", None) for s, _, _ in self._members
         ]
+        phase_stats.add_counter(
+            "slab_write", 0.0, self._total, members=len(self._members)
+        )
         scatter = ScatterBuffer(views)
         if self._scatter_ok:
             if all(sinks and len(sinks) == 1 for sinks in member_sinks):
@@ -277,22 +283,38 @@ def batch_read_requests(read_reqs: List[ReadReq]) -> List[ReadReq]:
 
     Tiled reads (``no_merge``) pass through untouched: they were split
     precisely to bound buffering, and they all target one location.
+
+    Only a member with no place of its own to land in is merged: one that
+    reads into its restore target (``into``, a megabyte and more) keeps a
+    ranged read of its own against the slab file.  The ``slab_read`` counter
+    says what a plan takes out of slab files either way: ``bytes`` and
+    ``members`` of the slab members read, ``reads`` issued for them once
+    merged, and ``merged`` bytes of those that go through a
+    :class:`BatchedBufferConsumer`.
     """
     max_gap = knobs.get_max_read_merge_gap_bytes()
     by_path: Dict[str, List[ReadReq]] = defaultdict(list)
     passthrough: List[ReadReq] = []
+    slab = {"bytes": 0, "members": 0, "reads": 0, "merged": 0}
     for rr in read_reqs:
+        from_slab = rr.byte_range is not None and rr.path.startswith(_SLAB_PREFIX)
+        if from_slab:
+            slab["bytes"] += rr.byte_range[1] - rr.byte_range[0]
+            slab["members"] += 1
         if rr.byte_range is not None and not rr.no_merge and rr.into is None:
             by_path[rr.path].append(rr)
         else:
             passthrough.append(rr)
+            slab["reads"] += from_slab
 
     out = passthrough
 
     def _flush_group(path: str, group: List[ReadReq]) -> None:
+        slab["reads"] += path.startswith(_SLAB_PREFIX)
         if len(group) == 1:
             out.append(group[0])
             return
+        slab["merged"] += sum(r.byte_range[1] - r.byte_range[0] for r in group)
         start = group[0].byte_range[0]
         end = max(r.byte_range[1] for r in group)
         members = [
@@ -321,6 +343,15 @@ def batch_read_requests(read_reqs: List[ReadReq]) -> List[ReadReq]:
             group_end = max(group_end, rr.byte_range[1])
         if group:
             _flush_group(path, group)
+    if slab["members"]:
+        phase_stats.add_counter(
+            "slab_read",
+            0.0,
+            slab["bytes"],
+            members=slab["members"],
+            reads=slab["reads"],
+            merged=slab["merged"],
+        )
     return out
 
 
@@ -335,12 +366,21 @@ class BatchedBufferConsumer(BufferConsumer):
         self, buf: BufferType, executor: Optional[Executor] = None
     ) -> None:
         view = memoryview(buf)
-        await asyncio.gather(
-            *(
-                consumer.consume_buffer(view[start:end], executor)
-                for start, end, consumer in self._members
+        # slab_scatter: from the merged read's arrival to its last member's
+        # consume, on the loop's thread across its turns (the members'
+        # checksum and consume_copy run under it, on the executor).
+        scatter = phase_stats.open_interval("slab_scatter")
+        try:
+            await asyncio.gather(
+                *(
+                    consumer.consume_buffer(view[start:end], executor)
+                    for start, end, consumer in self._members
+                )
             )
-        )
+        except BaseException:
+            scatter.drop()
+            raise
+        scatter.close(sum(end - start for start, end, _ in self._members))
 
     def get_consuming_cost_bytes(self) -> int:
         return self._total + sum(c.get_consuming_cost_bytes() for _, _, c in self._members)

@@ -59,6 +59,14 @@ _retired_hwm: Dict[str, float] = {}
 # payload) only stalls retirement for ITS phase — unrelated phases keep
 # compacting and their lists stay bounded.
 _active_begins: Dict[str, Dict[object, float]] = {}
+# begin timestamps of calls that will ask for their own account when they
+# end (``hold``; ``Snapshot.restore``): no interval ending after the earliest
+# of them is retired, in any phase, so ``attributed_wall_s`` and
+# ``walls_between`` over such a call see every interval it left, however
+# many.  A phase's list then outgrows the threshold for the length of the
+# call; ``_compact_at`` keeps the merge from running at every add meanwhile.
+_holds: Dict[object, float] = {}
+_compact_at: Dict[str, int] = {}
 
 
 # Compact a phase's interval list (exact union-merge) when it grows past
@@ -246,7 +254,7 @@ def add(
         # A fully-clamped interval (begin == end) union-sums to zero and
         # is appended anyway to keep "n" and interval counts aligned.
         ivs.append((begin, end))
-        if len(ivs) >= _COMPACT_THRESHOLD:
+        if len(ivs) >= _compact_at.get(phase, _COMPACT_THRESHOLD):
             merged = _merge(ivs)
             if len(merged) >= _COMPACT_THRESHOLD // 2:
                 # Exact merge couldn't shrink (disjoint intervals — e.g.
@@ -261,7 +269,8 @@ def add(
                 # for evenly spaced checkpoints.)
                 keep = _COMPACT_THRESHOLD // 4
                 low_water = min(
-                    _active_begins.get(phase, {}).values(), default=float("inf")
+                    (*_active_begins.get(phase, {}).values(), *_holds.values()),
+                    default=float("inf"),
                 )
                 retire_n = min(
                     len(merged) - keep,
@@ -274,6 +283,10 @@ def add(
                     )
                     _retired_hwm[phase] = retired[-1][1]
             _intervals[phase] = merged
+            # Merge again only once the list has doubled: one that cannot
+            # shrink (disjoint intervals under a hold or a running block)
+            # would otherwise be sorted at every add.
+            _compact_at[phase] = max(_COMPACT_THRESHOLD, 2 * len(merged))
     hook = _trace_hook
     if hook is not None:
         try:
@@ -288,19 +301,41 @@ def add(
             pass  # telemetry must never break the pipeline
 
 
-def add_counter(name: str, seconds: float, nbytes: int = 0) -> None:
+def add_counter(name: str, seconds: float, nbytes: int = 0, **more: int) -> None:
     """Record seconds (and bytes) that belong to no interval
     (``restore_unattributed``: what is left of a call once every phase's
     interval is taken out; ``read_ahead``: what a restore's pipeline read
     before the loader was ready for it, a sum over stretches that the reads'
-    own phases already draw).  The entry has ``s``, ``bytes`` and ``n`` and
-    no ``wall``, and reaches neither hook, so it can name no gap of a trace;
+    own phases already draw; ``slab_write``: one occurrence a slab file
+    staged, its bytes, and ``members=`` how many leaves it packs;
+    ``slab_read``: one occurrence a plan, the bytes it takes out of slab
+    files, and ``members=``, ``reads=``, ``merged=``, batcher.py).  The
+    entry has ``s``, ``bytes``, ``n`` and whatever ``more`` names, and no
+    ``wall``, and reaches neither hook, so it can name no gap of a trace;
     ``delta()`` differences it like any other."""
     with _lock:
         slot = _stats.setdefault(name, {"s": 0.0, "bytes": 0, "n": 0})
         slot["s"] += seconds
         slot["bytes"] += nbytes
         slot["n"] += 1
+        for key, value in more.items():
+            slot[key] = slot.get(key, 0) + value
+
+
+def hold(begin: float) -> object:
+    """Keep every interval that ends after ``begin`` (a ``time.monotonic``
+    stamp) out of compaction until ``release`` is given the token returned:
+    a call that will read ``attributed_wall_s`` or ``walls_between`` over
+    itself holds from its own beginning."""
+    token = object()
+    with _lock:
+        _holds[token] = begin
+    return token
+
+
+def release(token: object) -> None:
+    with _lock:
+        _holds.pop(token, None)
 
 
 @contextmanager
@@ -407,9 +442,10 @@ def attributed_wall_s(
     A call's coverage is this over its wall time — the r4 verdict's blind
     spot was 159 s of restore wall no phase could see (coverage 0.23).
     Retired wall bases are excluded (they cannot be unioned across
-    phases): exact while no one phase has left more than the compaction
-    threshold's worth of DISJOINT intervals inside the window, an
-    under-count after that."""
+    phases): exact for a window under a ``hold`` taken at its beginning,
+    whatever the number of intervals; without one, exact while no one
+    phase has left more than the compaction threshold's worth of DISJOINT
+    intervals inside the window, an under-count after that."""
     with _lock:
         ivs = [iv for lst in _intervals.values() for iv in lst]
     return union_s(_clipped(ivs, begin, end))
@@ -436,6 +472,7 @@ def reset() -> None:
         _intervals.clear()
         _wall_base.clear()
         _retired_hwm.clear()
+        _compact_at.clear()
 
 
 def delta(before: Dict[str, Dict[str, float]]) -> Dict[str, Dict[str, float]]:

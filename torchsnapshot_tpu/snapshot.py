@@ -608,6 +608,9 @@ class Snapshot:
         # that protects takes lets surviving ranks abort fast when a peer
         # dies mid-restore.
         lease = acquire_op_lease(pg.store, rank)
+        # This call reads its own account when it ends: every interval it
+        # leaves is kept until then, however many leaves it restores.
+        account_hold = phase_stats.hold(begin)
         try:
             storage = url_to_storage_plugin(self.path, self._storage_options)
             try:
@@ -663,6 +666,7 @@ class Snapshot:
                                     key, stateful, metadata, rank
                                 )
                             )
+                    leaves = sum(len(plan.futures) for plan in plans if plan)
                     pipeline = ReadAhead(
                         [plan.read_reqs if plan else [] for plan in plans],
                         storage,
@@ -731,6 +735,10 @@ class Snapshot:
             event_metadata["unattributed_s"] = unattributed_s
             event_metadata["read_ahead_s"] = pipeline.read_ahead_s
             event_metadata["read_ahead_bytes"] = pipeline.read_ahead_bytes
+            event_metadata["leaves"] = leaves
+            event_metadata["slab_read_bytes"] = int(
+                phases_delta.get("slab_read", {}).get("bytes", 0)
+            )
             event_metadata["bytes"] = int(
                 max(
                     (v.get("bytes", 0) for v in phases_delta.values()),
@@ -749,6 +757,7 @@ class Snapshot:
             tmonitor.op_finished(health, success=False)
             raise
         finally:
+            phase_stats.release(account_hold)
             release_op_lease(lease)
 
     @staticmethod

@@ -52,6 +52,10 @@ PHASE_GROUPS: Dict[str, frozenset] = {
             "slab_pack",
             "consume_copy",
             "scatter_copy",
+            # A merged read fanned out to its members (batcher.py): it
+            # spans their checksum and consume_copy, so by wall (a union)
+            # it adds the loop's turns between them and nothing twice.
+            "slab_scatter",
             # Content-defined chunk-boundary scan (chunker.py): a rolling
             # hash over the staged bytes — hash-class work, same group as
             # checksum.
