@@ -1,11 +1,14 @@
 """Read-ahead across statefuls: one read pipeline serves the whole restore
 (``scheduler.ReadAhead``).  On a slow fake storage plug-in: the next
-stateful is read while the one before it loads; nothing of it is consumed or
-sent to the device before that load has returned; the look-ahead is one
-stateful; user code stays on the calling thread, in key order; a failure
-ahead leaves what was loaded loaded and no thread behind."""
+stateful is read while the one before it loads (a read whose twin in that
+one is still landing is dispatched then and asks storage once the twin's
+host buffer has come back); nothing of it is consumed or sent to the device
+before that load has returned; the look-ahead is one stateful; user code
+stays on the calling thread, in key order; a failure ahead leaves what was
+loaded loaded and no thread behind."""
 
 import asyncio
+import gc
 import threading
 import time
 import types
@@ -22,6 +25,7 @@ from torchsnapshot_tpu.event_handlers import (
     register_event_handler,
     unregister_event_handler,
 )
+from torchsnapshot_tpu.io_preparers import array as array_mod
 from torchsnapshot_tpu.io_preparers.array import H2DBatcher
 from torchsnapshot_tpu.io_types import BufferConsumer, ReadReq, StoragePlugin
 from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
@@ -118,14 +122,36 @@ class SlowStorage(StoragePlugin):
         await self._inner.close()
 
 
-def make_app(log, keys=KEYS, zero=False, dtype=jnp.float32, seed=0, load_s=LOAD_S):
+def unaligned_buffer(nbytes):
+    """16 bytes off a 64-byte boundary, as a large ``np.empty`` is: the CPU
+    backend copies such a buffer on ``device_put`` (one at a 64-byte boundary
+    it takes as the array itself, and which a small ``np.empty`` gets is
+    chance), so what happens to it is what happens on an accelerator."""
+    raw = np.empty(nbytes + 80, dtype=np.uint8)
+    offset = (16 - raw.ctypes.data) % 64
+    return raw[offset : offset + nbytes]
+
+
+# One tree in every stateful, as a train state's parameters and moments are:
+# each leaf has a twin of its byte size in the stateful before.  Or a tree of
+# its own in each: no host buffer fits a later leaf.
+TREES = {
+    "twins": {key: SHAPE for key in KEYS},
+    "no_twins": {key: (SHAPE[0] + 64 * k, SHAPE[1]) for k, key in enumerate(KEYS)},
+}
+
+
+def make_app(
+    log, keys=KEYS, zero=False, dtype=jnp.float32, seed=0, load_s=LOAD_S, tree="twins"
+):
     rng = np.random.RandomState(seed)
     app = {}
     for key in keys:
+        shape = TREES[tree][key]
         data = {
-            f"w{i}": jnp.zeros(SHAPE, dtype)
+            f"w{i}": jnp.zeros(shape, dtype)
             if zero
-            else jnp.asarray(rng.rand(*SHAPE), dtype)
+            else jnp.asarray(rng.rand(*shape), dtype)
             for i in range(LEAVES)
         }
         app[key] = Recorder(key, data, log, load_s)
@@ -133,15 +159,17 @@ def make_app(log, keys=KEYS, zero=False, dtype=jnp.float32, seed=0, load_s=LOAD_
 
 
 @pytest.fixture
-def world(monkeypatch):
-    """A snapshot of ``KEYS`` in memory, and every seam recorded: reads
-    (slow), plans (weak references to the host buffers each stateful's
-    reads land in), consumes, H2D submits and dispatches, each with its
-    stateful."""
+def world(monkeypatch, request):
+    """A snapshot of ``KEYS`` in memory (the ``twins`` tree, or the one a
+    test names), and every seam recorded: reads (when the pipeline
+    dispatches one, and when storage is asked, slowly), plans, host buffers
+    (a weak reference to each one made, and when one is given back),
+    consumes, H2D submits and dispatches, each with its stateful."""
     MemoryStoragePlugin.reset()
     log = Log()
     url = f"memory://read_ahead_{time.monotonic_ns()}"
-    saved = make_app(Log())
+    tree = getattr(request, "param", "twins")
+    saved = make_app(Log(), tree=tree)
     with knobs.override_batching_disabled(True):
         Snapshot.take(url, saved)
 
@@ -155,38 +183,58 @@ def world(monkeypatch):
         ),
     )
 
-    plans = {}  # key -> (weak references to its host buffers, id of its batcher)
+    plans = {}  # key -> id of its batcher
+    pools = []  # the pool of each restore
     real_plan = Snapshot._plan_stateful_reads
 
-    def recording_plan(key, stateful, metadata, rank):
-        plan = real_plan(key, stateful, metadata, rank)
-        # the view a read lands through lives as long as the request's hold
-        # on the buffer (the buffer itself the CPU backend may alias into
-        # the restored array)
-        buffers = [
-            weakref.ref(rr.into.obj) for rr in plan.read_reqs if rr.into is not None
-        ]
-        assert len(buffers) == (LEAVES if key in KEYS else 0)
-        plans[key] = (buffers, id(plan.h2d_batch))
+    def recording_plan(key, stateful, metadata, rank, host_pool):
+        plan = real_plan(key, stateful, metadata, rank, host_pool)
+        into_place = [rr for rr in plan.read_reqs if rr.into is not None]
+        assert len(into_place) == (LEAVES if key in KEYS else 0)
+        plans[key] = id(plan.h2d_batch)
+        if host_pool not in pools:
+            pools.append(host_pool)
         return plan
 
     monkeypatch.setattr(Snapshot, "_plan_stateful_reads", staticmethod(recording_plan))
 
-    def key_of_batcher(batcher):
-        return next(k for k, (_, ident) in plans.items() if ident == id(batcher))
+    buffers = []  # a weak reference to every host buffer made
 
+    def recording_buffer(nbytes):
+        buf = unaligned_buffer(nbytes)
+        buffers.append(weakref.ref(buf))
+        return buf
+
+    monkeypatch.setattr(array_mod, "_fresh_host_buffer", recording_buffer)
+
+    def key_of_batcher(batcher):
+        return next(k for k, ident in plans.items() if ident == id(batcher))
+
+    real_give = array_mod.HostBufferPool.give
+
+    def recording_give(self, buf, recycle):
+        log.add("buffer_back", KEYS[self._lent[id(buf)][1]])
+        return real_give(self, buf, recycle)
+
+    monkeypatch.setattr(array_mod.HostBufferPool, "give", recording_give)
+    real_read = scheduler_mod._ReadPipeline.read_buffer
     real_consume = scheduler_mod._ReadPipeline.consume_buffer
+
+    async def recording_read(self):
+        log.add("read_dispatch", stateful_of(self.read_req.path))
+        return await real_read(self)
 
     async def recording_consume(self, executor):
         log.add("consume_begin", stateful_of(self.read_req.path))
         return await real_consume(self, executor)
 
+    monkeypatch.setattr(scheduler_mod._ReadPipeline, "read_buffer", recording_read)
     monkeypatch.setattr(scheduler_mod._ReadPipeline, "consume_buffer", recording_consume)
     real_submit, real_dispatch = H2DBatcher.submit, H2DBatcher._dispatch
 
-    def recording_submit(self, host, like, fut):
+    def recording_submit(self, host, like, fut, lease=None):
         log.add("h2d_submit", key_of_batcher(self))
-        return real_submit(self, host, like, fut)
+        return real_submit(self, host, like, fut, lease)
 
     def recording_dispatch(self, items, batch_bytes):
         log.add("h2d_dispatch", key_of_batcher(self))
@@ -196,7 +244,14 @@ def world(monkeypatch):
     monkeypatch.setattr(H2DBatcher, "_dispatch", recording_dispatch)
 
     return types.SimpleNamespace(
-        log=log, url=url, saved=saved, plans=plans, storage_args=storage_args
+        log=log,
+        url=url,
+        tree=tree,
+        saved=saved,
+        plans=plans,
+        pools=pools,
+        buffers=buffers,
+        storage_args=storage_args,
     )
 
 
@@ -236,16 +291,23 @@ def no_pipeline_thread_alive():
     return False
 
 
+@pytest.mark.parametrize("world", ["twins", "no_twins"], indirect=True)
 def test_the_next_stateful_is_read_while_this_one_loads(world):
-    target = make_app(world.log, zero=True)
+    target = make_app(world.log, zero=True, tree=world.tree)
     delta, (end,) = restore(world, target)
     log = world.log
     for this, ahead in zip(KEYS, KEYS[1:]):
         assert log.first("read_begin", ahead) < log.last("load_end", this), (this, ahead)
         # ... and not beside this one's reads, whose rate it would share: the
         # read-ahead begins where the tail begins
-        assert log.first("read_begin", ahead) >= log.last("read_end", this), (this, ahead)
-        assert log.first("read_begin", ahead) < log.first("load_begin", this), (this, ahead)
+        assert log.first("read_dispatch", ahead) >= log.last("read_end", this), (this, ahead)
+        assert log.first("read_dispatch", ahead) < log.first("load_begin", this), (this, ahead)
+        if world.tree == "no_twins":
+            # ... and a read that no landing buffer fits asks storage there
+            # and then: it is under way before this one's load begins
+            assert log.first("read_begin", ahead) < log.first("load_begin", this), (this, ahead)
+    if world.tree == "no_twins":
+        assert "host_buffer_wait" not in delta and end["host_pool"]["hits"] == 0
     # ... on a thread that is not the one that loads
     readers = {name for what, _, _, name in log.rows if what == "read_begin"}
     loaders = {name for what, _, _, name in log.rows if what == "load_begin"}
@@ -256,10 +318,51 @@ def test_the_next_stateful_is_read_while_this_one_loads(world):
     assert end["read_ahead_s"] == pytest.approx(counter["s"])
     assert end["read_ahead_bytes"] == counter["bytes"]
     # three statefuls are read ahead, and none of them twice
-    one = LEAVES * SHAPE[0] * SHAPE[1] * 4
-    assert 0 < counter["bytes"] <= (len(KEYS) - 1) * one
+    ahead_bytes = sum(LEAVES * int(np.prod(TREES[world.tree][key])) * 4 for key in KEYS[1:])
+    assert 0 < counter["bytes"] <= ahead_bytes
     assert_equal_bits(target, world.saved)
     assert no_pipeline_thread_alive()
+
+
+def test_a_read_whose_twin_is_landing_asks_storage_when_it_has_landed(world):
+    """What the pool changed of the rule above: a read of k+1 is dispatched
+    where k's tail begins, as ever, but while the host buffer of its twin in
+    k is still on its way to the device it holds its slot and asks storage
+    only once that buffer has come back, to land in pages already faulted
+    in.  Here every leaf is smaller than the batcher's flush size, so every
+    buffer of k comes back at k's drain, and every read of k+1 is such a
+    read: storage is driven from the drain's landing on, not through the
+    last consumes and the drain itself."""
+    target = make_app(world.log, zero=True)
+    delta, (end,) = restore(world, target)
+    log = world.log
+    for this, ahead in zip(KEYS, KEYS[1:]):
+        dispatched = log.times("read_dispatch", ahead)
+        begun = log.times("read_begin", ahead)
+        assert len(dispatched) == len(begun) == LEAVES
+        # held from the dispatch, where this one's tail begins ...
+        assert max(dispatched) < log.first("load_begin", this), (this, ahead)
+        # ... until a buffer of this one has come back, and not for another
+        # of the stateful's own (which come back only at its own drain)
+        back = sorted(log.times("buffer_back", this))
+        assert len(back) == LEAVES
+        for n, began in enumerate(sorted(begun)):
+            assert began >= back[n], (this, ahead, n)
+        # ... and no longer.  The landing that gives the last buffer back is
+        # the one the drain returns on, so the woken read (a turn of the
+        # pipeline's loop, then the plug-in) and the loader's load_begin race
+        # from the same moment: half a load is scheduling slack under xdist,
+        # against reads that would otherwise begin only when the load ends.
+        assert max(begun) < log.first("load_begin", this) + LOAD_S / 2, (this, ahead)
+    # the wait is a phase of its own, and what it bought is in the account:
+    # every read after the first stateful's landed in a buffer used before
+    waited = delta["host_buffer_wait"]
+    assert waited["n"] == (len(KEYS) - 1) * LEAVES and waited["wall"] > 0
+    assert waited["wall"] < end["duration_s"]
+    assert end["phases"]["host_buffer_wait"] == pytest.approx(waited["wall"], rel=0.05)
+    assert end["host_pool"]["hits"] == (len(KEYS) - 1) * LEAVES
+    assert end["host_pool"]["misses"] == LEAVES
+    assert_equal_bits(target, world.saved)
 
 
 def test_nothing_is_consumed_or_sent_to_the_device_ahead_of_the_load(world):
@@ -277,27 +380,38 @@ def test_nothing_is_consumed_or_sent_to_the_device_ahead_of_the_load(world):
 
 def test_the_look_ahead_is_one_stateful(world):
     violations = []
+    two_statefuls = 2 * LEAVES
 
     def on_read(key):
+        # the buffer this read lands in is there already: taken at dispatch
+        alive = [ref for ref in world.buffers if ref() is not None]
+        if len(alive) > two_statefuls:
+            violations.append((key, f"{len(alive)} host buffers alive"))
         k = KEYS.index(key)
-        if k < 2:
-            return
-        released = KEYS[k - 2]
-        if not world.log.times("load_end", released):
-            violations.append((key, released, "not loaded"))
-        alive = [ref for ref in world.plans[released][0] if ref() is not None]
-        if alive:
-            violations.append((key, released, f"{len(alive)} host buffers alive"))
+        if k >= 2 and not world.log.times("load_end", KEYS[k - 2]):
+            violations.append((key, KEYS[k - 2], "not loaded"))
 
     world.storage_args["on_read"] = on_read
     target = make_app(world.log, zero=True)
-    restore(world, target)
+    _, (end,) = restore(world, target)
     assert not violations, violations
     assert len(world.log.times("read_begin")) == len(KEYS) * LEAVES
-    # and it is a look-ahead: k+1 was being read before k's buffers went
+    # and it is a look-ahead: k+1 was being read before k was loaded
     for this, ahead in zip(KEYS, KEYS[1:]):
         assert world.log.first("read_begin", ahead) < world.log.last("load_end", this)
-    assert all(ref() is None for refs, _ in world.plans.values() for ref in refs)
+    # the pool's own account of the same bound, and what it saved: of four
+    # statefuls' buffers, two statefuls' at the most were ever made
+    leaf = int(np.prod(SHAPE)) * 4
+    pool = end["host_pool"]
+    assert 0 < pool["high_water"] <= two_statefuls * leaf
+    assert pool["misses"] == len(world.buffers) <= two_statefuls
+    assert pool["hits"] + pool["misses"] == len(KEYS) * LEAVES
+    assert pool["bytes"] == pool["hits"] * leaf and pool["fresh"] == pool["misses"] * leaf
+    # nothing of it outlives the restore
+    made = weakref.ref(world.pools.pop())
+    assert not world.pools
+    gc.collect()
+    assert made() is None and all(ref() is None for ref in world.buffers)
 
 
 def test_user_code_runs_on_the_calling_thread_in_key_order_rng_last(world):

@@ -21,9 +21,10 @@ from __future__ import annotations
 import asyncio
 import logging
 import math
-from collections import deque
+import threading
+from collections import defaultdict, deque
 from concurrent.futures import Executor
-from typing import Any, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -162,6 +163,8 @@ class ArrayIOPreparer:
 
         # Read-into-place: hand storage the assembly's own memory so fs
         # preads land the bytes directly (no allocation, no consume memcpy).
+        # The plan only learns that it will: the memory is taken when the
+        # read is dispatched (IntoPlace).
         _into_view = assembly.into_view
 
         if is_framed(entry):
@@ -360,6 +363,182 @@ def _approx_nbytes(obj: Any) -> int:
         return 4096
 
 
+# What an H2DBatcher is handed: the host array, the array to place it like,
+# the future of the result, and the pool's buffer under the host array (None
+# where it is nobody's to give back).
+_Item = Tuple[np.ndarray, Any, Future, Optional[np.ndarray]]
+# A landing array (None: the transfer was never made) and the pool's buffer
+# it was made from.
+_Lease = Tuple[Any, np.ndarray]
+
+
+def _fresh_host_buffer(nbytes: int) -> np.ndarray:
+    # A function of its own so that a test can choose where a buffer begins
+    # (the CPU backend aliases a 64-byte-aligned one into the landed array).
+    return np.empty(nbytes, dtype=np.uint8)
+
+
+class HostBufferPool:
+    """The host read buffers of ONE ``Snapshot.restore`` call, carried from a
+    leaf whose H2D has landed to the next leaf of the same byte size.
+
+    A leaf that is read into place and uploaded through an ``H2DBatcher``
+    reserves its size at plan time (``reserve``), takes its buffer when the
+    first of its reads is dispatched (``take``), and the batcher gives it
+    back once the transfer has landed (``give``).  The large statefuls of a
+    train state (parameters, first and second moments) are one tree three
+    times over and each is read smallest leaf first, so from the second on
+    a ``take`` finds the buffer its twin landed from long ago: nothing is
+    unmapped beside the reads (a ``munmap`` holds the GIL and takes the
+    address space's lock for writing while the readers fault pages in under
+    it) and the reads land in pages already faulted in.
+
+    A read finds its twin's buffer only once that has landed, and the reads
+    of a stateful of a dozen leaves are all dispatched in the instant the
+    one before it has been read, its largest leaves last: so a read whose
+    size has none free, while one lent to an EARLIER stateful is still to
+    come back, is held until it has (``coming``, which the assembly awaits
+    before it takes; a ``give`` wakes it).  That one is read already and
+    lands without anything this read could hold up.  What the wait buys
+    depends on the host: it trades the storage's time under that landing
+    for pages that need no first touch, and is worth it where a first touch
+    costs more than the read, as on the hosts measured (PERF.md section 5).
+    A buffer lent to the same stateful is never waited for: it may land
+    only at that stateful's drain, which waits for this very read.
+
+    Free lists keyed by exact byte size, nothing more: a size not seen
+    before is a plain ``np.empty``, as without a pool.  A buffer that
+    nothing will take again is kept all the same, so that it is not freed
+    beside reads: it goes with the rest when the restore ends (``close``),
+    or earlier only to make room, when a miss would take the bytes alive
+    through the pool (taken and not given back, plus free) over the two
+    largest groups' reservations: the bound the read pipeline keeps without
+    a pool.  Nothing outlives the restore.
+
+    Thread-safe: ``reserve`` runs on the planning thread, ``coming`` and
+    ``take`` on the read pipeline's thread or its executor, ``give`` on the
+    lander."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._free: Dict[int, List[np.ndarray]] = defaultdict(list)
+        self._group_bytes: List[int] = []  # reserved by each stateful
+        self._lent: Dict[int, Tuple[int, int]] = {}  # id(buffer) -> (size, group)
+        # size -> (loop, future) of each read held until one comes back
+        self._waiters: Dict[int, List[Tuple[Any, Any]]] = defaultdict(list)
+        self._alive = 0  # bytes taken and not given back, plus free
+        self._stats = {"bytes": 0, "fresh": 0, "hits": 0, "misses": 0, "high_water": 0}
+
+    def begin_group(self) -> None:
+        """The reservations that follow are one stateful's."""
+        with self._lock:
+            self._group_bytes.append(0)
+
+    def reserve(self, nbytes: int) -> int:
+        """One ``take`` of ``nbytes`` is to come; the group (stateful) it
+        will come from."""
+        with self._lock:
+            self._group_bytes[-1] += nbytes
+            return len(self._group_bytes) - 1
+
+    def coming(self, nbytes: int, group: int) -> Optional["asyncio.Future[None]"]:
+        """None where a ``take`` need not wait; else a future of the running
+        loop that the next ``give`` of a buffer of ``nbytes`` resolves: no
+        such buffer is free, and one lent to a group before ``group`` is yet
+        to be given back."""
+        with self._lock:
+            if self._free[nbytes] or not any(
+                size == nbytes and lent_to < group
+                for size, lent_to in self._lent.values()
+            ):
+                return None
+            loop = asyncio.get_running_loop()
+            woken = loop.create_future()
+            self._waiters[nbytes].append((loop, woken))
+            return woken
+
+    def take(self, nbytes: int, group: int) -> np.ndarray:
+        """A flat uint8 buffer of exactly ``nbytes`` for a leaf of ``group``:
+        one given back, else a new one."""
+        evicted: List[np.ndarray] = []
+        stats = self._stats
+        with self._lock:
+            if self._free[nbytes]:
+                stats["hits"] += 1
+                stats["bytes"] += nbytes
+                buf = self._free[nbytes].pop()
+            else:
+                stats["misses"] += 1
+                stats["fresh"] += nbytes
+                over = self._alive + nbytes - sum(sorted(self._group_bytes)[-2:])
+                for bufs in self._free.values():
+                    while over > 0 and bufs:
+                        evicted.append(bufs.pop())
+                        over -= evicted[-1].nbytes
+                self._alive += nbytes - sum(buf.nbytes for buf in evicted)
+                stats["high_water"] = max(stats["high_water"], self._alive)
+                buf = _fresh_host_buffer(nbytes)
+            self._lent[id(buf)] = (nbytes, group)
+        del evicted  # unmapped outside the lock
+        return buf
+
+    def give(self, buf: np.ndarray, recycle: bool) -> None:
+        """``buf``, taken here, is done with.  ``recycle`` only where its
+        transfer has landed and the landed array is not the buffer itself;
+        otherwise it is dropped, and only counted out.  Either way whoever
+        waits for a buffer of its size (``coming``) is woken to look again."""
+        with self._lock:
+            del self._lent[id(buf)]
+            if recycle:
+                self._free[buf.nbytes].append(buf)
+            else:
+                self._alive -= buf.nbytes
+            waiters = self._waiters.pop(buf.nbytes, ())
+        for loop, woken in waiters:
+            try:
+                loop.call_soon_threadsafe(_wake, woken)
+            except RuntimeError:  # the pipeline was aborted, its loop closed
+                pass
+
+    def close(self) -> None:
+        """The restore is over, nothing reads any more: what is free goes."""
+        with self._lock:
+            free = [buf for bufs in self._free.values() for buf in bufs]
+            self._free.clear()
+            self._alive -= sum(buf.nbytes for buf in free)
+        del free  # unmapped outside the lock
+
+    def stats(self) -> Dict[str, int]:
+        """``bytes`` read into recycled buffers (``hits`` of them), ``fresh``
+        bytes read into new ones (``misses``), and the ``high_water`` of the
+        bytes alive through the pool."""
+        with self._lock:
+            return dict(self._stats)
+
+
+def _wake(woken: "asyncio.Future[None]") -> None:
+    if not woken.done():  # cancelled with its read
+        woken.set_result(None)
+
+
+def _may_alias(out: Any, buf: np.ndarray) -> bool:
+    """Whether the landed array ``out`` may be the host buffer ``buf``
+    itself.  The CPU backend's ``device_put`` of a 64-byte-aligned host array
+    copies nothing: the device array is the host memory, and writing the
+    next leaf into it would change a restored array.  An accelerator's
+    arrays live in memory of its own."""
+    try:
+        if all(device.platform != "cpu" for device in out.devices()):
+            return False
+        begin = buf.ctypes.data
+        return any(
+            begin <= shard.data.unsafe_buffer_pointer() < begin + buf.nbytes
+            for shard in out.addressable_shards
+        )
+    except Exception:  # noqa: BLE001 -- not seen to be apart: not recycled
+        return True
+
+
 class H2DBatcher:
     """Cross-array H2D upload batching + landing pacing for the restore path.
 
@@ -382,6 +561,12 @@ class H2DBatcher:
     finishes: on return every submitted array is ON DEVICE, not in flight,
     and the lander thread has exited.
 
+    With a ``host_pool`` (``Snapshot.restore``'s), a buffer submitted with
+    its ``lease`` (the pool's buffer under ``host``) goes back to the pool
+    when its transfer has landed, unless the landed array may be the buffer
+    itself (``_may_alias``); one whose transfer failed, or was never made,
+    is dropped.  Nothing here keeps a host buffer past its landing.
+
     Thread-safety: ``submit``/``flush`` may run on the read pipeline's loop
     or executor threads, ``drain`` on the caller thread.  Because landings
     run on the lander (never on the flushing thread), a backpressure wait
@@ -396,10 +581,10 @@ class H2DBatcher:
         self,
         flush_bytes: int = _DEFAULT_FLUSH_BYTES,
         inflight_cap_bytes: Optional[int] = None,
+        host_pool: Optional[HostBufferPool] = None,
     ) -> None:
-        import threading
-
-        self._items: List[Tuple[np.ndarray, Any, Future]] = []
+        self.host_pool = host_pool
+        self._items: List[_Item] = []
         self._bytes = 0
         self._flush_bytes = flush_bytes
         self._inflight_cap = (
@@ -407,15 +592,22 @@ class H2DBatcher:
         )
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
-        self._inflight: "deque[Tuple[List[Any], int]]" = deque()
+        # (landing arrays, their bytes, (array, lease) of the pool's buffers)
+        self._inflight: "deque[Tuple[List[Any], int, List[_Lease]]]" = deque()
         self._unlanded_bytes = 0  # dispatched, not yet landed
         self._lander: Optional[Any] = None
         self._lander_stop = False
         self._lander_error: Optional[BaseException] = None
 
-    def submit(self, host: np.ndarray, like: Any, fut: Future) -> None:
+    def submit(
+        self,
+        host: np.ndarray,
+        like: Any,
+        fut: Future,
+        lease: Optional[np.ndarray] = None,
+    ) -> None:
         with self._lock:
-            self._items.append((host, like, fut))
+            self._items.append((host, like, fut, lease))
             self._bytes += host.nbytes
             should_flush = self._bytes >= self._flush_bytes
         if should_flush:
@@ -428,7 +620,7 @@ class H2DBatcher:
             items, self._items, self._bytes = self._items, [], 0
         if not items:
             return
-        batch_bytes = sum(host.nbytes for host, _, _ in items)
+        batch_bytes = sum(host.nbytes for host, *_ in items)
         # Backpressure: wait for the lander to free window room, and RESERVE
         # this batch's bytes in the same critical section — otherwise N
         # concurrent flushers all pass the check against the
@@ -461,19 +653,22 @@ class H2DBatcher:
                 self._cond.notify_all()
             raise
         landed_bytes = sum(
-            host.nbytes for (host, _, _), out in zip(items, outs) if out is not None
+            host.nbytes for (host, *_), out in zip(items, outs) if out is not None
         )
         good = [out for out in outs if out is not None]
-        for out, (_, _, fut) in zip(outs, items):
+        leases: List[_Lease] = []
+        for out, (_, _, fut, lease) in zip(outs, items):
             if out is not None:
                 fut.obj = out
+                if lease is not None:
+                    leases.append((out, lease))
         with self._cond:
             # Release the reservation for items that did not dispatch (they
             # land synchronously in the per-item retry below, outside the
             # window).
             self._unlanded_bytes -= batch_bytes - landed_bytes
             if good:
-                self._inflight.append((good, landed_bytes))
+                self._inflight.append((good, landed_bytes, leases))
                 self._ensure_lander()
             self._cond.notify_all()
         if failed:
@@ -527,8 +722,6 @@ class H2DBatcher:
     def _ensure_lander(self) -> None:
         # Called under the lock.
         if self._lander is None:
-            import threading
-
             self._lander = threading.Thread(
                 target=self._land_loop, name="tpusnap-h2d-lander", daemon=True
             )
@@ -545,7 +738,7 @@ class H2DBatcher:
                     self._cond.wait()
                 if not self._inflight:  # stop requested and queue empty
                     return
-                outs, nbytes = self._inflight.popleft()
+                outs, nbytes, leases = self._inflight.popleft()
             # A landing failure must not wedge the batcher: record the first
             # error, keep the byte accounting exact, and KEEP LANDING the
             # remaining batches so backpressure waiters and drain() always
@@ -556,15 +749,27 @@ class H2DBatcher:
                     jax.block_until_ready(outs)
             except BaseException as e:  # noqa: BLE001
                 err = e
+            # Before the window opens: whoever it lets through finds the
+            # buffers this batch landed from.
+            self._settle(leases, landed=err is None)
+            outs = leases = None
             with self._cond:
                 self._unlanded_bytes -= nbytes
                 if err is not None and self._lander_error is None:
                     self._lander_error = err
                 self._cond.notify_all()
 
+    def _settle(self, leases: List[_Lease], landed: bool) -> None:
+        """Give the pool's buffers back: to be used again where the transfer
+        has ``landed`` and the array it made is not the buffer itself."""
+        for out, lease in leases:
+            self.host_pool.give(
+                lease, recycle=landed and out is not None and not _may_alias(out, lease)
+            )
+
     def _dispatch(
-        self, items: List[Tuple[np.ndarray, Any, Future]], batch_bytes: int
-    ) -> Tuple[List[Any], List[Tuple[np.ndarray, Any, Future]]]:
+        self, items: List[_Item], batch_bytes: int
+    ) -> Tuple[List[Any], List[_Item]]:
         """Dispatch the batch in ONE batched ``device_put``, each buffer onto
         its target's own sharding (device, layout and memory kind preserved
         exactly, as _device_put_like does per item); returns (outs, failed)
@@ -578,8 +783,8 @@ class H2DBatcher:
         idx: List[int] = []
         bufs: List[np.ndarray] = []
         shardings: List[Any] = []
-        failed: List[Tuple[np.ndarray, Any, Future]] = []
-        for i, (host, like, _) in enumerate(items):
+        failed: List[_Item] = []
+        for i, (host, like, _, _) in enumerate(items):
             # Classification must never sink the batch: an item whose dtype
             # cast raises goes straight to the per-item retry (correct
             # blame), the rest dispatch normally.
@@ -619,37 +824,50 @@ class H2DBatcher:
             dispatch.close(sum(b.nbytes for b in bufs))
         return outs, failed
 
-    def _dispatch_per_item(
-        self, items: List[Tuple[np.ndarray, Any, Future]]
-    ) -> None:
+    def _dispatch_per_item(self, items: List[_Item]) -> None:
         import jax
 
         from .. import phase_stats
 
         first_exc: Optional[BaseException] = None
         outs: List[Any] = []
+        leases: List[_Lease] = []
         nbytes = 0
-        for host, like, fut in items:
+        for host, like, fut, lease in items:
+            out = None
             try:
-                fut.obj = _device_put_like(host, like)
-                outs.append(fut.obj)
+                fut.obj = out = _device_put_like(host, like)
+                outs.append(out)
                 nbytes += host.nbytes
             except Exception as e:
                 if first_exc is None:
                     first_exc = e
+            if lease is not None:
+                leases.append((out, lease))
         # These transfers bypass the in-flight window (error path): land them
         # here so drain()'s "on device on return" contract still holds and
         # the landing wall stays attributed.
-        if outs:
-            with phase_stats.timed("h2d_land", nbytes):
-                jax.block_until_ready(outs)
+        landed = False
+        try:
+            if outs:
+                with phase_stats.timed("h2d_land", nbytes):
+                    jax.block_until_ready(outs)
+            landed = True
+        finally:
+            self._settle(leases, landed)
         if first_exc is not None:
             raise first_exc
 
 
 class ArrayAssembly:
     """Shared restore target for one logical array: a host buffer that one or
-    more consumers fill, finalized into the caller's target exactly once."""
+    more consumers fill, finalized into the caller's target exactly once.
+
+    The buffer lives from the dispatch of the first read that lands in it
+    (``host``: nothing is allocated at plan time) to ``finalize``, which
+    hands it on and lets go.  A jax-array target of a megabyte and more that
+    uploads through an ``H2DBatcher`` with a ``HostBufferPool`` takes its
+    buffer from the pool, and the batcher gives it back once landed."""
 
     def __init__(
         self,
@@ -663,10 +881,55 @@ class ArrayAssembly:
         self._pending = 0
         self._h2d_batch = h2d_batch
         self._inplace = ArrayIOPreparer.can_load_inplace(entry, obj_out)
-        if self._inplace:
-            self.host = obj_out
-        else:
-            self.host = ArrayIOPreparer.empty_array_from_entry(entry)
+        self._host: Optional[np.ndarray] = obj_out if self._inplace else None
+        # Pieces are copied in on executor threads while the loop's thread
+        # dispatches reads: one of them makes the buffer.
+        self._host_lock = threading.Lock()
+        self._lease: Optional[np.ndarray] = None  # the pool's buffer under _host
+        self._pool: Optional[HostBufferPool] = None
+        self._nbytes = serialization.array_nbytes(entry.shape, entry.dtype)
+        if (
+            h2d_batch is not None
+            and h2d_batch.host_pool is not None
+            and not self._inplace
+            and staging.is_jax_array(obj_out)
+            and self._nbytes >= _INTO_PLACE_MIN_BYTES
+        ):
+            self._pool = h2d_batch.host_pool
+            self._group = self._pool.reserve(self._nbytes)
+
+    @property
+    def host(self) -> np.ndarray:
+        with self._host_lock:
+            if self._host is None:
+                if self._pool is not None:
+                    self._lease = self._pool.take(self._nbytes, self._group)
+                    self._host = self._lease.view(
+                        serialization.string_to_dtype(self.entry.dtype)
+                    ).reshape(self.entry.shape)
+                else:
+                    self._host = ArrayIOPreparer.empty_array_from_entry(self.entry)
+            return self._host
+
+    async def buffer_ready(self) -> None:
+        """Before a read into this assembly is dispatched: while the pool
+        has no buffer of its size free and its twin's, lent to a stateful
+        before this one, is still landing, wait for that one
+        (``HostBufferPool.coming``; the phase ``host_buffer_wait``)."""
+        from .. import phase_stats
+
+        waited = None
+        try:
+            while self._host is None and self._pool is not None:
+                woken = self._pool.coming(self._nbytes, self._group)
+                if woken is None:
+                    break
+                if waited is None:
+                    waited = phase_stats.open_interval("host_buffer_wait")
+                await woken
+        finally:
+            if waited is not None:
+                waited.close()
 
     def expect(self, n: int) -> None:
         self._pending = n
@@ -674,20 +937,18 @@ class ArrayAssembly:
             self.finalize()
 
     def flat_u8(self) -> np.ndarray:
-        arr = self.host if self.host.ndim > 0 else self.host.reshape(1)
+        host = self.host
+        arr = host if host.ndim > 0 else host.reshape(1)
         return arr.view(np.uint8).reshape(-1)
 
-    def into_view(self, offset: int, nbytes: int) -> Optional[memoryview]:
-        """Read-into-place view of ``[offset, offset+nbytes)`` of this
-        assembly, or None when not worth it (below the size threshold —
-        small reads should keep merging in the batcher) or not possible.
-        The single policy point for the dense and chunked read paths."""
+    def into_view(self, offset: int, nbytes: int) -> Optional["IntoPlace"]:
+        """Read-into-place of ``[offset, offset+nbytes)`` of this assembly,
+        or None when not worth it (below the size threshold — small reads
+        should keep merging in the batcher).  The single policy point for
+        the dense and chunked read paths."""
         if nbytes < _INTO_PLACE_MIN_BYTES:
             return None
-        try:
-            return memoryview(self.flat_u8())[offset : offset + nbytes]
-        except Exception:
-            return None
+        return IntoPlace(self, offset, nbytes)
 
     def piece_done(self) -> None:
         self._pending -= 1
@@ -696,6 +957,7 @@ class ArrayAssembly:
 
     def finalize(self) -> None:
         out = self.host
+        lease, self._host, self._lease = self._lease, None, None
         target = self.obj_out
         if self._inplace:
             self.fut.obj = target
@@ -705,7 +967,7 @@ class ArrayAssembly:
             return
         if staging.is_jax_array(target):
             if self._h2d_batch is not None:
-                self._h2d_batch.submit(out, target, self.fut)
+                self._h2d_batch.submit(out, target, self.fut, lease)
             else:
                 self.fut.obj = _device_put_like(out, target)
             return
@@ -718,6 +980,36 @@ class ArrayAssembly:
             self.fut.obj = target
             return
         self.fut.obj = out
+
+
+class IntoPlace:
+    """Where one read lands in its assembly's buffer (``ReadReq.into``).  The
+    plan knows that much; the memory is there from ``acquire()``, which the
+    read pipeline awaits when it dispatches the read, and the consumer lets
+    go of it (``release``) once it has seen the read arrive."""
+
+    def __init__(self, assembly: ArrayAssembly, offset: int, nbytes: int) -> None:
+        self._assembly = assembly
+        self._offset = offset
+        self._nbytes = nbytes
+        self._view: Optional[memoryview] = None
+
+    async def acquire(self) -> memoryview:
+        """The same view at every call: a plug-in that read in place hands
+        it back as the read's buffer, and a read tried again lands where
+        the first try did."""
+        if self._view is None:
+            await self._assembly.buffer_ready()
+            flat = memoryview(self._assembly.flat_u8())
+            self._view = flat[self._offset : self._offset + self._nbytes]
+        return self._view
+
+    def holds(self, buf: Any) -> bool:
+        """Whether ``buf`` is the view this read was given to land in."""
+        return self._view is not None and buf is self._view
+
+    def release(self) -> None:
+        self._view = None
 
 
 def _device_put_like(host: np.ndarray, like: Any) -> Any:
@@ -748,7 +1040,7 @@ class ArrayBufferConsumer(BufferConsumer):
         nbytes: int,
         checksum: Optional[str] = None,
         location: str = "",
-        into: Optional[memoryview] = None,
+        into: Optional[IntoPlace] = None,
         codec: Optional[str] = None,
         frame_nbytes: Optional[int] = None,
     ) -> None:
@@ -773,7 +1065,7 @@ class ArrayBufferConsumer(BufferConsumer):
     async def consume_buffer(
         self, buf: BufferType, executor: Optional[Executor] = None
     ) -> None:
-        in_place = self._into is not None and buf is self._into
+        in_place = self._into is not None and self._into.holds(buf)
 
         def _copy() -> None:
             from .. import integrity, phase_stats
@@ -803,6 +1095,8 @@ class ArrayBufferConsumer(BufferConsumer):
             await asyncio.get_running_loop().run_in_executor(executor, _copy)
         else:
             _copy()
+        if self._into is not None:
+            self._into.release()
         self._assembly.piece_done()
 
     def get_consuming_cost_bytes(self) -> int:

@@ -40,6 +40,7 @@ from ..io_types import (
     BufferConsumer,
     BufferType,
     Future,
+    IntoView,
     ReadReq,
     WriteReq,
 )
@@ -302,7 +303,7 @@ class ShardedArrayIOPreparer:
                         scatter=scatter,
                         into=into,
                     ),
-                    into=into,
+                    into=IntoView(into) if into is not None else None,
                 )
             )
         restore.expect(n_pieces)

@@ -166,10 +166,24 @@ class ReadReq:
     # re-merged by the batcher — that would silently defeat the caller's
     # buffer_size_limit_bytes and buffer the whole payload at once.
     no_merge: bool = False
-    # Read-into-place: the consumer's destination view, forwarded to the
-    # storage plugin via ReadIO.into.  Requests carrying one are never
-    # merged (their destinations are not contiguous in host memory).
-    into: Optional[memoryview] = None
+    # Read-into-place: the consumer's destination, forwarded to the storage
+    # plugin via ReadIO.into once the read is dispatched and no sooner: the
+    # read pipeline awaits its ``acquire()`` for the memoryview (IntoView:
+    # memory that is there already; io_preparers/array.IntoPlace: memory
+    # taken then, from the restore's pool of host buffers).  Requests
+    # carrying one are never merged (their destinations are not contiguous
+    # in host memory).
+    into: Optional[Any] = None
+
+
+class IntoView:
+    """A ``ReadReq.into`` whose memory is there at plan time."""
+
+    def __init__(self, view: memoryview) -> None:
+        self._view = view
+
+    async def acquire(self) -> memoryview:
+        return self._view
 
 
 class StoragePlugin(abc.ABC):

@@ -814,9 +814,18 @@ class _ReadPipeline:
         self.consuming_cost = read_req.buffer_consumer.get_consuming_cost_bytes()
         self.buf: Optional[bytearray] = None
         self.hash64: Optional[int] = None
+        self.read_began = 0.0  # when storage was asked, its memory acquired
 
     async def read_buffer(self) -> "_ReadPipeline":
         consumer = self.read_req.buffer_consumer
+        # The read is being dispatched: only now is the memory it lands in
+        # taken (a restored leaf's host buffer lives from here to its
+        # landing; ``acquire`` may hold the read until the buffer its twin
+        # in the stateful before is landing from has come back).
+        into = self.read_req.into
+        if into is not None:
+            into = await into.acquire()
+        self.read_began = time.monotonic()
         read_io = ReadIO(
             path=self.read_req.path,
             byte_range=(
@@ -824,7 +833,7 @@ class _ReadPipeline:
                 if self.read_req.byte_range is not None
                 else None
             ),
-            into=self.read_req.into,
+            into=into,
             # Ask for a read-fused digest only when this consumer will
             # actually verify the whole payload against one — merged
             # spanning reads (composite consumers) and digest-less entries
@@ -862,12 +871,12 @@ class ReadAhead:
     The groups are the statefuls' read requests in the order they are
     loaded.  The thread that makes this object (the one that called
     ``restore``) is the loader: for each group k it calls
-    ``wait_consumed(k)``, does what only it may do (the H2D drain, user
-    code, the release of k's host buffers) and then ``mark_loaded(k)``.
-    The pipeline's thread runs ``execute_read_reqs`` over every group on a
-    loop of its own and is meanwhile reading group k+1.  ``close()``
-    (always, from a ``finally``) cancels what is still in flight and joins
-    the thread.
+    ``wait_consumed(k)``, does what only it may do (the H2D drain, after
+    which none of k's host buffers is in use, and user code) and then
+    ``mark_loaded(k)``.  The pipeline's thread runs ``execute_read_reqs``
+    over every group on a loop of its own and is meanwhile reading group
+    k+1.  ``close()`` (always, from a ``finally``) cancels what is still in
+    flight and joins the thread.
 
     After ``close()``, ``read_ahead_s`` and ``read_ahead_bytes`` say how
     much was read ahead: see ``_read_ahead``."""
@@ -928,7 +937,7 @@ class ReadAhead:
                 )
 
     def mark_loaded(self, group: int) -> None:
-        """``group`` is loaded and its host buffers are released: its
+        """``group`` is loaded and none of its host buffers is in use: its
         successor may be consumed, and the group after that read."""
         with self._cond:
             assert group == len(self._loaded_at), (group, len(self._loaded_at))
@@ -1018,18 +1027,28 @@ async def execute_read_reqs(
 
     - the reads of group k+1 start when the last read of group k has
       finished, so storage is driven through k's tail (its last consumes,
-      the loader's H2D drain, ``load_state_dict`` and the release of its
-      host buffers).  Reading ahead of the loader is safe: a committed
-      snapshot is immutable.  They start no sooner, because reads in
-      flight share the storage's rate: read beside k, group k+1 only
-      delays k's last read and so its own H2D.
+      the loader's H2D drain and ``load_state_dict``).  Reading ahead of
+      the loader is safe: a committed snapshot is immutable.  They start
+      no sooner, because reads in flight share the storage's rate: read
+      beside k, group k+1 only delays k's last read and so its own H2D.
     - no consume of group k+1 (checksum, H2D submit, sharded
       ``device_put``) starts before the loader has loaded group k.  Until
       then k's restore target is alive on the device, and k+1's arrays
       landing beside it would raise the restore's HBM peak.  A read that
       finishes early is parked with its bytes debited from the budget.
-    - no read of group k+2 starts before group k is loaded and its host
-      buffers released, so at most two groups' buffers are resident.
+    - in a restore (``HostBufferPool``) a leaf's host buffer is taken when
+      its first read is dispatched, and a buffer of k whose H2D has landed
+      is k+1's to read into.  A read of k+1 whose twin in k (the leaf of
+      its byte size) is still landing is dispatched as above and then HELD,
+      in its io slot and with its bytes debited, until that buffer has come
+      back (``host_buffer_wait``): storage is asked for it from the twin's
+      landing on, which for k's last leaves is the loader's drain, and not
+      through k's last consumes and the drain itself.  That gives up read-
+      ahead for those leaves to read into pages already faulted in; a read
+      with no twin in flight is never held.
+    - no read of group k+2 starts before group k is loaded, all of its
+      host buffers landed from, so at most two groups' buffers are
+      resident.
 
     With no loader a group counts as loaded once it is consumed.  An error
     in any read or consume cancels everything in flight and is raised."""
@@ -1050,8 +1069,7 @@ async def execute_read_reqs(
     ]
     n_groups = len(ready_for_io)
     n_reqs = len(all_reqs)
-    # From here the queues own the requests: a loaded group's host buffers
-    # die with the loader's own references (Snapshot._load_stateful).
+    # From here the queues own the requests, and drop each once consumed.
     del read_groups, all_reqs
     unread = [len(queue) for queue in ready_for_io]
     unconsumed = list(unread)
@@ -1098,10 +1116,13 @@ async def execute_read_reqs(
                 slot_wait = phase_stats.open_interval("io_slot_wait")
                 async with io_semaphore:
                     slot_wait.close(min_s=0.001)
-                    begin = time.monotonic()
                     await pipeline.read_buffer()
                     reads[pipeline.group].append(
-                        (begin, time.monotonic(), _buf_nbytes(pipeline.buf))
+                        (
+                            pipeline.read_began,
+                            time.monotonic(),
+                            _buf_nbytes(pipeline.buf),
+                        )
                     )
                     return pipeline
             except asyncio.CancelledError:
@@ -1255,8 +1276,8 @@ async def execute_read_reqs(
                     reporter.io_done += 1
                     reporter.bytes_done += pipeline.consuming_cost
                     tmetrics.record_io_bytes("read", pipeline.consuming_cost)
-            # A consumed request is the loader's alone from here: no local
-            # of this frame keeps it (and its host buffer) past the turn.
+            # No local of this frame keeps a consumed request (and, of a
+            # leaf not uploaded through a pool, its host buffer) past the turn.
             done = waiting = task = pipeline = None
             note_progress()
             dispatch_io()

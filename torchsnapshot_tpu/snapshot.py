@@ -51,6 +51,7 @@ from .dist_store import (
 from .event import Event
 from .event_handlers import log_event
 from .flatten import flatten, inflate
+from .io_preparers.array import H2DBatcher, HostBufferPool
 from .io_types import Future, ReadReq, StoragePlugin, WriteReq
 from .manifest import (
     Entry,
@@ -557,23 +558,33 @@ class Snapshot:
         thread of its own, reads them in that order under one memory budget
         and one set of io slots.  This thread loads: for each stateful it
         waits for the last consume, drains the H2D batcher, calls
-        ``load_state_dict``, releases the stateful's host buffers and passes
-        the per-key barrier, RNG state last, as ever.
+        ``load_state_dict`` and passes the per-key barrier, RNG state last,
+        as ever.
 
         What is **read ahead**: the next stateful's storage reads start when
         the last read of this one has finished, and run while its last
-        consumes, its H2D drain, its load and the release of its buffers go
-        on, so storage is driven through every stateful's tail but the last.
-        Reading ahead of a barrier is safe: a committed snapshot is
-        immutable.  What is **never consumed ahead**: no checksum, H2D
-        submit or sharded ``device_put`` of stateful k+1 starts before k is
-        loaded, because until ``load_state_dict`` of k has returned k's
-        restore target is alive on the device and the restore's HBM peak
-        (1.333 x state with the state split four ways) would rise; and no
-        read of k+2 starts before k's host buffers are released, so host
-        memory holds two statefuls' bytes at the most.  An in-place numpy
-        target of k+1 is filled as its reads arrive, as within a stateful;
-        no user code runs ahead.
+        consumes, its H2D drain and its load go on, so storage is driven
+        through every stateful's tail but the last.  Reading ahead of a
+        barrier is safe: a committed snapshot is immutable.  What is
+        **never consumed ahead**: no checksum, H2D submit or sharded
+        ``device_put`` of stateful k+1 starts before k is loaded, because
+        until ``load_state_dict`` of k has returned k's restore target is
+        alive on the device and the restore's HBM peak (1.333 x state with
+        the state split four ways) would rise; and no read of k+2 starts
+        before k is loaded, so host memory holds two statefuls' bytes at
+        the most.  An in-place numpy target of k+1 is filled as its reads
+        arrive, as within a stateful; no user code runs ahead.
+
+        **Host read buffers are reused** (``HostBufferPool``, this call's
+        own): a leaf uploaded through the H2D batcher takes its buffer when
+        its first read is dispatched and the batcher gives it back once the
+        transfer has landed, to the next leaf of the same byte size: the
+        moments of an optimizer read into the buffers the parameters landed
+        from.  So nothing is unmapped beside the reads, and from the second
+        stateful on the reads fault in no fresh page.  What the pool holds
+        is freed when the last stateful is loaded (or the call fails; the
+        phase ``host_pool_free``), and the pool dies with the call; its account is the ``host_pool``
+        counter and the ``host_pool`` entry of the ``restore.end`` event.
 
         On-device contract: dense and chunked array uploads are drained
         before return (H2DBatcher.drain — their bytes are ON DEVICE, with
@@ -658,12 +669,13 @@ class Snapshot:
                 if rng_state_item is not None:
                     keyed.append(rng_state_item)
                 plans: List[Optional[_StatefulPlan]] = []
+                host_pool = HostBufferPool()
                 try:
                     for key, stateful in keyed:
                         with phase_stats.timed("plan_read"):
                             plans.append(
                                 self._plan_stateful_reads(
-                                    key, stateful, metadata, rank
+                                    key, stateful, metadata, rank, host_pool
                                 )
                             )
                     leaves = sum(len(plan.futures) for plan in plans if plan)
@@ -693,6 +705,10 @@ class Snapshot:
                     for plan in plans:
                         if plan is not None:
                             plan.h2d_batch.shutdown()
+                    # Nothing reads any more: the host buffers go here, and
+                    # not beside a read.
+                    with phase_stats.timed("host_pool_free"):
+                        host_pool.close()
                 phases_delta = phase_stats.delta(phases_before)
                 if tsidecar.enabled():
                     extra = {
@@ -730,11 +746,16 @@ class Snapshot:
             phase_stats.add_counter(
                 "read_ahead", pipeline.read_ahead_s, pipeline.read_ahead_bytes
             )
+            # How often a read landed in a buffer an earlier leaf had
+            # landed from.
+            pooled = host_pool.stats()
+            phase_stats.add_counter("host_pool", 0.0, pooled.pop("bytes"), **pooled)
             event_metadata["duration_s"] = end - begin
             event_metadata["phases"] = phase_stats.walls_between(begin, end)
             event_metadata["unattributed_s"] = unattributed_s
             event_metadata["read_ahead_s"] = pipeline.read_ahead_s
             event_metadata["read_ahead_bytes"] = pipeline.read_ahead_bytes
+            event_metadata["host_pool"] = host_pool.stats()
             event_metadata["leaves"] = leaves
             event_metadata["slab_read_bytes"] = int(
                 phases_delta.get("slab_read", {}).get("bytes", 0)
@@ -765,7 +786,8 @@ class Snapshot:
         group: int, plan: "_StatefulPlan", pipeline: ReadAhead, strict: bool
     ) -> None:
         """The loader's part of one stateful, on the thread that called
-        ``restore``: everything after its last consume."""
+        ``restore``: everything after its last consume (the H2D drain, then
+        ``inflate`` and the stateful's own ``load_state_dict``)."""
         pipeline.wait_consumed(group)
         # Flush the tail AND wait for every H2D transfer to land:
         # restore's contract is "dense/chunked state is on device when
@@ -789,11 +811,10 @@ class Snapshot:
                 stateful.load_state_dict(restored_state_dict, strict=False)  # type: ignore[call-arg]
             else:
                 stateful.load_state_dict(restored_state_dict)
-            # What this stateful's restore held dies here, inside the phase
-            # and not in the return after it: the read requests own the
-            # host buffers, as many bytes as were restored, and unmapping
-            # them is the larger part of this phase (PERF.md section 5).
-            # The pipeline dropped its references as each was consumed.
+            # What this stateful's restore still holds dies here, before the
+            # next stateful's arrays may land: the restored values, and the
+            # requests.  No host buffer of an uploaded leaf is among it: each
+            # went back to the restore's pool, or was dropped, as it landed.
             plan.read_reqs.clear()
             plan.futures.clear()
             del resolved, restored_state_dict
@@ -807,9 +828,12 @@ class Snapshot:
         stateful: Stateful,
         metadata: SnapshotMetadata,
         rank: int,
+        host_pool: HostBufferPool,
     ) -> Optional["_StatefulPlan"]:
         """The ``plan_read`` phase of one stateful; None where the snapshot
-        holds nothing for it."""
+        holds nothing for it.  No host buffer is allocated here: a leaf's is
+        taken when its first read is dispatched (from ``host_pool``, the
+        restore's, where it uploads through the H2D batcher)."""
         local_manifest, merged_entries = get_manifest_for_rank(metadata, rank)
 
         # Current state dict provides in-place restore targets, avoiding 2x
@@ -845,9 +869,8 @@ class Snapshot:
         # batched pjrt transfers (flushed incrementally and after the read
         # pipeline drains) instead of one dispatch per array serialized
         # behind its read.
-        from .io_preparers.array import H2DBatcher
-
-        h2d_batch = H2DBatcher()
+        host_pool.begin_group()
+        h2d_batch = H2DBatcher(host_pool=host_pool)
         try:
             read_reqs: List[ReadReq] = []
             futures: Dict[str, Future] = {}
@@ -1282,9 +1305,9 @@ class Snapshot:
 
 @dataclasses.dataclass
 class _StatefulPlan:
-    """What ``plan_read`` makes of one stateful: its read requests (host
-    buffers included; one group of the restore's read pipeline), the future
-    of every entry, its container entries and the H2D batcher the requests
+    """What ``plan_read`` makes of one stateful: its read requests (one
+    group of the restore's read pipeline; no host buffer yet), the future of
+    every entry, its container entries and the H2D batcher the requests
     feed."""
 
     key: str
