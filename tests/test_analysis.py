@@ -63,7 +63,7 @@ def test_fixture_dir_is_excluded_from_repo_walk():
     rels = [rel for _, rel in core.iter_python_files(REPO_ROOT)]
     assert not any("analysis_fixtures" in rel for rel in rels)
     assert "torchsnapshot_tpu/knobs.py" in rels
-    assert "bench.py" in rels
+    assert "chip_smoke.py" in rels
 
 
 # -------------------------------------------------------- golden fixtures

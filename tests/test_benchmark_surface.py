@@ -1,0 +1,102 @@
+"""The surface the driver runs must work in one shot: ``chipbench/run.py``
+and the readers under ``chipbench/metrics/``, which find the library's work
+by ``phase_stats`` names.  A library change that renames or stops firing a
+phase, or a benchmark change that lists a metric whose reader finds nothing,
+would otherwise meet the driver as a ``null`` under ``per_layer`` on the chip.
+
+One rehearsal of a toy cell on the CPU, untraced and traced, under a
+benchmark file made of the toy ``configs`` and ``workloads`` of
+``chipbench/tests/data/BENCHMARK.tiny.json`` and the ``per_layer`` list of the
+real ``BENCHMARK.json``.  Nothing a rehearsal prints is a device number."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from torchsnapshot_tpu.io_preparers.array import HostBufferPool
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+    REAL = json.load(f)
+CELL = "codestral22b.kill-resume"
+# No toy leaf reaches the pool's megabyte, so a rehearsal's ``host_pool``
+# counter is empty and this one reader reads nothing from it.
+NEEDS_A_MEGABYTE_LEAF = "host_reuse_pct.resume"
+
+
+@pytest.fixture(scope="module")
+def lines(tmp_path_factory):
+    """The result lines of the two rehearsals, ``[untraced, traced]``."""
+    tmp = tmp_path_factory.mktemp("benchmark_surface")
+    with open(os.path.join(ROOT, "chipbench", "tests", "data", "BENCHMARK.tiny.json")) as f:
+        tiny = json.load(f)
+    toy_cells = [w["name"] for w in tiny["workloads"] if w["traffic"] == "kill-resume"]
+    assert CELL in toy_cells
+    bench = dict(
+        REAL,
+        configs=tiny["configs"],
+        workloads=tiny["workloads"],
+        per_layer=[dict(m, workloads=toy_cells) for m in REAL["per_layer"]],
+    )
+    bench_file = tmp / "BENCHMARK.json"
+    bench_file.write_text(json.dumps(bench))
+    # The child's environment as chipbench/tests/test_rehearsal.py makes it,
+    # less the eight virtual devices of tests/conftest.py: a cell asks for one.
+    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_RUN="ignored", TMPDIR=str(tmp))
+    env["XLA_FLAGS"] = " ".join(
+        flag
+        for flag in env.get("XLA_FLAGS", "").split()
+        if not flag.startswith("--xla_force_host_platform_device_count")
+    )
+    procs = [
+        subprocess.Popen(
+            [sys.executable, os.path.join(ROOT, REAL["command"][1]),
+             "--benchmark", str(bench_file), "--workload", CELL, "--seed", "3000000019",
+             "--seconds", "1.5", "--rehearsal", "--trace", str(trace)],
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, cwd=ROOT, env=env,
+        )
+        for trace in (0, 1)
+    ]
+    out = []
+    try:
+        for proc in procs:
+            stdout, stderr = proc.communicate(timeout=600)
+            assert proc.returncode == 0, stderr[-3000:]
+            out.append(json.loads(stdout.strip().splitlines()[-1]))
+    finally:
+        for proc in procs:
+            if proc.poll() is None:
+                proc.kill()
+                proc.wait()
+    return out
+
+
+def test_the_untraced_line_is_the_contracts(lines):
+    line = lines[0]
+    assert line["rehearsal"] is True
+    assert line["correct"] is True, (line["checks"], line["notes"])
+    assert line["attempted"] > 0 and line["failed"] == 0
+    for m in REAL["end_to_end"]:
+        got = line["metrics"][m["name"]]
+        assert got["value"] > 0 and got["unit"] == m["unit"]
+
+
+@pytest.mark.parametrize("metric", REAL["per_layer"], ids=lambda m: m["name"])
+def test_per_layer_metric_finds_what_it_reads(metric, lines):
+    if metric["layer"] == "Device":
+        pytest.skip("read from the device: a CPU rehearsal has nothing to show it")
+    if metric["name"] == NEEDS_A_MEGABYTE_LEAF:
+        # what its reader takes from the counter (chipbench/metrics/<name>.py)
+        assert {"bytes", "fresh"} <= set(HostBufferPool().stats())
+        return
+    line = lines[1]
+    assert line["correct"] is True and line["failed"] == 0
+    got = line["metrics"].get(metric["name"])
+    assert got is not None, (
+        f"{metric['name']}: its reader found nothing in a traced restore "
+        f"(a phase renamed or no longer fired?); reported: {sorted(line['metrics'])}"
+    )
+    assert isinstance(got["value"], (int, float)) and got["unit"] == metric["unit"]

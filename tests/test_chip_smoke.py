@@ -1,5 +1,5 @@
 """chip_smoke.py: its body at toy size on the CPU mesh, its refusal to run
-without a chip, and the compile-cache helper it shares with bench.py and
+without a chip, and the compile-cache helper it shares with
 __graft_entry__.py."""
 
 import json

@@ -1,12 +1,11 @@
-"""Fleet telemetry plane + barrier timing + perf-trajectory gate.
+"""Fleet telemetry plane + barrier timing.
 
 Covers telemetry/fleet.py (atomic spool publish, stale aging, collector
 aggregation, merged Prometheus), the `tpusnap top` CLI, the
 LinearBarrier barrier_wait phase + store-exchanged arrival stamps, the
 cache single-flight wait metering (cache_wait phase / cache.wait event /
-counter), and tools/bench_trajectory.py's trailing-median regression
-gate.  The multi-process aggregation test reuses the bench.py
-``--serve-worker`` harness, so the spool sees real worker processes and
+counter).  The multi-process aggregation test spawns
+``tests/serve_worker.py``, so the spool sees real worker processes and
 `top --json` totals are cross-checked against the per-worker `serve`
 telemetry sidecars.
 """
@@ -29,8 +28,7 @@ from torchsnapshot_tpu.telemetry import monitor as tmonitor
 from torchsnapshot_tpu.telemetry import sidecar as tsidecar
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO_ROOT, "bench.py")
-TRAJECTORY = os.path.join(REPO_ROOT, "tools", "bench_trajectory.py")
+SERVE_WORKER = os.path.join(REPO_ROOT, "tests", "serve_worker.py")
 
 OP = "feedc0dedeadbeef" * 2
 
@@ -298,7 +296,7 @@ def _state(nbytes_per_leaf=1 << 19, leaves=4, seed=3):
 
 
 def test_multiprocess_fleet_aggregation(tmp_path, capsys):
-    """The acceptance scenario: N bench serve workers publish into one
+    """The acceptance scenario: N serve workers publish into one
     spool; `top --json` reports all N worker processes and its aggregated
     cache totals equal the sums from the per-worker `serve` telemetry
     sidecars; stale aging then empties the view."""
@@ -316,7 +314,7 @@ def test_multiprocess_fleet_aggregation(tmp_path, capsys):
     env.pop("TPUSNAP_FAULTS", None)
     procs = [
         subprocess.Popen(
-            [sys.executable, BENCH, "--serve-worker", snap_path],
+            [sys.executable, SERVE_WORKER, snap_path],
             stdout=subprocess.PIPE,
             stderr=subprocess.PIPE,
             text=True,
@@ -487,60 +485,3 @@ def test_warm_and_serve_cli_write_sidecars(tmp_path, capsys):
     assert cli_main(["stats", snap_path]) == 0
     out = capsys.readouterr().out
     assert "warm" in out and "serve" in out
-
-
-# ------------------------------------------------------- trajectory gate
-
-
-def _write_round(path, value, incomplete=False, backend="cpu"):
-    doc = {
-        "metric": "m",
-        "value": value,
-        "unit": "GB/s",
-        "vs_baseline": 1.0,
-        "backend": backend,
-        "aux": {"incomplete": True} if incomplete else {},
-    }
-    with open(path, "w") as f:
-        json.dump(doc, f)
-
-
-def _run_trajectory(args):
-    proc = subprocess.run(
-        [sys.executable, TRAJECTORY, *args],
-        capture_output=True,
-        text=True,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    return proc.returncode, proc.stdout
-
-
-def test_trajectory_flags_injected_regression(tmp_path):
-    """Six healthy rounds then a 10x-slower one: the gate must flag it
-    and exit nonzero with --fail-on-regression."""
-    for i in range(1, 7):
-        _write_round(tmp_path / f"BENCH_r{i:02d}.json", 2.0)
-    _write_round(tmp_path / "BENCH_r07.json", 0.2)
-    rc, out = _run_trajectory([str(tmp_path), "--fail-on-regression"])
-    assert rc == 1, out
-    assert "REGRESSION" in out
-
-
-def test_trajectory_skips_incomplete_and_mixed_backends(tmp_path):
-    """Incomplete rounds and other-backend rounds must not poison the
-    baseline: a TPU 0.02 GB/s round is not a CPU regression."""
-    for i in range(1, 7):
-        _write_round(tmp_path / f"BENCH_r{i:02d}.json", 2.0)
-    _write_round(tmp_path / "BENCH_r07.json", 0.02, backend="tpu")
-    _write_round(tmp_path / "BENCH_r08.json", 0.01, incomplete=True)
-    _write_round(tmp_path / "BENCH_r09.json", 2.1)
-    rc, out = _run_trajectory([str(tmp_path), "--fail-on-regression"])
-    assert rc == 0, out
-    assert "skipped" in out
-
-
-def test_trajectory_clean_on_real_bank():
-    """The banked repo rounds must pass the gate (this is the check.sh
-    gate line, asserted here so a regression in the TOOL fails tier-1)."""
-    rc, out = _run_trajectory([REPO_ROOT, "--fail-on-regression"])
-    assert rc == 0, out

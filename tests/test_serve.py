@@ -27,7 +27,7 @@ from torchsnapshot_tpu.io_types import ReadIO, StoragePlugin, WriteIO
 from torchsnapshot_tpu.manager import SnapshotManager
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO_ROOT, "bench.py")
+SERVE_WORKER = os.path.join(REPO_ROOT, "tests", "serve_worker.py")
 
 
 def _payload_read_bytes() -> int:
@@ -615,7 +615,7 @@ def _spawn_serve_workers(snap_path, n, cache_dir):
     env.pop("TPUSNAP_FAULTS", None)
     procs = [
         subprocess.Popen(
-            [sys.executable, BENCH, "--serve-worker", snap_path],
+            [sys.executable, SERVE_WORKER, snap_path],
             stdout=subprocess.PIPE,
             stderr=subprocess.PIPE,
             text=True,

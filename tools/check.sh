@@ -12,14 +12,15 @@
 #                                the gate here always lints everything).
 #   2. tpusnap lint --external — ruff + mypy when installed (skip = ok);
 #                                mypy runs _analysis/ at non-lenient settings
-#   3. bench trajectory        — banked BENCH_r*/SERVE_r* rounds vs their
-#                                trailing medians (perf-regression gate)
-#   4. tier-1 pytest           — the ROADMAP verify suite (not slow-marked)
-#   5. sanitizer smoke         — TSAN race-regression legs, only when the
+#   3. tier-1 pytest           — the ROADMAP verify suite (not slow-marked)
+#   4. sanitizer smoke         — TSAN race-regression legs, only when the
 #                                toolchain can build+host the instrumented
 #                                library (the suite itself skips otherwise)
 #
-# Usage: tools/check.sh [--fast]   (--fast = lint + trajectory, no pytest)
+# Speed is not judged here: the benchmark is BENCHMARK.json, run on the chip
+# (chipbench/run.py), and its record is PERF_LEDGER.jsonl.
+#
+# Usage: tools/check.sh [--fast]   (--fast = lint only, no pytest)
 
 set -u -o pipefail
 
@@ -35,13 +36,6 @@ python -m torchsnapshot_tpu lint "$REPO_ROOT" || fail=1
 
 step "tpusnap lint --external (ruff + mypy; missing tools skip)"
 python -m torchsnapshot_tpu lint "$REPO_ROOT" --external || fail=1
-
-# Perf-trajectory gate: the banked BENCH_r*/SERVE_r* rounds folded into
-# per-series trends with trailing-median regression detection (reuses
-# telemetry/history.py's logic) — a PR that tanks a banked number fails
-# here, not in the next human's head.
-step "bench trajectory (banked rounds, trailing-median regression gate)"
-python tools/bench_trajectory.py "$REPO_ROOT" --fail-on-regression || fail=1
 
 if [ "${1:-}" = "--fast" ]; then
   [ "$fail" -eq 0 ] && echo "check.sh --fast: OK" || echo "check.sh --fast: FAILED"

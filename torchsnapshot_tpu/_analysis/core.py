@@ -24,7 +24,7 @@ from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 # Directories the walker descends into, relative to the project root.
-SCAN_DIRS = ("torchsnapshot_tpu", "tests", "benchmarks", "examples")
+SCAN_DIRS = ("torchsnapshot_tpu", "tests", "examples")
 # Directory basenames never descended into.  ``analysis_fixtures`` holds
 # the golden rule-trigger snippets — deliberate violations that must fail
 # only their own test, never the repo-wide lint.
@@ -199,7 +199,7 @@ def _load_module(path: str, rel: str) -> ModuleFile:
 
 def iter_python_files(root: str) -> Iterable[Tuple[str, str]]:
     """Yield (abs_path, rel_path) for every lintable .py under the scan
-    roots, plus top-level .py files (bench.py and friends)."""
+    roots, plus top-level .py files (chip_smoke.py and friends)."""
     for entry in sorted(os.listdir(root)):
         full = os.path.join(root, entry)
         if entry.endswith(".py") and os.path.isfile(full):

@@ -26,8 +26,8 @@ source compiles with ``-fsanitize=...`` into a separately-named
 ``libtpusnap-<mode>.so`` so the production library is never replaced by an
 instrumented one.  The race-regression suite (tests/test_native_sanitize.py)
 loads that library in a subprocess with the sanitizer runtime preloaded to
-catch data races in the worker pool; bench.py refuses to bank results while
-the knob is set.  A sanitizer build that fails (toolchain without the
+catch data races in the worker pool; a time taken while the knob is set
+measures the sanitizer.  A sanitizer build that fails (toolchain without the
 runtime) returns None — the data plane then degrades to pure Python rather
 than silently running uninstrumented.
 """

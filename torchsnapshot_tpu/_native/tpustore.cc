@@ -1772,8 +1772,8 @@ int64_t tpusnap_zlib_encode(const void* src, int64_t src_len, void* dst,
 
 // ------------------------------------------------------------ zstd codec
 // Native zstd directly into/out of the compression frame's payload region
-// — the codec the checkpoint hot path actually wants (BENCH_r07: Python
-// zlib at 0.14 GB/s was 15.7 s of a 16.5 s compressed save).  Frames are
+// — the codec the checkpoint hot path actually wants (Python zlib was
+// nearly all of a compressed save on a CPU box; no chip reading).  Frames are
 // standard single-segment zstd frames: the `zstandard` wheel decodes
 // native output and vice versa (the cross-decode matrix in the parity
 // suite pins this).  Availability is runtime-probed (see ZstdApi): built

@@ -1,8 +1,8 @@
 """Pluggable chunk-compression codecs + the self-describing frame format.
 
 Every byte the pipeline persists is raw by default; on object-store-backed
-TPU hosts bytes-on-the-wire is the dominant save/restore cost (round-5
-bench: fs_write ~2-3 GB/s, cloud plugins bottlenecked on payload size).
+TPU hosts bytes-on-the-wire is the dominant save/restore cost (cloud
+plugins are bottlenecked on payload size; not measured on the chip).
 This module is the codec tier the production stacks ship (Orbax/TensorStore
 compress chunks by default): a registry of codecs (``raw``, ``zstd``,
 ``lz4``, plus always-available stdlib ``zlib``) and a 16-byte per-chunk

@@ -481,8 +481,8 @@ def maybe_wrap_faults(
     plugin: StoragePlugin, spec: Optional[str]
 ) -> StoragePlugin:
     """Wrap ``plugin`` when a fault spec is configured.  A spec of
-    ``"none"`` installs the wrapper with zero rules — the overhead probe
-    ``bench.py --faults none`` measures."""
+    ``"none"`` installs the wrapper with zero rules — a pure meter of
+    origin reads and writes, which the serve and kill-chaos tests read."""
     if spec is None or not spec.strip():
         return plugin
     return FaultyStoragePlugin(plugin, parse_fault_spec(spec))

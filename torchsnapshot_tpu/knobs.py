@@ -523,18 +523,6 @@ def override_blackbox_dir(value: Optional[str]) -> Generator[None, None, None]:
         yield
 
 
-@contextmanager
-def override_blackbox_slots(value: int) -> Generator[None, None, None]:
-    with _override_env(BLACKBOX_SLOTS_ENV_VAR, str(value)):
-        yield
-
-
-@contextmanager
-def override_blackbox_slot_bytes(value: int) -> Generator[None, None, None]:
-    with _override_env(BLACKBOX_SLOT_BYTES_ENV_VAR, str(value)):
-        yield
-
-
 def get_regression_factor() -> float:
     """A committed save whose duration exceeds this multiple of the
     trailing-window median (``TPUSNAP_REGRESSION_WINDOW``) emits
@@ -677,12 +665,6 @@ def override_journal_max_segments(value: int) -> Generator[None, None, None]:
         yield
 
 
-@contextmanager
-def override_journal_max_bytes(value: int) -> Generator[None, None, None]:
-    with _override_env(JOURNAL_MAX_BYTES_ENV_VAR, str(value)):
-        yield
-
-
 def native_enabled() -> bool:
     """Whether the native data plane (libtpusnap.so) may be used at all.
     ``TPUSNAP_NATIVE=0`` forces the pure-Python fallback path everywhere —
@@ -701,12 +683,6 @@ def get_native_threads() -> int:
     return max(0, _get_int_env(NATIVE_THREADS_ENV_VAR, 0))
 
 
-@contextmanager
-def override_native(enabled: bool) -> Generator[None, None, None]:
-    with _override_env(NATIVE_ENV_VAR, "1" if enabled else "0"):
-        yield
-
-
 def get_native_batch() -> int:
     """Max payloads the fs plugin's fused write+hash path groups into one
     native batch call (``TPUSNAP_NATIVE_BATCH``): a drain of small write
@@ -714,12 +690,6 @@ def get_native_batch() -> int:
     payload.  ``0``/``1`` disables micro-batching (every payload keeps its
     own call — today's behavior)."""
     return max(0, _get_int_env(NATIVE_BATCH_ENV_VAR, _DEFAULT_NATIVE_BATCH))
-
-
-@contextmanager
-def override_native_batch(value: int) -> Generator[None, None, None]:
-    with _override_env(NATIVE_BATCH_ENV_VAR, str(value)):
-        yield
 
 
 def direct_io_enabled() -> bool:
@@ -733,12 +703,6 @@ def direct_io_enabled() -> bool:
     writeback RAM.  On-disk bytes are identical in every mode, and the
     tmp+fsync+rename durability discipline is unchanged."""
     return _get_bool_env(DIRECT_IO_ENV_VAR)
-
-
-@contextmanager
-def override_direct_io(enabled: bool) -> Generator[None, None, None]:
-    with _override_env(DIRECT_IO_ENV_VAR, "1" if enabled else "0"):
-        yield
 
 
 def get_faults_spec() -> Optional[str]:
@@ -1245,12 +1209,6 @@ def override_cache_dir(value: Optional[str]) -> Generator[None, None, None]:
 
 
 @contextmanager
-def override_cache_max_bytes(value: int) -> Generator[None, None, None]:
-    with _override_env(CACHE_MAX_BYTES_ENV_VAR, str(value)):
-        yield
-
-
-@contextmanager
 def override_partial_reads(enabled: bool) -> Generator[None, None, None]:
     with _override_env(PARTIAL_READS_ENV_VAR, "1" if enabled else "0"):
         yield
@@ -1398,12 +1356,6 @@ def override_peer_fetch(enabled: bool) -> Generator[None, None, None]:
 
 
 @contextmanager
-def override_peer_addr(value: Optional[str]) -> Generator[None, None, None]:
-    with _override_env(PEER_ADDR_ENV_VAR, value):
-        yield
-
-
-@contextmanager
 def override_peer_timeout_s(value: float) -> Generator[None, None, None]:
     with _override_env(PEER_TIMEOUT_S_ENV_VAR, str(value)):
         yield
@@ -1416,20 +1368,8 @@ def override_peer_retries(value: int) -> Generator[None, None, None]:
 
 
 @contextmanager
-def override_peer_grace_s(value: float) -> Generator[None, None, None]:
-    with _override_env(PEER_GRACE_S_ENV_VAR, str(value)):
-        yield
-
-
-@contextmanager
 def override_peer_bad_ttl_s(value: float) -> Generator[None, None, None]:
     with _override_env(PEER_BAD_TTL_S_ENV_VAR, str(value)):
-        yield
-
-
-@contextmanager
-def override_peer_trace_max_spans(value: int) -> Generator[None, None, None]:
-    with _override_env(PEER_TRACE_MAX_SPANS_ENV_VAR, str(value)):
         yield
 
 
@@ -1442,18 +1382,6 @@ def override_peer_trace_flush_s(value: float) -> Generator[None, None, None]:
 @contextmanager
 def override_peer_demote_factor(value: float) -> Generator[None, None, None]:
     with _override_env(PEER_DEMOTE_FACTOR_ENV_VAR, str(value)):
-        yield
-
-
-@contextmanager
-def override_peerd_access_log(value: Optional[str]) -> Generator[None, None, None]:
-    with _override_env(PEERD_ACCESS_LOG_ENV_VAR, value):
-        yield
-
-
-@contextmanager
-def override_peerd_access_log_max_bytes(value: int) -> Generator[None, None, None]:
-    with _override_env(PEERD_ACCESS_LOG_MAX_BYTES_ENV_VAR, str(value)):
         yield
 
 

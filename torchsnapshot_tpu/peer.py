@@ -254,7 +254,7 @@ def reset_peer_scoreboard() -> None:
 
 def calibrated_scoreboard_cost_s(samples: int = 200) -> Dict[str, Any]:
     """Isolated per-update scoreboard cost x updates this process — the
-    scoreboard half of the serve bench's overhead proof (same shape as
+    scoreboard half of a serving worker's overhead bill (same shape as
     trace.calibrated_span_cost_s / fleet.calibrated_overhead_s)."""
     global _SCORE_UPDATES
     updates = _SCORE_UPDATES

@@ -3,7 +3,8 @@
 Two consumers:
 
 - :func:`measure_rss_deltas` — the reference's benchmark context manager:
-  samples RSS deltas on a thread at a fixed interval (benchmarks/*).
+  samples RSS deltas on a thread at a fixed interval (kept for parity
+  with the reference; no caller in this tree but its test).
 - :class:`RSSWatermark` — the health monitor's incremental variant
   (telemetry/monitor.py): no thread of its own; the monitor samples it on
   each progress tick, and the high-water mark lands in the operation's

@@ -472,10 +472,9 @@ class S3StoragePlugin(StoragePlugin):
                         )
                     filled = 0
                     # 8 MB pieces: each iter_content piece is a GIL bounce
-                    # plus a memcpy into the view; 1 MB pieces measurably
-                    # bottlenecked the restore path at ~1/16 of the
-                    # transport's line rate (benchmarks/cloud).  Cancel
-                    # latency stays bounded at one piece.
+                    # plus a memcpy into the view, and 1 MB pieces held the
+                    # restore path well under the transport's line rate.
+                    # Cancel latency stays bounded at one piece.
                     for piece in resp.iter_content(chunk_size=8 << 20):
                         if cancel is not None and cancel.is_set():
                             # Mirror the GCS between-chunk check: a

@@ -23,8 +23,7 @@ Design constraints, in order:
 - **Cheap.**  One JSON encode + one pwrite per record, no locks shared
   with the pipeline, every entry point swallows its own exceptions.
   ``calibrated_overhead_s`` measures the real per-record cost the same
-  way the fleet spool calibrates its publish cost; the bench blackbox
-  probe banks overhead <1% of op wall.
+  way the fleet spool calibrates its publish cost.
 
 Record format: each slot is a newline-terminated, space-padded JSON
 object ``{"seq", "t" (wall clock), "host", "pid", "kind", "name",

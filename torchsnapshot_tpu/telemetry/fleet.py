@@ -35,9 +35,8 @@ Three cooperating pieces:
   periodic publishes self-limit to ``OVERHEAD_BUDGET_FRAC`` of op
   elapsed (preemption-inflated raw cost pausing the beacons under load
   is deliberate backpressure).  :func:`calibrated_overhead_s` prices the
-  honest marginal bill — isolated per-publish cost × publishes — and
-  the serve bench asserts it stays <1% of op wall.  Telemetry that
-  can't price itself gets turned off the first time someone is paged.
+  honest marginal bill — isolated per-publish cost × publishes.  Telemetry
+  that can't price itself gets turned off the first time someone is paged.
 
 With the knob unset (the default) nothing is written and the whole module
 costs one env lookup per monitor tick.
@@ -307,8 +306,8 @@ def _atomic_write_json(path: str, doc: Dict[str, Any]) -> None:
     beacon rewritten every interval and aged out in seconds — crash
     durability buys nothing — and an fsync here lands mid-op, exactly
     when the data plane's own writeback storm makes a journal flush cost
-    tens of ms (measured: the serve bench's terminal-publish fsync alone
-    blew the <1%-of-op-wall telemetry budget 10x).  Same call the
+    tens of ms (a terminal-publish fsync alone once cost ten times the
+    telemetry budget of a serving worker's pull).  Same call the
     heartbeat file makes (monitor.py)."""
     directory = os.path.dirname(path)
     os.makedirs(directory, exist_ok=True)

@@ -33,7 +33,7 @@ profile diff A B`` (differential profile between two runs — the native
 vs fallback / direct-io A/B tool), and the stall watchdog's diagnostic
 bundle (``sample_burst``).  Self-overhead is calibrated estimate-by-
 parts like blackbox's: per-tick sampling cost x ticks, published in
-every profile and banked by the bench as ``profiler_overhead_pct``.
+every profile.
 """
 
 from __future__ import annotations

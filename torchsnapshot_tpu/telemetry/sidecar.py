@@ -5,8 +5,7 @@ Each take/restore persists a small per-rank JSON summary into the snapshot
 itself — phase_stats deltas, throughput, codec and knob values — so "where
 did this 40 s save go" is answerable *after the fact*, from the snapshot
 alone, without logs or an attached tracer.  ``python -m torchsnapshot_tpu
-stats <url>`` renders them; ``bench.py --telemetry`` embeds one in its
-result JSON.
+stats <url>`` renders them.
 
 Sidecars ride the snapshot's own storage plugin (fs/s3/gs/memory all
 work), live under the dot-free ``telemetry/`` prefix — outside every

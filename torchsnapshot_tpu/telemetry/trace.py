@@ -58,9 +58,9 @@ _parent_span: contextvars.ContextVar[Optional[int]] = contextvars.ContextVar(
     "tpusnap_parent_span", default=None
 )
 
-# Process-lifetime span count: the calibration meter the serve bench
-# multiplies by the isolated per-span cost (same estimate-by-parts shape as
-# fleet.calibrated_overhead_s).
+# Process-lifetime span count: the calibration meter that
+# calibrated_span_cost_s multiplies by the isolated per-span cost (same
+# estimate-by-parts shape as fleet.calibrated_overhead_s).
 _SPAN_TOTALS_LOCK = threading.Lock()
 _SPANS_RECORDED = 0
 
@@ -617,7 +617,7 @@ def validate_access_log(path: str) -> List[str]:
 
 def calibrated_span_cost_s(samples: int = 200) -> Dict[str, Any]:
     """Isolated per-span recording cost x spans recorded this process —
-    the tracing half of the serve bench's <1%-of-wall overhead proof
+    the tracing half of a serving worker's overhead bill
     (same estimate-by-parts shape as ``fleet.calibrated_overhead_s``)."""
     spans = spans_recorded()  # snapshot first: probe spans are not workload
     probe = _TraceOp("calibration", "calibration", 0, trace_dir="")

@@ -2,9 +2,9 @@
 
 The cache key includes the cache path, so a directory that moves never
 hits: the path is either the one the caller's environment names or one fixed
-directory inside the checkout.  Nothing here runs at import; ``chip_smoke.py``,
-``bench.py`` and ``__graft_entry__.py`` call :func:`place_compile_cache`
-before their first compile, and the library itself sets no cache.
+directory inside the checkout.  Nothing here runs at import; ``chip_smoke.py``
+and ``__graft_entry__.py`` call :func:`place_compile_cache` before their
+first compile, and the library itself sets no cache.
 """
 
 from __future__ import annotations
