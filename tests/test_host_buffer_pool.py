@@ -1,13 +1,16 @@
-"""Host read buffers that are reused (``io_preparers.array.HostBufferPool``,
-one a ``Snapshot.restore``): a leaf's buffer is taken when its read is
-dispatched and given back when its H2D has landed, to the next leaf of the
-same byte size, which waits for it where it is still landing.  Restores of
-train-state-shaped trees (three statefuls, one tree) through the fs plug-in,
-the reads one at a time so that each has its number; then the pool alone."""
+"""The host arena of a restore (``io_preparers.array.HostBufferPool``, one a
+``Snapshot.restore``): a leaf takes a page-aligned range of it when its read is
+dispatched and the lander gives the range back when the H2D has landed, to
+whichever read comes next, of any size and any stateful, which waits for room
+where there is none and makes the batchers flush.  Restores of train-state-
+shaped trees (three statefuls, one tree) through the fs plug-in, the reads one
+at a time so that each has its number; then the pool alone."""
 
 import asyncio
+import functools
 import gc
 import os
+import random
 import sys
 import threading
 import time
@@ -27,12 +30,13 @@ from torchsnapshot_tpu.event_handlers import (
 )
 from torchsnapshot_tpu.integrity import ChecksumError
 from torchsnapshot_tpu.io_preparers import array as array_mod
-from torchsnapshot_tpu.io_preparers.array import HostBufferPool
+from torchsnapshot_tpu.io_preparers.array import H2DBatcher, HostBufferPool
 from torchsnapshot_tpu.io_types import StoragePlugin
 
 KEYS = ("a_params", "b_mu", "c_nu")  # loaded in the order of their names
-# float32 leaves of 1, 2 and 3 MiB: from the size a read lands in place, and
-# no two of a stateful alike, so a leaf's buffer can only come from its twin
+MIB = 1 << 20
+PAGE = array_mod._PAGE
+# float32 leaves of 1, 2 and 3 MiB: from the size a read lands in place
 SHAPES = ((256, 1024), (512, 1024), (768, 1024))
 LEAVES = len(SHAPES)
 STATEFUL_BYTES = sum(4 * rows * cols for rows, cols in SHAPES)
@@ -65,14 +69,15 @@ def assert_equal_bits(target, saved):
             )
 
 
-def buffer_at(nbytes, offset_from_64):
-    """A flat uint8 buffer that begins ``offset_from_64`` bytes past a 64-byte
-    boundary.  At 16, where a large ``np.empty`` begins, the CPU backend's
-    ``device_put`` copies it, as an accelerator does; at 0 it takes the memory
-    as the array itself."""
+def memory_at(nbytes, offset_from_64):
+    """``(raw, buf)``: a flat uint8 buffer that begins ``offset_from_64`` bytes
+    past a 64-byte boundary, and the allocation under it.  At 16, where a large
+    ``np.empty`` begins, the CPU backend's ``device_put`` copies it, as an
+    accelerator does, and so it does every range a whole number of pages on;
+    at 0 it takes the memory as the array itself."""
     raw = np.empty(nbytes + 128, dtype=np.uint8)
     offset = (offset_from_64 - raw.ctypes.data) % 64
-    return raw[offset : offset + nbytes]
+    return raw, raw[offset : offset + nbytes]
 
 
 class Faulty(StoragePlugin):
@@ -117,14 +122,20 @@ class Faulty(StoragePlugin):
 
 @pytest.fixture
 def world(monkeypatch, tmp_path):
-    """Every seam of the pool recorded: the pools made (weakly), the buffers
-    made (weakly, and where they begin), the reads into place."""
+    """The CPU backend taken for an accelerator (``accelerator``: it keeps no
+    host memory, and the arena begins where it copies), and every seam of the
+    pool recorded: the pools made (weakly), the arenas and the plain buffers
+    made (weakly, their sizes, and where they begin), the reads into place."""
     w = types.SimpleNamespace(
         root=str(tmp_path),
         pools=[],
-        buffers=[],
+        arenas=[],
+        arena_sizes=[],
+        plain=[],
         addresses=[],
+        accelerator=True,
         offset_from_64=16,
+        batcher_args={},
         made_before=0,
         reads=[],
         fails=None,
@@ -137,10 +148,30 @@ def world(monkeypatch, tmp_path):
             w.pools.append(weakref.ref(self))
 
     monkeypatch.setattr(snapshot_mod, "HostBufferPool", RecordedPool)
+    monkeypatch.setattr(
+        snapshot_mod,
+        "H2DBatcher",
+        lambda **kwargs: H2DBatcher(**w.batcher_args, **kwargs),
+    )
+    really_keeps = array_mod._keeps_host_memory
+    monkeypatch.setattr(
+        array_mod,
+        "_keeps_host_memory",
+        lambda target: not w.accelerator and really_keeps(target),
+    )
+
+    def recorded_arena(nbytes):
+        raw, buf = memory_at(nbytes, w.offset_from_64)
+        w.arenas.append(weakref.ref(raw))
+        w.arena_sizes.append(nbytes)
+        w.addresses.append((buf.ctypes.data, nbytes))
+        return buf
+
+    monkeypatch.setattr(array_mod, "_arena_memory", recorded_arena)
 
     def recorded_buffer(nbytes):
-        buf = buffer_at(nbytes, w.offset_from_64)
-        w.buffers.append(weakref.ref(buf))
+        buf = np.empty(nbytes, dtype=np.uint8)
+        w.plain.append(weakref.ref(buf))
         w.addresses.append((buf.ctypes.data, nbytes))
         return buf
 
@@ -150,7 +181,8 @@ def world(monkeypatch, tmp_path):
 
     def plan_that_allocates_nothing(*args):
         plan = real_plan(*args)
-        assert len(w.buffers) == w.made_before, "a host buffer was made at plan time"
+        made = len(w.arenas) + len(w.plain)
+        assert made == w.made_before, "host memory was taken at plan time"
         return plan
 
     monkeypatch.setattr(
@@ -175,47 +207,69 @@ def take(world, name, app):
     return path
 
 
-def restore(world, path, target):
-    """The restore's ``host_pool`` counter and its ``restore.end`` event."""
-    ends = []
+def restore(world, path, target, within_s=60.0):
+    """The restore's ``host_pool`` counter and its ``restore.end`` event.  On a
+    thread of its own, so that a restore that waits on itself fails its test
+    and does not hang the run."""
+    ends, raised = [], []
 
     def on_event(event):
         if event.name == "restore.end":
             ends.append(dict(event.metadata))
 
+    def run():
+        try:
+            Snapshot(path).restore(target)
+        except BaseException as e:  # noqa: BLE001 -- raised again below
+            raised.append(e)
+
     world.reads.clear()
-    world.made_before = len(world.buffers)
+    world.made_before = len(world.arenas) + len(world.plain)
     before = phase_stats.snapshot()
     register_event_handler(on_event)
     try:
-        Snapshot(path).restore(target)
+        runner = threading.Thread(target=run, name="restore-under-test", daemon=True)
+        runner.start()
+        runner.join(timeout=within_s)
+        assert not runner.is_alive(), f"the restore did not end within {within_s} s"
     finally:
         unregister_event_handler(on_event)
+    if raised:
+        error = raised.pop()  # (a kept traceback would hold the pool)
+        raise error.with_traceback(None)
     (end,) = ends
     return phase_stats.delta(before).get("host_pool"), end
 
 
 def nothing_left(world):
-    """No pool, no buffer the restored arrays do not own, no thread."""
+    """No pool, no arena, no plain buffer the restored arrays do not own, no
+    thread."""
     deadline = time.monotonic() + 5.0
     while [t for t in threading.enumerate() if t.name in PIPELINE_THREADS]:
         if time.monotonic() > deadline:
             return False
         time.sleep(0.01)
     gc.collect()
-    return all(ref() is None for ref in world.pools)
+    return all(ref() is None for ref in world.pools + world.arenas + world.plain)
+
+
+def restored_leaves(target):
+    return [leaf for sd in target.values() for leaf in sd.state_dict().values()]
 
 
 # ------------------------------------------------------------ the restore
 
 
-def test_three_same_shaped_statefuls_read_into_the_first_ones_buffers(world):
+def test_three_same_shaped_statefuls_are_read_into_one_arena(world):
     saved = make_app(1)
     path = take(world, "snap", saved)
     target = make_app(0, zero=True)
     counter, end = restore(world, path, target)
     assert_equal_bits(target, saved)
-    # the second and the third stateful made no buffer of their own
+    # one arena of one stateful's bytes (the window is larger, and a restore
+    # needs no more than its largest stateful), and no other host buffer: the
+    # second and the third stateful touched no page the first had not
+    assert world.arena_sizes == [STATEFUL_BYTES] and not world.plain
     assert end["host_pool"] == {
         "bytes": 2 * STATEFUL_BYTES,
         "fresh": STATEFUL_BYTES,
@@ -223,19 +277,48 @@ def test_three_same_shaped_statefuls_read_into_the_first_ones_buffers(world):
         "misses": LEAVES,
         "high_water": STATEFUL_BYTES,
     }
-    assert len(world.buffers) == LEAVES
-    # because a read whose twin was still landing waited for it
+    # because a read that found no room waited for a landing
     assert end["phases"]["host_buffer_wait"] > 0
-    # and what the pool held went once, with a name, when the last had loaded
+    # and the arena went once, with a name, when the last had loaded
     assert end["phases"]["host_pool_free"] > 0
     # the counter holds the same numbers, once a restore
     assert counter["n"] == 1 and counter["s"] == 0
     assert {k: counter[k] for k in end["host_pool"]} == end["host_pool"]
     assert len(world.pools) == 1 and nothing_left(world)
-    assert all(ref() is None for ref in world.buffers)
 
 
-def test_sizes_that_never_repeat_restore_as_without_a_pool(world):
+@pytest.mark.parametrize(
+    "batcher",
+    ["every_leaf_over_the_flush_threshold", "every_leaf_under_the_flush_threshold"],
+)
+def test_the_first_stateful_reuses_its_own_pages(world, batcher):
+    """One stateful of six 2 MiB leaves behind a window of 4 MiB: the arena is
+    two leaves, a third of the stateful, and four of the six reads land in
+    pages this very stateful has landed from.  With every leaf far under the
+    batcher's flush threshold nothing would ever land of itself before the
+    drain, which waits for these very reads: the read that waits for room
+    makes the batcher flush."""
+    if batcher == "every_leaf_over_the_flush_threshold":
+        world.batcher_args.update(flush_bytes=2 * MIB)
+    else:
+        world.batcher_args.update(flush_bytes=256 * MIB, inflight_cap_bytes=4 * MIB)
+    shapes, keys = ((512, 1024),) * 6, ("only",)
+    saved = make_app(9, shapes=shapes, keys=keys)
+    path = take(world, "snap", saved)
+    target = make_app(0, shapes=shapes, keys=keys, zero=True)
+    _, end = restore(world, path, target)
+    assert_equal_bits(target, saved)
+    arena = 4 * MIB
+    assert world.arena_sizes == [arena] and not world.plain
+    pool = end["host_pool"]
+    assert pool["fresh"] == pool["high_water"] == arena
+    assert pool["bytes"] == 12 * MIB - arena
+    assert pool["misses"] == 2 and pool["hits"] == 4
+    assert end["phases"]["host_buffer_wait"] > 0
+    assert nothing_left(world)
+
+
+def test_sizes_that_never_repeat_share_the_arena_all_the_same(world):
     shapes = {
         0: ((256, 1024), (320, 1024)),
         1: ((384, 1024), (448, 1024)),
@@ -246,48 +329,63 @@ def test_sizes_that_never_repeat_restore_as_without_a_pool(world):
     target = make_app(0, shapes=shapes, zero=True)
     _, end = restore(world, path, target)
     assert_equal_bits(target, saved)
-    pool = end["host_pool"]
-    assert pool["hits"] == 0 and pool["bytes"] == 0 and pool["misses"] == 6
     group_bytes = [sum(4 * r * c for r, c in shapes[k]) for k in shapes]
-    assert pool["fresh"] == sum(group_bytes)
-    assert pool["high_water"] <= sum(sorted(group_bytes)[-2:])
-    assert nothing_left(world) and all(ref() is None for ref in world.buffers)
+    assert world.arena_sizes == [max(group_bytes)] and not world.plain
+    pool = end["host_pool"]
+    # a range is any size: a leaf lands where leaves of other sizes landed
+    assert pool["hits"] >= 2 and pool["hits"] + pool["misses"] == 6
+    assert pool["bytes"] + pool["fresh"] == sum(group_bytes)
+    assert pool["fresh"] <= pool["high_water"] <= max(group_bytes)
+    assert nothing_left(world)
 
 
-def test_a_buffer_the_landed_array_aliases_is_never_read_into_again(world):
-    # 64-byte aligned: the CPU backend's device_put copies nothing, the
-    # restored array IS the host buffer
-    world.offset_from_64 = 0
+@pytest.mark.parametrize("seen", ["at_plan_time", "only_at_the_landing"])
+def test_a_backend_that_keeps_host_memory_gets_no_arena(world, seen):
+    """The CPU backend's ``device_put`` of a 64-byte-aligned host array copies
+    nothing: the restored array IS the host memory.  Seen from the targets'
+    devices when the leaves are reserved, there is no arena at all.  Were it
+    not seen there (the backend taken for an accelerator, the arena at a
+    64-byte boundary), the lander sees it of each landed array: the range is
+    never handed out again, and nor is any other."""
+    if seen == "at_plan_time":
+        world.accelerator = False
+    else:
+        world.offset_from_64 = 0
     saved, other = make_app(3), make_app(4)
     path, other_path = take(world, "snap", saved), take(world, "other", other)
     target = make_app(0, zero=True)
     _, end = restore(world, path, target)
-    restored = [leaf for sd in target.values() for leaf in sd.state_dict().values()]
-    aliased = [
-        leaf
-        for leaf in restored
-        if any(
-            begin <= leaf.unsafe_buffer_pointer() < begin + nbytes
-            for begin, nbytes in world.addresses
-        )
-    ]
-    assert len(aliased) == len(restored) == len(KEYS) * LEAVES
-    # not one buffer was used twice, though each read waited for its twin
-    assert end["host_pool"]["hits"] == 0
-    assert end["host_pool"]["misses"] == len(KEYS) * LEAVES
     assert_equal_bits(target, saved)
+    # not one page was used twice
+    assert end["host_pool"]["hits"] == 0 and end["host_pool"]["bytes"] == 0
+    assert end["host_pool"]["misses"] == len(KEYS) * LEAVES
+    assert end["host_pool"]["fresh"] == len(KEYS) * STATEFUL_BYTES
+    if seen == "at_plan_time":
+        assert not world.arenas and len(world.plain) == len(KEYS) * LEAVES
+    else:
+        aliased = [
+            leaf
+            for leaf in restored_leaves(target)
+            if any(
+                begin <= leaf.unsafe_buffer_pointer() < begin + nbytes
+                for begin, nbytes in world.addresses[:1]
+            )
+        ]
+        assert len(world.arenas) == 1 and len(aliased) == LEAVES
+        assert len(world.plain) == (len(KEYS) - 1) * LEAVES
+        del aliased
     # and another snapshot restored through the same code changes nothing
     second = make_app(0, zero=True)
     restore(world, other_path, second)
     assert_equal_bits(second, other)
     assert_equal_bits(target, saved)
-    del restored, aliased
+    del target, second
     assert nothing_left(world)
 
 
 @pytest.mark.parametrize("fault", ["corrupt", "truncated", "silently_short"])
-def test_a_bad_read_into_a_recycled_buffer_raises(world, fault):
-    """``b_mu/w1`` lands in the buffer ``a_params/w1`` landed from, which still
+def test_a_bad_read_into_a_range_used_before_raises(world, fault):
+    """``b_mu/w1`` lands in the range ``a_params/w1`` landed from, which still
     holds those bytes: a read that does not fill it must not pass for one
     that did."""
     saved = make_app(5)
@@ -313,10 +411,8 @@ def test_a_bad_read_into_a_recycled_buffer_raises(world, fault):
         restore(world, path, target)
     del raises  # and with it the traceback, whose frames hold the pool
     assert world.reads[LEAVES + 1].endswith("b_mu/w1")
-    # the buffer was a recycled one (where the read itself raised, the read
-    # behind it may have gone ahead of its twin's landing and made its own)
-    assert len(world.buffers) == LEAVES or fault == "truncated"
-    assert len(world.buffers) <= LEAVES + 1
+    # the memory was the one arena's
+    assert len(world.arenas) == 1 and not world.plain
     # nothing stale was handed back: the stateful before is as saved (or not
     # yet loaded, where the read itself raised), the others untouched
     if fault != "truncated" or np.asarray(target["a_params"].state_dict()["w0"]).any():
@@ -324,11 +420,13 @@ def test_a_bad_read_into_a_recycled_buffer_raises(world, fault):
     for key in ("b_mu", "c_nu"):
         for leaf in target[key].state_dict().values():
             assert not np.asarray(leaf).any()
-    assert nothing_left(world) and all(ref() is None for ref in world.buffers)
+    assert nothing_left(world)
 
 
-@pytest.mark.parametrize("failure", ["read_fails", "load_raises", "device_put_fails"])
-def test_a_failure_leaves_no_buffer_in_flight_and_no_pool_behind(
+@pytest.mark.parametrize(
+    "failure", ["read_fails", "load_raises", "device_put_fails", "landing_fails"]
+)
+def test_a_failure_returns_its_range_and_leaves_no_arena_behind(
     world, monkeypatch, failure
 ):
     saved = make_app(6)
@@ -347,9 +445,23 @@ def test_a_failure_leaves_no_buffer_in_flight_and_no_pool_behind(
         with pytest.raises(RuntimeError, match="user code failed"):
             restore(world, path, target)
         assert_equal_bits({"a_params": target["a_params"]}, {"a_params": saved["a_params"]})
+    elif failure == "landing_fails":
+        # no transfer is seen to land: every range comes back unfit, so none
+        # is handed out again, and the restore raises at its first drain
+        real_ready = jax.block_until_ready
+
+        def never_lands(x):
+            if threading.current_thread().name == "tpusnap-h2d-lander":
+                raise RuntimeError("injected landing failure")
+            return real_ready(x)
+
+        monkeypatch.setattr(jax, "block_until_ready", never_lands)
+        with pytest.raises(RuntimeError, match="injected landing failure"):
+            restore(world, path, target)
+        monkeypatch.setattr(jax, "block_until_ready", real_ready)
     else:
         # every batched device_put fails: each leaf goes the per-item way,
-        # lands there, and its buffer is given back all the same
+        # lands there, and its range is given back all the same
         real_put = jax.device_put
         # (the warning's traceback, kept by the log capture, would hold the pool)
         monkeypatch.setattr(array_mod.logger, "warning", lambda *args, **kwargs: None)
@@ -365,13 +477,13 @@ def test_a_failure_leaves_no_buffer_in_flight_and_no_pool_behind(
         assert_equal_bits(target, saved)
         assert end["host_pool"]["hits"] == 2 * LEAVES
         assert end["host_pool"]["high_water"] == STATEFUL_BYTES
+    assert len(world.arenas) == 1 and (not world.plain or failure == "landing_fails")
     assert nothing_left(world)
-    assert all(ref() is None for ref in world.buffers)
 
 
 def test_chunked_leaves_go_through_the_pool(world):
     # the path of a leaf at the chunk knob, at toy size: four reads a leaf
-    # into one buffer, taken when the first of them is dispatched
+    # into one range, taken when the first of them is dispatched
     shapes = ((1024, 1024),) * LEAVES
     leaf = 4 << 20
     saved = make_app(7, shapes=shapes)
@@ -383,14 +495,13 @@ def test_chunked_leaves_go_through_the_pool(world):
     _, end = restore(world, path, target)
     assert_equal_bits(target, saved)
     assert len(world.reads) == len(KEYS) * LEAVES * 4
-    # one take a leaf, not a read; a leaf of the first stateful may already
-    # read into the buffer of one before it (they are all of one size)
+    # one take a leaf, not a read
     pool = end["host_pool"]
-    assert pool["hits"] + pool["misses"] == len(KEYS) * LEAVES
-    assert pool["hits"] >= 2 * LEAVES and len(world.buffers) == pool["misses"]
+    assert world.arena_sizes == [LEAVES * leaf] and not world.plain
+    assert pool["misses"] == LEAVES and pool["hits"] == 2 * LEAVES
     assert pool["bytes"] == pool["hits"] * leaf
     assert pool["fresh"] == pool["high_water"] == pool["misses"] * leaf
-    assert nothing_left(world) and all(ref() is None for ref in world.buffers)
+    assert nothing_left(world)
 
 
 def test_what_is_not_uploaded_through_the_batcher_never_touches_the_pool(world):
@@ -413,7 +524,7 @@ def test_what_is_not_uploaded_through_the_batcher_never_touches_the_pool(world):
     assert end["host_pool"] == dict.fromkeys(
         ("bytes", "fresh", "hits", "misses", "high_water"), 0
     )
-    assert not world.buffers
+    assert not world.arenas and not world.plain
     got = Snapshot(path).read_object("0/host/w")
     np.testing.assert_array_equal(got, saved["host"].state_dict()["w"])
     assert len(world.pools) == 1 and nothing_left(world)
@@ -421,152 +532,258 @@ def test_what_is_not_uploaded_through_the_batcher_never_touches_the_pool(world):
 
 # ----------------------------------------------------------- the pool alone
 
+ON_A_CHIP = types.SimpleNamespace(devices=lambda: [types.SimpleNamespace(platform="tpu")])
+ON_THE_HOST = types.SimpleNamespace(devices=lambda: [types.SimpleNamespace(platform="cpu")])
 
-def reserved(pool, *groups):
+
+class Batcher:
+    """What the pool knows of an ``H2DBatcher``."""
+
+    def __init__(self, inflight_cap_bytes):
+        self.inflight_cap_bytes = inflight_cap_bytes
+        self.flushes = 0
+
+    def flush(self):
+        self.flushes += 1
+
+
+def pool_of(*groups, window, target=ON_A_CHIP):
+    """A pool with ``groups`` of leaves reserved, in pages, behind one
+    batcher (to be kept alive) with an in-flight cap of ``window`` pages: the
+    arena's size, where no leaf is larger and a stateful is."""
+    pool, batcher = HostBufferPool(), Batcher(window * PAGE)
+    pool.attach(batcher)
     for group in groups:
         pool.begin_group()
-        for nbytes in group:
-            pool.reserve(nbytes)
+        for pages in group:
+            pool.reserve(pages * PAGE, target)
+    return pool, batcher
 
 
-def test_the_pool_hands_a_buffer_to_the_next_taker_of_its_size_only():
-    pool = HostBufferPool()
-    reserved(pool, [100, 200], [100, 200], [100, 300])
-    a, b = pool.take(100, 0), pool.take(200, 0)
-    assert a.dtype == np.uint8 and a.shape == (100,) and b.shape == (200,)
+def in_arena(buf, base, pages):
+    return base <= buf.ctypes.data and buf.ctypes.data + buf.nbytes <= base + pages * PAGE
+
+
+def test_a_take_is_a_range_a_give_frees_it_and_neighbours_coalesce():
+    pool, _batcher = pool_of([4, 2, 2], [4, 2, 2], [4, 2, 2], window=8)
+    a = pool.take(4 * PAGE - 100)  # any size: the range is whole pages
+    b, c = pool.take(2 * PAGE), pool.take(2 * PAGE)
+    assert a.dtype == np.uint8 and a.shape == (4 * PAGE - 100,) and b.shape == (2 * PAGE,)
+    base = a.ctypes.data
+    assert base % PAGE == 0
+    assert (b.ctypes.data, c.ctypes.data) == (base + 4 * PAGE, base + 6 * PAGE)
+    # full: a take that cannot wait is a plain buffer, and counted as one
+    plain = pool.take(PAGE)
+    assert not in_arena(plain, base, 8)
+    pool.give(plain, recycle=True)
     pool.give(a, recycle=True)
-    pool.give(b, recycle=True)
-    assert pool.take(100, 0) is a and pool.take(200, 0) is b
-    pool.give(a, recycle=True)
-    assert pool.take(100, 0) is a
-    assert pool.take(300, 0) is not b  # 300 is no size seen before
+    pool.give(c, recycle=True)
+    # [0, 4) and [6, 8) are free, and five pages fit in neither
+    assert not in_arena(pool.take(5 * PAGE), base, 8)
+    # first fit from the lowest address, where the pages have been touched
+    d = pool.take(PAGE)
+    assert d.ctypes.data == base
+    pool.give(d, recycle=True)
+    pool.give(b, recycle=True)  # one with both its neighbours
+    whole = pool.take(8 * PAGE)
+    assert whole.ctypes.data == base
     assert pool.stats() == {
-        "bytes": 400,
-        "fresh": 600,
-        "hits": 3,
-        "misses": 3,
-        "high_water": 600,
+        "bytes": PAGE + 8 * PAGE,
+        "fresh": (4 * PAGE - 100) + 2 * PAGE + 2 * PAGE + PAGE + 5 * PAGE,
+        "hits": 2,
+        "misses": 5,
+        "high_water": 8 * PAGE + 5 * PAGE,
     }
 
 
-def test_a_take_waits_for_a_buffer_lent_to_an_earlier_stateful_only():
-    pool = HostBufferPool()
-    reserved(pool, [100, 100], [100, 200])
-    a = pool.take(100, 0)
+def test_a_take_with_no_room_flushes_the_batchers_and_waits_its_turn():
+    pool, batcher = pool_of([4, 4, 6, 1, 1], [4, 4, 6, 1, 1], window=12)
+    from_thread = []
+
+    def landed(buf):
+        lander = threading.Thread(target=pool.give, args=(buf, True))
+        lander.start()
+        lander.join(5)
+        from_thread.append(lander.is_alive())
 
     async def read_pipeline():
-        assert pool.coming(100, 0) is None  # a sibling's may land only once this is read
-        assert pool.coming(200, 1) is None  # nothing of that size is out
-        woken = pool.coming(100, 1)  # the stateful before is landing from it
-        assert woken is not None and not woken.done()
-        lander = threading.Thread(target=pool.give, args=(a, True))
-        lander.start()
-        await asyncio.wait_for(woken, 5)  # woken by the give, from its thread
-        lander.join()
-        assert pool.coming(100, 1) is None and pool.take(100, 1) is a  # it is there
-        first, second = pool.coming(100, 2), pool.coming(100, 2)
-        assert first is not None and second is not None
-        pool.give(a, recycle=False)  # dropped: every waiter looks again ...
-        await asyncio.wait_for(asyncio.gather(first, second), 5)
-        assert pool.coming(100, 2) is None  # ... and nothing is coming any more
+        loop = asyncio.get_running_loop()
+        a, b, c = (pool.take(4 * PAGE, loop) for _ in range(3))
+        base = a.ctypes.data
+        assert not pool.waiting() and batcher.flushes == 0
+        first = pool.take(6 * PAGE, loop)  # no room: what the batchers hold goes
+        assert isinstance(first, asyncio.Future) and not first.done()
+        assert pool.waiting() and batcher.flushes == 1
+        landed(b)  # four pages free in the middle: not six
+        second = pool.take(PAGE, loop)  # fits, and is behind the first all the same
+        assert isinstance(second, asyncio.Future) and batcher.flushes == 2
+        await asyncio.sleep(0.05)
+        assert not first.done() and not second.done()
+        landed(a)  # eight pages from the bottom: woken by the give, from its thread
+        got = await asyncio.wait_for(first, 5)
+        assert got.ctypes.data == base and got.shape == (6 * PAGE,)
+        got = await asyncio.wait_for(second, 5)
+        assert got.ctypes.data == base + 6 * PAGE
+        assert not pool.waiting()
+        assert isinstance(pool.take(PAGE, loop), np.ndarray)  # room, nobody in front
+        assert not any(from_thread) and c.ctypes.data == base + 8 * PAGE
 
     asyncio.run(read_pipeline())
 
 
 def test_a_give_after_the_waiting_pipeline_is_gone_is_only_a_give():
-    pool = HostBufferPool()
-    reserved(pool, [100], [100], [100])
-    a = pool.take(100, 0)
+    pool, _batcher = pool_of([4, 4], [4, 4], window=8)
+    a, b = pool.take(4 * PAGE), pool.take(4 * PAGE)
+    base = a.ctypes.data
 
     async def aborted():
-        cancelled = pool.coming(100, 1)
+        loop = asyncio.get_running_loop()
+        cancelled = pool.take(4 * PAGE, loop)
         cancelled.cancel()  # with its read
-        return pool.coming(100, 2)
+        pool.give(a, recycle=True)  # granted to a read that is gone ...
+        await asyncio.sleep(0.05)
+        assert not pool.waiting()
+        again = pool.take(4 * PAGE, loop)  # ... and given back by the grant
+        assert isinstance(again, np.ndarray) and again.ctypes.data == base
+        return pool.take(4 * PAGE, loop)
 
     loop = asyncio.new_event_loop()
     left = loop.run_until_complete(aborted())
     loop.close()
-    pool.give(a, recycle=True)  # the lander, after the pipeline's loop has closed
-    assert not left.done() and pool.take(100, 1) is a
+    pool.give(b, recycle=True)  # the lander, after the pipeline's loop has closed
+    assert not left.done() and not pool.waiting()
+    assert pool.take(4 * PAGE).ctypes.data == base + 4 * PAGE
 
 
-def test_a_buffer_given_back_unfit_is_counted_out_and_not_handed_on():
-    pool = HostBufferPool()
-    reserved(pool, [100], [100])
-    a = pool.take(100, 0)
-    pool.give(a, recycle=False)
-    assert pool.take(100, 0) is not a
+def test_a_range_given_back_unfit_ends_the_arena():
+    pool, _batcher = pool_of([4, 4], [4, 4], window=8)
+    a, b = pool.take(4 * PAGE), pool.take(4 * PAGE)
+    base = a.ctypes.data
+
+    async def read_pipeline():
+        coming = pool.take(4 * PAGE, asyncio.get_running_loop())
+        # its transfer failed, or the landed array may be the range itself
+        pool.give(a, recycle=False)
+        return await asyncio.wait_for(coming, 5)
+
+    got = asyncio.run(read_pipeline())
+    assert got.shape == (4 * PAGE,) and not in_arena(got, base, 8)
+    pool.give(b, recycle=True)  # free, and handed to nobody
+    assert not in_arena(pool.take(4 * PAGE), base, 8)
     stats = pool.stats()
-    assert stats["hits"] == 0 and stats["misses"] == 2 and stats["high_water"] == 100
+    assert stats["hits"] == 0 and stats["misses"] == 4 and stats["bytes"] == 0
 
 
-def test_nothing_is_freed_beside_reads_and_all_of_it_when_the_restore_ends():
-    pool = HostBufferPool()
-    reserved(pool, [100, 200])
-    a, b = pool.take(100, 0), pool.take(200, 0)
-    alive = [weakref.ref(a), weakref.ref(b)]
-    pool.give(a, recycle=True)  # nothing will take it again, and it is kept
-    pool.give(b, recycle=True)
-    del a, b
-    assert alive[0]() is not None and alive[1]() is not None
-    pool.close()  # the restore has ended: what is free goes
-    assert alive[0]() is None and alive[1]() is None
-    assert pool.stats()["high_water"] == 300
-    pool.close()  # said twice is said once
+@pytest.mark.parametrize(
+    "groups, window, target, pages",
+    [
+        # every leaf fits
+        pytest.param([[1, 2, 4, 8, 8, 16, 16]] * 3, 10, ON_A_CHIP, 16, id="the_largest_leaf"),
+        # many small leaves: what the batchers may have in flight
+        pytest.param([[1] * 30 + [6] * 5] * 3, 20, ON_A_CHIP, 20, id="the_window"),
+        pytest.param([[2, 3], [2, 3], [1]], 1 << 17, ON_A_CHIP, 5, id="the_largest_stateful"),
+        pytest.param([[8]], 4, ON_A_CHIP, None, id="one_leaf"),
+        pytest.param([[2, 3], []], 20, ON_A_CHIP, None, id="nothing_to_use_twice"),
+        pytest.param([[1, 2, 4, 8]] * 3, 4, ON_THE_HOST, None, id="host_memory_kept"),
+    ],
+)
+def test_the_arena_is_sized_from_what_the_plan_reserved(
+    monkeypatch, groups, window, target, pages
+):
+    made = []
+    real = array_mod._arena_memory
+    monkeypatch.setattr(
+        array_mod, "_arena_memory", lambda nbytes: made.append(nbytes) or real(nbytes)
+    )
+    pool, _batcher = pool_of(*groups, window=window, target=target)
+    assert not made  # nothing before the first take
+    first = pool.take(PAGE)
+    assert made == ([pages * PAGE] if pages else [])
+    assert first.shape == (PAGE,) and pool.stats()["fresh"] == PAGE
 
 
-def test_the_pool_stays_under_two_groups_where_sizes_never_repeat():
-    pool = HostBufferPool()
-    groups = [[100, 110], [200, 210], [300, 310], [50, 60]]
-    reserved(pool, *groups)
-    for group in groups:  # each lands before the next is read
-        for buf in [pool.take(nbytes, 0) for nbytes in group]:
-            pool.give(buf, recycle=True)
-    stats = pool.stats()
-    assert stats["hits"] == 0
-    assert stats["high_water"] <= 610 + 410  # the two largest groups
-    # room is made from what is free, and no more of it than the miss needs
-    pool = HostBufferPool()
-    reserved(pool, [100], [200], [300])
-    a, b = pool.take(100, 0), pool.take(200, 0)
+def test_the_arena_is_not_touched_up_front_and_goes_when_the_restore_ends(monkeypatch):
+    arenas = []
+
+    def recorded(nbytes):
+        raw, buf = memory_at(nbytes, 0)
+        arenas.append(weakref.ref(raw))
+        return buf
+
+    monkeypatch.setattr(array_mod, "_arena_memory", recorded)
+    pool, _batcher = pool_of([4, 4], [4, 4], window=8)
+    a, b = pool.take(4 * PAGE), pool.take(PAGE)
+    assert len(arenas) == 1 and pool.stats()["high_water"] == 5 * PAGE
     pool.give(a, recycle=True)
-    pool.give(b, recycle=True)
-    gone, kept = weakref.ref(a), weakref.ref(b)
-    del a, b
-    pool.take(300, 0)  # 600 alive against the 500 of the two largest groups
-    assert gone() is None and kept() is not None
+    del a
+    pool.close()  # the restore has ended ...
+    assert arenas[0]() is not None  # ... and a range still out is still memory
+    pool.give(b, recycle=True)  # given back late, to nobody
+    del b
+    assert arenas[0]() is None
+    pool.close()  # said twice is said once
+    assert pool.stats()["high_water"] == 5 * PAGE
 
 
-def test_no_buffer_is_in_two_hands_at_once():
-    """More takers than cores, a short switch interval: a buffer taken is
-    nobody else's until given back, and the account adds up."""
-    pool = HostBufferPool()
-    workers, rounds, sizes = 16, 200, (64, 128, 192)
-    reserved(pool, [size for size in sizes for _ in range(workers * rounds)])
-    clashes = []
+def test_no_range_is_in_two_hands_at_once_however_the_landings_come():
+    """Takes in dispatch order from one loop, more of them than the arena
+    holds; four landers that give back in whatever order their naps end; a
+    short switch interval.  A range taken is nobody else's until given back,
+    every read gets its turn, and the account adds up."""
+    rng = random.Random(7)
+    takes = [rng.randint(1, 6) for _ in range(600)]
+    pool, _batcher = pool_of(*[takes[i::4] for i in range(4)], window=12)
+    out, clashes, held = [], [], {}
+    check, stop = threading.Lock(), threading.Event()
 
-    def work(ident):
-        for n in range(rounds):
-            for size in sizes:
-                buf = pool.take(size, 0)
-                buf[:] = ident
-                time.sleep(0)
-                if (buf != ident).any():
-                    clashes.append((ident, n, size))
-                pool.give(buf, recycle=n % 7 != 0)
+    def lander(seed):
+        naps = random.Random(seed)
+        while not stop.is_set() or out:
+            try:
+                n, lease = out.pop(naps.randrange(len(out)))
+            except (IndexError, ValueError):
+                time.sleep(0.0005)
+                continue
+            time.sleep(naps.random() * 0.002)
+            if (lease != n % 251).any():
+                clashes.append(("written over", n))
+            with check:
+                del held[n]
+            pool.give(lease, recycle=True)
+
+    async def read(n, pages):
+        taken = pool.take(pages * PAGE - n % 64, asyncio.get_running_loop())
+        lease = taken if isinstance(taken, np.ndarray) else await asyncio.wait_for(taken, 30)
+        begin = lease.ctypes.data
+        with check:
+            for other, (b, e) in held.items():
+                if begin < e and b < begin + pages * PAGE:
+                    clashes.append(("overlaps", n, other))
+            held[n] = (begin, begin + pages * PAGE)
+        lease[:] = n % 251
+        out.append((n, lease))
+        return begin
+
+    async def read_pipeline():
+        return await asyncio.gather(*(read(n, pages) for n, pages in enumerate(takes)))
 
     interval = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
+    landers = [threading.Thread(target=lander, args=(seed,)) for seed in range(4)]
     try:
-        threads = [threading.Thread(target=work, args=(i + 1,)) for i in range(workers)]
-        for t in threads:
+        for t in landers:
             t.start()
-        for t in threads:
-            t.join(timeout=60)
+        begins = asyncio.run(read_pipeline())
     finally:
+        stop.set()
+        for t in landers:
+            t.join(timeout=60)
         sys.setswitchinterval(interval)
-    assert not any(t.is_alive() for t in threads)
-    assert not clashes
+    assert not any(t.is_alive() for t in landers)
+    assert not clashes and not held and not pool.waiting()
+    base = min(begins)
+    assert max(begins) < base + 12 * PAGE  # never a plain buffer
     stats = pool.stats()
-    assert stats["hits"] + stats["misses"] == workers * rounds * len(sizes)
-    assert stats["high_water"] <= workers * sum(sizes)
+    assert stats["hits"] + stats["misses"] == len(takes)
+    assert stats["fresh"] <= stats["high_water"] <= 12 * PAGE
+    assert pool.take(12 * PAGE).ctypes.data == base  # all of it is free, and one range

@@ -1,11 +1,12 @@
 """Read-ahead across statefuls: one read pipeline serves the whole restore
 (``scheduler.ReadAhead``).  On a slow fake storage plug-in: the next
-stateful is read while the one before it loads (a read whose twin in that
-one is still landing is dispatched then and asks storage once the twin's
-host buffer has come back); nothing of it is consumed or sent to the device
-before that load has returned; the look-ahead is one stateful; user code
-stays on the calling thread, in key order; a failure ahead leaves what was
-loaded loaded and no thread behind."""
+stateful is read while the one before it loads (a read that finds no room in
+the restore's host arena is dispatched then and asks storage once a landing
+has freed some, which its own wait sets off); nothing of it is consumed or
+sent to the device before that load has returned; the look-ahead is one
+stateful, and a read of it parked with its range held starves no read of the
+stateful in front; user code stays on the calling thread, in key order; a
+failure ahead leaves what was loaded loaded and no thread behind."""
 
 import asyncio
 import gc
@@ -122,19 +123,20 @@ class SlowStorage(StoragePlugin):
         await self._inner.close()
 
 
-def unaligned_buffer(nbytes):
-    """16 bytes off a 64-byte boundary, as a large ``np.empty`` is: the CPU
-    backend copies such a buffer on ``device_put`` (one at a 64-byte boundary
-    it takes as the array itself, and which a small ``np.empty`` gets is
-    chance), so what happens to it is what happens on an accelerator."""
+def unaligned_memory(nbytes):
+    """``(raw, buf)``: memory that begins 16 bytes off a 64-byte boundary, as
+    a large ``np.empty`` does, and the allocation under it.  The CPU backend
+    copies such a buffer on ``device_put``, and every range of it a whole
+    number of pages on (one at a 64-byte boundary it takes as the array
+    itself), so what happens to it is what happens on an accelerator."""
     raw = np.empty(nbytes + 80, dtype=np.uint8)
     offset = (16 - raw.ctypes.data) % 64
-    return raw[offset : offset + nbytes]
+    return raw, raw[offset : offset + nbytes]
 
 
-# One tree in every stateful, as a train state's parameters and moments are:
-# each leaf has a twin of its byte size in the stateful before.  Or a tree of
-# its own in each: no host buffer fits a later leaf.
+# One tree in every stateful, as a train state's parameters and moments are.
+# Or a tree of its own in each, no two leaves of a size: a range of the arena
+# is any size, so the one is read ahead as the other is.
 TREES = {
     "twins": {key: SHAPE for key in KEYS},
     "no_twins": {key: (SHAPE[0] + 64 * k, SHAPE[1]) for k, key in enumerate(KEYS)},
@@ -163,8 +165,10 @@ def world(monkeypatch, request):
     """A snapshot of ``KEYS`` in memory (the ``twins`` tree, or the one a
     test names), and every seam recorded: reads (when the pipeline
     dispatches one, and when storage is asked, slowly), plans, host buffers
-    (a weak reference to each one made, and when one is given back),
-    consumes, H2D submits and dispatches, each with its stateful."""
+    (the CPU backend taken for an accelerator; a weak reference to each arena
+    made and its size, when a read has its range, and when one is given
+    back), consumes, H2D submits and dispatches, each with its stateful.  A
+    test may give every batcher other arguments (``batcher_args``)."""
     MemoryStoragePlugin.reset()
     log = Log()
     url = f"memory://read_ahead_{time.monotonic_ns()}"
@@ -198,42 +202,62 @@ def world(monkeypatch, request):
 
     monkeypatch.setattr(Snapshot, "_plan_stateful_reads", staticmethod(recording_plan))
 
-    buffers = []  # a weak reference to every host buffer made
+    arenas, arena_sizes = [], []  # a weak reference to every arena made
 
-    def recording_buffer(nbytes):
-        buf = unaligned_buffer(nbytes)
-        buffers.append(weakref.ref(buf))
+    def recording_arena(nbytes):
+        raw, buf = unaligned_memory(nbytes)
+        arenas.append(weakref.ref(raw))
+        arena_sizes.append(nbytes)
         return buf
 
-    monkeypatch.setattr(array_mod, "_fresh_host_buffer", recording_buffer)
+    monkeypatch.setattr(array_mod, "_arena_memory", recording_arena)
+    monkeypatch.setattr(array_mod, "_keeps_host_memory", lambda target: False)
+    plain = []  # the size of every plain buffer made beside an arena
+    monkeypatch.setattr(
+        array_mod,
+        "_fresh_host_buffer",
+        lambda nbytes: plain.append(nbytes) or np.empty(nbytes, dtype=np.uint8),
+    )
+    batcher_args = {}
+    monkeypatch.setattr(
+        snapshot_mod,
+        "H2DBatcher",
+        lambda **kwargs: H2DBatcher(**batcher_args, **kwargs),
+    )
 
     def key_of_batcher(batcher):
         return next(k for k, ident in plans.items() if ident == id(batcher))
 
     real_give = array_mod.HostBufferPool.give
+    lent_to = {}  # where a range begins -> the stateful whose leaf is in it
 
     def recording_give(self, buf, recycle):
-        log.add("buffer_back", KEYS[self._lent[id(buf)][1]])
+        log.add("buffer_back", lent_to.pop(buf.ctypes.data))
         return real_give(self, buf, recycle)
 
     monkeypatch.setattr(array_mod.HostBufferPool, "give", recording_give)
-    real_read = scheduler_mod._ReadPipeline.read_buffer
+    real_take = scheduler_mod._ReadPipeline.take_memory
     real_consume = scheduler_mod._ReadPipeline.consume_buffer
 
-    async def recording_read(self):
-        log.add("read_dispatch", stateful_of(self.read_req.path))
-        return await real_read(self)
+    async def recording_take(self):
+        key = stateful_of(self.read_req.path)
+        log.add("read_dispatch", key)
+        await real_take(self)
+        log.add("range_taken", key)
+
 
     async def recording_consume(self, executor):
         log.add("consume_begin", stateful_of(self.read_req.path))
         return await real_consume(self, executor)
 
-    monkeypatch.setattr(scheduler_mod._ReadPipeline, "read_buffer", recording_read)
+    monkeypatch.setattr(scheduler_mod._ReadPipeline, "take_memory", recording_take)
     monkeypatch.setattr(scheduler_mod._ReadPipeline, "consume_buffer", recording_consume)
     real_submit, real_dispatch = H2DBatcher.submit, H2DBatcher._dispatch
 
     def recording_submit(self, host, like, fut, lease=None):
         log.add("h2d_submit", key_of_batcher(self))
+        if lease is not None:
+            lent_to[lease.ctypes.data] = key_of_batcher(self)
         return real_submit(self, host, like, fut, lease)
 
     def recording_dispatch(self, items, batch_bytes):
@@ -250,8 +274,11 @@ def world(monkeypatch, request):
         saved=saved,
         plans=plans,
         pools=pools,
-        buffers=buffers,
+        arenas=arenas,
+        arena_sizes=arena_sizes,
+        plain=plain,
         storage_args=storage_args,
+        batcher_args=batcher_args,
     )
 
 
@@ -302,12 +329,9 @@ def test_the_next_stateful_is_read_while_this_one_loads(world):
         # read-ahead begins where the tail begins
         assert log.first("read_dispatch", ahead) >= log.last("read_end", this), (this, ahead)
         assert log.first("read_dispatch", ahead) < log.first("load_begin", this), (this, ahead)
-        if world.tree == "no_twins":
-            # ... and a read that no landing buffer fits asks storage there
-            # and then: it is under way before this one's load begins
-            assert log.first("read_begin", ahead) < log.first("load_begin", this), (this, ahead)
-    if world.tree == "no_twins":
-        assert "host_buffer_wait" not in delta and end["host_pool"]["hits"] == 0
+    # whether its leaves are of the sizes of the one before or of no size seen
+    # before: it lands in the ranges that one landed from
+    assert end["host_pool"]["hits"] > 0 and "host_buffer_wait" in delta
     # ... on a thread that is not the one that loads
     readers = {name for what, _, _, name in log.rows if what == "read_begin"}
     loaders = {name for what, _, _, name in log.rows if what == "load_begin"}
@@ -324,45 +348,76 @@ def test_the_next_stateful_is_read_while_this_one_loads(world):
     assert no_pipeline_thread_alive()
 
 
-def test_a_read_whose_twin_is_landing_asks_storage_when_it_has_landed(world):
-    """What the pool changed of the rule above: a read of k+1 is dispatched
-    where k's tail begins, as ever, but while the host buffer of its twin in
-    k is still on its way to the device it holds its slot and asks storage
-    only once that buffer has come back, to land in pages already faulted
-    in.  Here every leaf is smaller than the batcher's flush size, so every
-    buffer of k comes back at k's drain, and every read of k+1 is such a
-    read: storage is driven from the drain's landing on, not through the
-    last consumes and the drain itself."""
+def test_a_read_with_no_room_asks_storage_when_a_landing_has_freed_some(world):
+    """What the arena changed of the rule above: a read of k+1 is dispatched
+    where k's tail begins, as ever, but the arena (here one stateful's bytes)
+    is full of k, so it asks storage only once a range of k has come back, to
+    land in pages already faulted in.  Every leaf is smaller than the
+    batcher's flush size, so of itself nothing of k would land before k's
+    drain: the read's wait makes k's batcher flush, and storage is driven from
+    that landing on."""
     target = make_app(world.log, zero=True)
     delta, (end,) = restore(world, target)
     log = world.log
+    leaf = int(np.prod(SHAPE)) * 4
+    assert world.arena_sizes == [LEAVES * leaf] and not world.plain
     for this, ahead in zip(KEYS, KEYS[1:]):
         dispatched = log.times("read_dispatch", ahead)
         begun = log.times("read_begin", ahead)
         assert len(dispatched) == len(begun) == LEAVES
         # held from the dispatch, where this one's tail begins ...
         assert max(dispatched) < log.first("load_begin", this), (this, ahead)
-        # ... until a buffer of this one has come back, and not for another
-        # of the stateful's own (which come back only at its own drain)
+        # ... each until a range of this one has come back
         back = sorted(log.times("buffer_back", this))
         assert len(back) == LEAVES
         for n, began in enumerate(sorted(begun)):
             assert began >= back[n], (this, ahead, n)
-        # ... and no longer.  The landing that gives the last buffer back is
-        # the one the drain returns on, so the woken read (a turn of the
-        # pipeline's loop, then the plug-in) and the loader's load_begin race
-        # from the same moment: half a load is scheduling slack under xdist,
-        # against reads that would otherwise begin only when the load ends.
+        # ... and no longer: none waits for this one's load to end
         assert max(begun) < log.first("load_begin", this) + LOAD_S / 2, (this, ahead)
     # the wait is a phase of its own, and what it bought is in the account:
-    # every read after the first stateful's landed in a buffer used before
+    # every read after the first stateful's landed in pages used before
     waited = delta["host_buffer_wait"]
-    assert waited["n"] == (len(KEYS) - 1) * LEAVES and waited["wall"] > 0
+    assert len(KEYS) - 1 <= waited["n"] <= (len(KEYS) - 1) * LEAVES and waited["wall"] > 0
     assert waited["wall"] < end["duration_s"]
     assert end["phases"]["host_buffer_wait"] == pytest.approx(waited["wall"], rel=0.05)
     assert end["host_pool"]["hits"] == (len(KEYS) - 1) * LEAVES
     assert end["host_pool"]["misses"] == LEAVES
     assert_equal_bits(target, world.saved)
+
+
+def test_a_parked_read_of_the_next_stateful_starves_no_read_of_this_one(world):
+    """An arena of two leaves behind statefuls of three: within a stateful a
+    read waits for a landing of the stateful's own.  A read of k+1 that has
+    finished is parked until k is loaded, with its range held; were it to hold
+    room that a read of k still waits for, k could never be loaded.  It
+    cannot: k+1's first range is taken when k's last read has FINISHED, and
+    by then every read of k has had its range."""
+    world.batcher_args.update(flush_bytes=256 << 20, inflight_cap_bytes=4 << 20)
+    target = make_app(world.log, zero=True)
+    done = []
+    runner = threading.Thread(
+        target=lambda: done.append(restore(world, target)), daemon=True
+    )
+    runner.start()
+    runner.join(timeout=60)
+    assert done, "the restore waits on itself"
+    ((_, (end,)),) = done
+    log = world.log
+    leaf = int(np.prod(SHAPE)) * 4
+    assert world.arena_sizes == [2 * leaf] and not world.plain
+    for this, ahead in zip(KEYS, KEYS[1:]):
+        assert len(log.times("range_taken", this)) == LEAVES
+        assert log.first("range_taken", ahead) >= log.last("read_end", this)
+        assert log.last("read_end", this) > log.last("range_taken", this)
+        # and reads of the next one were parked, their ranges held, while
+        # this one was loading
+        assert log.first("read_end", ahead) < log.last("load_end", this)
+        assert log.first("consume_begin", ahead) >= log.last("load_end", this)
+    pool = end["host_pool"]
+    assert pool["fresh"] == pool["high_water"] == 2 * leaf
+    assert pool["misses"] == 2 and pool["hits"] == len(KEYS) * LEAVES - 2
+    assert_equal_bits(target, world.saved)
+    assert no_pipeline_thread_alive()
 
 
 def test_nothing_is_consumed_or_sent_to_the_device_ahead_of_the_load(world):
@@ -380,13 +435,8 @@ def test_nothing_is_consumed_or_sent_to_the_device_ahead_of_the_load(world):
 
 def test_the_look_ahead_is_one_stateful(world):
     violations = []
-    two_statefuls = 2 * LEAVES
 
     def on_read(key):
-        # the buffer this read lands in is there already: taken at dispatch
-        alive = [ref for ref in world.buffers if ref() is not None]
-        if len(alive) > two_statefuls:
-            violations.append((key, f"{len(alive)} host buffers alive"))
         k = KEYS.index(key)
         if k >= 2 and not world.log.times("load_end", KEYS[k - 2]):
             violations.append((key, KEYS[k - 2], "not loaded"))
@@ -399,19 +449,20 @@ def test_the_look_ahead_is_one_stateful(world):
     # and it is a look-ahead: k+1 was being read before k was loaded
     for this, ahead in zip(KEYS, KEYS[1:]):
         assert world.log.first("read_begin", ahead) < world.log.last("load_end", this)
-    # the pool's own account of the same bound, and what it saved: of four
-    # statefuls' buffers, two statefuls' at the most were ever made
+    # host memory is bounded by the one arena, whatever is read ahead: of four
+    # statefuls' bytes, one stateful's were ever touched
     leaf = int(np.prod(SHAPE)) * 4
     pool = end["host_pool"]
-    assert 0 < pool["high_water"] <= two_statefuls * leaf
-    assert pool["misses"] == len(world.buffers) <= two_statefuls
+    assert world.arena_sizes == [LEAVES * leaf]
+    assert pool["fresh"] == pool["high_water"] == LEAVES * leaf
+    assert pool["misses"] == LEAVES
     assert pool["hits"] + pool["misses"] == len(KEYS) * LEAVES
-    assert pool["bytes"] == pool["hits"] * leaf and pool["fresh"] == pool["misses"] * leaf
+    assert pool["bytes"] == pool["hits"] * leaf
     # nothing of it outlives the restore
     made = weakref.ref(world.pools.pop())
     assert not world.pools
     gc.collect()
-    assert made() is None and all(ref() is None for ref in world.buffers)
+    assert made() is None and all(ref() is None for ref in world.arenas)
 
 
 def test_user_code_runs_on_the_calling_thread_in_key_order_rng_last(world):

@@ -19,10 +19,13 @@ design:
 from __future__ import annotations
 
 import asyncio
+import bisect
 import logging
 import math
+import mmap
 import threading
-from collections import defaultdict, deque
+import weakref
+from collections import deque
 from concurrent.futures import Executor
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -371,154 +374,282 @@ _Item = Tuple[np.ndarray, Any, Future, Optional[np.ndarray]]
 # it was made from.
 _Lease = Tuple[Any, np.ndarray]
 
+_PAGE = mmap.PAGESIZE
+
+
+def _pages(nbytes: int) -> int:
+    """``nbytes`` rounded up to whole pages."""
+    return -(-nbytes // _PAGE) * _PAGE
+
 
 def _fresh_host_buffer(nbytes: int) -> np.ndarray:
-    # A function of its own so that a test can choose where a buffer begins
-    # (the CPU backend aliases a 64-byte-aligned one into the landed array).
+    # A function of its own so that a test can count the plain buffers made.
     return np.empty(nbytes, dtype=np.uint8)
 
 
+def _arena_memory(nbytes: int) -> np.ndarray:
+    """``nbytes`` of anonymous memory that begins at a page boundary and has
+    not been touched: one allocation, one ``munmap`` when the last view of it
+    dies.  A function of its own so that a test can choose where it begins
+    (the CPU backend aliases a 64-byte-aligned range into the landed array)."""
+    raw = np.empty(nbytes + _PAGE, dtype=np.uint8)
+    begin = -raw.ctypes.data % _PAGE
+    return raw[begin : begin + nbytes]
+
+
+def _keeps_host_memory(target: Any) -> bool:
+    """Whether a ``device_put`` like ``target`` may keep the host memory it
+    is given as the array itself, as the CPU backend does with a 64-byte-
+    aligned buffer: such a target never lands from the arena, or a restored
+    array would pin it and the next tenant of its range would change it."""
+    try:
+        return any(device.platform == "cpu" for device in target.devices())
+    except Exception:  # noqa: BLE001 -- not seen to be apart: no arena
+        return True
+
+
 class HostBufferPool:
-    """The host read buffers of ONE ``Snapshot.restore`` call, carried from a
-    leaf whose H2D has landed to the next leaf of the same byte size.
+    """The host read buffers of ONE ``Snapshot.restore`` call: one bounded
+    arena, handed out as page-aligned ranges of any size.
 
     A leaf that is read into place and uploaded through an ``H2DBatcher``
-    reserves its size at plan time (``reserve``), takes its buffer when the
-    first of its reads is dispatched (``take``), and the batcher gives it
-    back once the transfer has landed (``give``).  The large statefuls of a
-    train state (parameters, first and second moments) are one tree three
-    times over and each is read smallest leaf first, so from the second on
-    a ``take`` finds the buffer its twin landed from long ago: nothing is
-    unmapped beside the reads (a ``munmap`` holds the GIL and takes the
-    address space's lock for writing while the readers fault pages in under
-    it) and the reads land in pages already faulted in.
+    reserves its size at plan time (``reserve``; every stateful is planned
+    before the first read), takes a range when the first of its reads is
+    dispatched (``take``), and the batcher's lander gives the range back once
+    the transfer has landed (``give``).  The arena is one allocation, made at
+    the first ``take`` and never touched up front, so a page costs its first
+    touch when the first leaf is read into it and nothing after: from the
+    arena's first turn on every read, the first stateful's included, lands
+    in pages the restore has touched before, and nothing is unmapped beside
+    the reads (a ``munmap`` holds the GIL and takes the address space's lock
+    for writing while the readers fault pages in under it).  What a first
+    touch costs against a read into a touched page, on the hosts measured,
+    is in PERF.md section 5.
 
-    A read finds its twin's buffer only once that has landed, and the reads
-    of a stateful of a dozen leaves are all dispatched in the instant the
-    one before it has been read, its largest leaves last: so a read whose
-    size has none free, while one lent to an EARLIER stateful is still to
-    come back, is held until it has (``coming``, which the assembly awaits
-    before it takes; a ``give`` wakes it).  That one is read already and
-    lands without anything this read could hold up.  What the wait buys
-    depends on the host: it trades the storage's time under that landing
-    for pages that need no first touch, and is worth it where a first touch
-    costs more than the read, as on the hosts measured (PERF.md section 5).
-    A buffer lent to the same stateful is never waited for: it may land
-    only at that stateful's drain, which waits for this very read.
+    **Its size** is a rule over what the plan reserved and the batchers'
+    window, and no knob: ``max(the largest reserved leaf, the batchers'
+    in-flight cap)``, in whole pages, and no more than the largest stateful's
+    bytes.  The largest leaf, so that every leaf fits; the in-flight cap, so
+    that the landings the batcher allows can all be under way while reads go
+    on, and the arena, not the batcher's window, is what holds the pipeline
+    back.  No more: reads into touched pages outrun the landings, so a
+    second buffer of the largest leaf's size buys no overlap and costs its
+    first touches (PERF.md section 5 holds what each rule tried on the chip
+    read).
 
-    Free lists keyed by exact byte size, nothing more: a size not seen
-    before is a plain ``np.empty``, as without a pool.  A buffer that
-    nothing will take again is kept all the same, so that it is not freed
-    beside reads: it goes with the rest when the restore ends (``close``),
-    or earlier only to make room, when a miss would take the bytes alive
-    through the pool (taken and not given back, plus free) over the two
-    largest groups' reservations: the bound the read pipeline keeps without
-    a pool.  Nothing outlives the restore.
+    **A take** is first-fit from the lowest address over a short list of free
+    ranges, so that touched pages are used before untouched ones (what was
+    ever handed out is always a prefix of the arena: ``high_water``); a give
+    frees the range and coalesces it with its neighbours.  **A read that
+    finds no room waits** (``take`` with the pipeline's loop: a future of
+    that loop; the assembly's phase ``host_buffer_wait``), in the order of
+    dispatch, for a landing of ANY stateful, its own included, and the wait
+    makes every attached batcher send what it holds to the device (a flush
+    on pressure: ``attach``, ``waiting``), or a stateful would wait on its
+    own small leaves gathering under the batcher's threshold.  Whoever holds
+    a range gets on without the waiter: a read under way holds its io slot
+    (the scheduler takes the range first), a landing needs the lander alone,
+    and a read of the next stateful parked behind the load holds a range only
+    when every read of the stateful in front has one.  ``give`` hands the
+    freed room to the waiters at the head of the queue itself, so no range
+    is in two hands and none is taken out of turn.
 
-    Thread-safe: ``reserve`` runs on the planning thread, ``coming`` and
+    **No arena** (``take`` is a plain ``np.empty``, ``give`` drops it) where
+    a target's backend may keep the host memory as the landed array
+    (``_keeps_host_memory``: decided at ``reserve``, from the target's
+    devices), where nothing could be used twice (one pooled leaf; everything
+    reserved fits the arena), and from the moment a range is given back unfit
+    (``recycle=False``: its transfer failed, or the landed array may be the
+    range itself).  A take that cannot wait (a piece copied in on an executor
+    thread) and finds no room is a plain buffer too.  Nothing outlives the
+    restore: ``close()`` drops the arena.
+
+    Thread-safe: ``reserve`` and ``attach`` run on the planning thread,
     ``take`` on the read pipeline's thread or its executor, ``give`` on the
     lander."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
-        self._free: Dict[int, List[np.ndarray]] = defaultdict(list)
-        self._group_bytes: List[int] = []  # reserved by each stateful
-        self._lent: Dict[int, Tuple[int, int]] = {}  # id(buffer) -> (size, group)
-        # size -> (loop, future) of each read held until one comes back
-        self._waiters: Dict[int, List[Tuple[Any, Any]]] = defaultdict(list)
-        self._alive = 0  # bytes taken and not given back, plus free
+        self._group_bytes: List[int] = []  # reserved by each stateful, in pages
+        self._largest = 0  # the largest reserved leaf, in pages
+        self._batchers: List["weakref.ref[H2DBatcher]"] = []
+        self._plain = False  # no arena (any more)
+        self._arena: Optional[np.ndarray] = None
+        self._base = self._size = 0
+        self._free: List[List[int]] = []  # [offset, size], by offset
+        self._lent: Dict[int, int] = {}  # offset -> size of each range out
+        # (loop, future, nbytes) of each read held for room, in dispatch order
+        self._waiters: "deque[Tuple[Any, Any, int]]" = deque()
+        self._plain_alive = 0  # bytes of plain buffers taken and not given back
         self._stats = {"bytes": 0, "fresh": 0, "hits": 0, "misses": 0, "high_water": 0}
+        self._touched = 0  # the arena's prefix ever handed out
 
     def begin_group(self) -> None:
         """The reservations that follow are one stateful's."""
         with self._lock:
             self._group_bytes.append(0)
 
-    def reserve(self, nbytes: int) -> int:
-        """One ``take`` of ``nbytes`` is to come; the group (stateful) it
-        will come from."""
+    def reserve(self, nbytes: int, target: Any) -> None:
+        """One ``take`` of ``nbytes`` is to come, for a leaf that will be
+        placed like ``target``."""
+        keeps = _keeps_host_memory(target)
         with self._lock:
-            self._group_bytes[-1] += nbytes
-            return len(self._group_bytes) - 1
+            self._group_bytes[-1] += _pages(nbytes)
+            self._largest = max(self._largest, _pages(nbytes))
+            self._plain = self._plain or keeps
 
-    def coming(self, nbytes: int, group: int) -> Optional["asyncio.Future[None]"]:
-        """None where a ``take`` need not wait; else a future of the running
-        loop that the next ``give`` of a buffer of ``nbytes`` resolves: no
-        such buffer is free, and one lent to a group before ``group`` is yet
-        to be given back."""
+    def attach(self, batcher: "H2DBatcher") -> None:
+        """``batcher`` uploads leaves of this pool: its in-flight cap goes
+        into the arena's size, and a read that waits for room flushes it."""
         with self._lock:
-            if self._free[nbytes] or not any(
-                size == nbytes and lent_to < group
-                for size, lent_to in self._lent.values()
-            ):
-                return None
-            loop = asyncio.get_running_loop()
-            woken = loop.create_future()
-            self._waiters[nbytes].append((loop, woken))
-            return woken
+            self._batchers.append(weakref.ref(batcher))
 
-    def take(self, nbytes: int, group: int) -> np.ndarray:
-        """A flat uint8 buffer of exactly ``nbytes`` for a leaf of ``group``:
-        one given back, else a new one."""
-        evicted: List[np.ndarray] = []
+    def _live_batchers(self) -> List["H2DBatcher"]:
+        return [b for b in (ref() for ref in self._batchers) if b is not None]
+
+    def _make_arena(self) -> None:
+        # Under the lock, at the first take: every stateful is planned.
+        window = max(
+            (_pages(b.inflight_cap_bytes) for b in self._live_batchers()), default=0
+        )
+        size = min(max(self._largest, window), max(self._group_bytes, default=0))
+        if size >= sum(self._group_bytes):
+            self._plain = True  # nothing would be used twice (one leaf, say)
+            return
+        self._arena = _arena_memory(size)
+        self._base, self._size = self._arena.ctypes.data, size
+        self._free = [[0, size]]
+
+    def _fit(self, nbytes: int) -> Optional[np.ndarray]:
+        """Under the lock: the lowest free range that holds ``nbytes``, taken
+        out of the free list and counted, or None."""
+        size = _pages(nbytes)
+        for i, (offset, room) in enumerate(self._free):
+            if room >= size:
+                break
+        else:
+            return None
+        if room == size:
+            del self._free[i]
+        else:
+            self._free[i] = [offset + size, room - size]
+        self._lent[offset] = size
+        fresh = min(nbytes, max(0, offset + size - self._touched))
+        self._touched = max(self._touched, offset + size)
+        self._count(nbytes, fresh)
+        return self._arena[offset : offset + nbytes]
+
+    def _count(self, nbytes: int, fresh: int) -> None:
         stats = self._stats
+        stats["misses" if fresh else "hits"] += 1
+        stats["fresh"] += fresh
+        stats["bytes"] += nbytes - fresh
+        stats["high_water"] = max(
+            stats["high_water"], self._touched + self._plain_alive
+        )
+
+    def _plain_buffer(self, nbytes: int) -> np.ndarray:
+        # Under the lock (an np.empty of this size touches nothing).
+        self._plain_alive += nbytes
+        self._count(nbytes, nbytes)
+        return _fresh_host_buffer(nbytes)
+
+    def take(self, nbytes: int, loop: Optional[Any] = None) -> Any:
+        """A flat uint8 buffer of exactly ``nbytes``: a range of the arena,
+        or a plain buffer where there is no arena (or, with no ``loop``, no
+        room in it).  With a ``loop`` and no room, or with reads already
+        waiting: a future of ``loop`` that a ``give`` resolves to the buffer,
+        this read's turn having come; the batchers are flushed first."""
         with self._lock:
-            if self._free[nbytes]:
-                stats["hits"] += 1
-                stats["bytes"] += nbytes
-                buf = self._free[nbytes].pop()
-            else:
-                stats["misses"] += 1
-                stats["fresh"] += nbytes
-                over = self._alive + nbytes - sum(sorted(self._group_bytes)[-2:])
-                for bufs in self._free.values():
-                    while over > 0 and bufs:
-                        evicted.append(bufs.pop())
-                        over -= evicted[-1].nbytes
-                self._alive += nbytes - sum(buf.nbytes for buf in evicted)
-                stats["high_water"] = max(stats["high_water"], self._alive)
-                buf = _fresh_host_buffer(nbytes)
-            self._lent[id(buf)] = (nbytes, group)
-        del evicted  # unmapped outside the lock
-        return buf
+            if self._arena is None and not self._plain:
+                self._make_arena()
+            if self._plain or _pages(nbytes) > self._size:
+                return self._plain_buffer(nbytes)
+            if loop is None or not self._waiters:
+                buf = self._fit(nbytes)
+                if buf is not None:
+                    return buf
+                if loop is None:
+                    return self._plain_buffer(nbytes)
+            coming = loop.create_future()
+            self._waiters.append((loop, coming, nbytes))
+            batchers = self._live_batchers()
+        for batcher in batchers:
+            batcher.flush()
+        return coming
+
+    def waiting(self) -> bool:
+        """Whether a read is held for room: what a batcher is handed then
+        goes to the device at once."""
+        with self._lock:
+            return bool(self._waiters)
 
     def give(self, buf: np.ndarray, recycle: bool) -> None:
         """``buf``, taken here, is done with.  ``recycle`` only where its
-        transfer has landed and the landed array is not the buffer itself;
-        otherwise it is dropped, and only counted out.  Either way whoever
-        waits for a buffer of its size (``coming``) is woken to look again."""
+        transfer has landed and the landed array is not the buffer itself:
+        its range is free again, and goes to the reads at the head of the
+        queue as far as it reaches.  Otherwise the range is never handed out
+        again, and nor is any other: there is no arena from here on, and
+        whoever waits gets a plain buffer."""
+        granted: List[Tuple[Any, Any, np.ndarray]] = []
         with self._lock:
-            del self._lent[id(buf)]
+            offset = buf.ctypes.data - self._base
+            size = self._lent.pop(offset, None)
+            if size is None:
+                self._plain_alive -= buf.nbytes  # a plain buffer: dropped
+                return
             if recycle:
-                self._free[buf.nbytes].append(buf)
+                self._release(offset, size)
             else:
-                self._alive -= buf.nbytes
-            waiters = self._waiters.pop(buf.nbytes, ())
-        for loop, woken in waiters:
+                self._plain = True
+            while self._waiters:
+                loop, coming, nbytes = self._waiters[0]
+                lease = (
+                    self._plain_buffer(nbytes) if self._plain else self._fit(nbytes)
+                )
+                if lease is None:
+                    break
+                self._waiters.popleft()
+                granted.append((loop, coming, lease))
+        for loop, coming, lease in granted:
             try:
-                loop.call_soon_threadsafe(_wake, woken)
+                loop.call_soon_threadsafe(self._grant, coming, lease)
             except RuntimeError:  # the pipeline was aborted, its loop closed
-                pass
+                self.give(lease, recycle=True)
+
+    def _grant(self, coming: "asyncio.Future[np.ndarray]", lease: np.ndarray) -> None:
+        if coming.done():  # cancelled with its read
+            self.give(lease, recycle=True)
+        else:
+            coming.set_result(lease)
+
+    def _release(self, offset: int, size: int) -> None:
+        # Under the lock: the range is free, one with its free neighbours.
+        free = self._free
+        i = bisect.bisect_left(free, [offset, 0])
+        if i < len(free) and offset + size == free[i][0]:
+            size += free.pop(i)[1]
+        if i and free[i - 1][0] + free[i - 1][1] == offset:
+            free[i - 1][1] += size
+        else:
+            free.insert(i, [offset, size])
 
     def close(self) -> None:
-        """The restore is over, nothing reads any more: what is free goes."""
+        """The restore is over, nothing reads any more: the arena goes."""
         with self._lock:
-            free = [buf for bufs in self._free.values() for buf in bufs]
-            self._free.clear()
-            self._alive -= sum(buf.nbytes for buf in free)
-        del free  # unmapped outside the lock
+            arena, self._arena = self._arena, None
+            self._free, self._plain = [], True
+        del arena  # unmapped outside the lock
 
     def stats(self) -> Dict[str, int]:
-        """``bytes`` read into recycled buffers (``hits`` of them), ``fresh``
-        bytes read into new ones (``misses``), and the ``high_water`` of the
-        bytes alive through the pool."""
+        """``fresh`` bytes handed out from pages of the arena never handed
+        out before, and every byte of a plain buffer (``misses``: the takes
+        that touched any); ``bytes`` handed out from pages that were
+        (``hits``: the takes that touched nothing new); and the
+        ``high_water`` of the arena's bytes ever handed out, at most its
+        size, plus the plain buffers alive."""
         with self._lock:
             return dict(self._stats)
-
-
-def _wake(woken: "asyncio.Future[None]") -> None:
-    if not woken.done():  # cancelled with its read
-        woken.set_result(None)
 
 
 def _may_alias(out: Any, buf: np.ndarray) -> bool:
@@ -562,10 +693,16 @@ class H2DBatcher:
     and the lander thread has exited.
 
     With a ``host_pool`` (``Snapshot.restore``'s), a buffer submitted with
-    its ``lease`` (the pool's buffer under ``host``) goes back to the pool
-    when its transfer has landed, unless the landed array may be the buffer
-    itself (``_may_alias``); one whose transfer failed, or was never made,
-    is dropped.  Nothing here keeps a host buffer past its landing.
+    its ``lease`` (the pool's range under ``host``) goes back to the pool
+    when its transfer has landed (the lander's ``block_until_ready`` before
+    the ``give`` is what keeps a range from its next tenant until its last
+    one is on the device), unless the landed array may be the buffer itself
+    (``_may_alias``); one whose transfer failed, or was never made, is given
+    back unfit.  While a read waits for room in the pool
+    (``HostBufferPool.waiting``) nothing gathers here: the pool flushes every
+    batcher when the wait begins, and a leased buffer submitted during it is
+    dispatched at once, whatever ``flush_bytes`` says.  Nothing here keeps a
+    host buffer past its landing.
 
     Thread-safety: ``submit``/``flush`` may run on the read pipeline's loop
     or executor threads, ``drain`` on the caller thread.  Because landings
@@ -587,9 +724,11 @@ class H2DBatcher:
         self._items: List[_Item] = []
         self._bytes = 0
         self._flush_bytes = flush_bytes
-        self._inflight_cap = (
+        self.inflight_cap_bytes = (
             inflight_cap_bytes if inflight_cap_bytes is not None else 2 * flush_bytes
         )
+        if host_pool is not None:
+            host_pool.attach(self)
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
         # (landing arrays, their bytes, (array, lease) of the pool's buffers)
@@ -610,7 +749,7 @@ class H2DBatcher:
             self._items.append((host, like, fut, lease))
             self._bytes += host.nbytes
             should_flush = self._bytes >= self._flush_bytes
-        if should_flush:
+        if should_flush or (lease is not None and self.host_pool.waiting()):
             self.flush()
 
     def flush(self) -> None:
@@ -636,7 +775,7 @@ class H2DBatcher:
             self._raise_lander_error()
             while (
                 self._unlanded_bytes > 0
-                and self._unlanded_bytes + batch_bytes > self._inflight_cap
+                and self._unlanded_bytes + batch_bytes > self.inflight_cap_bytes
             ):
                 if window_wait is None:
                     window_wait = phase_stats.open_interval("h2d_window_wait")
@@ -866,8 +1005,10 @@ class ArrayAssembly:
     The buffer lives from the dispatch of the first read that lands in it
     (``host``: nothing is allocated at plan time) to ``finalize``, which
     hands it on and lets go.  A jax-array target of a megabyte and more that
-    uploads through an ``H2DBatcher`` with a ``HostBufferPool`` takes its
-    buffer from the pool, and the batcher gives it back once landed."""
+    uploads through an ``H2DBatcher`` with a ``HostBufferPool`` takes a range
+    of the pool's arena (``buffer_ready``, which waits for room; every read
+    into the leaf shares the one range), and the batcher gives it back once
+    landed."""
 
     def __init__(
         self,
@@ -887,6 +1028,8 @@ class ArrayAssembly:
         self._host_lock = threading.Lock()
         self._lease: Optional[np.ndarray] = None  # the pool's buffer under _host
         self._pool: Optional[HostBufferPool] = None
+        # the pool's promise of a range, while the first read waits for room
+        self._coming: Optional["asyncio.Future[np.ndarray]"] = None
         self._nbytes = serialization.array_nbytes(entry.shape, entry.dtype)
         if (
             h2d_batch is not None
@@ -896,40 +1039,58 @@ class ArrayAssembly:
             and self._nbytes >= _INTO_PLACE_MIN_BYTES
         ):
             self._pool = h2d_batch.host_pool
-            self._group = self._pool.reserve(self._nbytes)
+            self._pool.reserve(self._nbytes, obj_out)
 
     @property
     def host(self) -> np.ndarray:
         with self._host_lock:
             if self._host is None:
                 if self._pool is not None:
-                    self._lease = self._pool.take(self._nbytes, self._group)
-                    self._host = self._lease.view(
-                        serialization.string_to_dtype(self.entry.dtype)
-                    ).reshape(self.entry.shape)
+                    self._adopt(self._pool.take(self._nbytes))
                 else:
                     self._host = ArrayIOPreparer.empty_array_from_entry(self.entry)
             return self._host
 
+    def _adopt(self, lease: np.ndarray) -> None:
+        # Under _host_lock.
+        self._lease = lease
+        self._host = lease.view(
+            serialization.string_to_dtype(self.entry.dtype)
+        ).reshape(self.entry.shape)
+
     async def buffer_ready(self) -> None:
-        """Before a read into this assembly is dispatched: while the pool
-        has no buffer of its size free and its twin's, lent to a stateful
-        before this one, is still landing, wait for that one
-        (``HostBufferPool.coming``; the phase ``host_buffer_wait``)."""
+        """Before a read into this assembly is dispatched, and before it
+        takes its io slot: the leaf's range of the pool's arena is taken, by
+        the first of its reads to come, and where there is no room that read
+        waits for a landing to free some (``HostBufferPool.take``; the phase
+        ``host_buffer_wait``), the leaf's other reads with it."""
         from .. import phase_stats
 
+        if self._pool is None or self._host is not None:
+            return
         waited = None
+        if self._coming is None:
+            taken = self._pool.take(self._nbytes, asyncio.get_running_loop())
+            if isinstance(taken, np.ndarray):
+                self._settle_on(taken)
+                return
+            self._coming = taken
+            waited = phase_stats.open_interval("host_buffer_wait")
         try:
-            while self._host is None and self._pool is not None:
-                woken = self._pool.coming(self._nbytes, self._group)
-                if woken is None:
-                    break
-                if waited is None:
-                    waited = phase_stats.open_interval("host_buffer_wait")
-                await woken
+            lease = await self._coming
         finally:
             if waited is not None:
                 waited.close()
+        self._settle_on(lease)
+
+    def _settle_on(self, lease: np.ndarray) -> None:
+        """``lease`` is this leaf's, unless a piece copied in on an executor
+        thread has made the buffer meanwhile: then it goes back unused."""
+        with self._host_lock:
+            if self._host is None:
+                self._adopt(lease)
+            elif self._lease is not lease:
+                self._pool.give(lease, recycle=True)
 
     def expect(self, n: int) -> None:
         self._pending = n
@@ -985,8 +1146,9 @@ class ArrayAssembly:
 class IntoPlace:
     """Where one read lands in its assembly's buffer (``ReadReq.into``).  The
     plan knows that much; the memory is there from ``acquire()``, which the
-    read pipeline awaits when it dispatches the read, and the consumer lets
-    go of it (``release``) once it has seen the read arrive."""
+    read pipeline awaits when it dispatches the read, before the read's io
+    slot, and the consumer lets go of it (``release``) once it has seen the
+    read arrive."""
 
     def __init__(self, assembly: ArrayAssembly, offset: int, nbytes: int) -> None:
         self._assembly = assembly

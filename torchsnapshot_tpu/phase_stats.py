@@ -310,8 +310,9 @@ def add_counter(name: str, seconds: float, nbytes: int = 0, **more: int) -> None
     staged, its bytes, and ``members=`` how many leaves it packs;
     ``slab_read``: one occurrence a plan, the bytes it takes out of slab
     files, and ``members=``, ``reads=``, ``merged=``, batcher.py;
-    ``host_pool``: one occurrence a restore, the bytes read into recycled
-    host buffers, and ``fresh=``, ``hits=``, ``misses=``, ``high_water=``,
+    ``host_pool``: one occurrence a restore, the bytes read into pages of
+    the restore's host arena that had been handed out before, and ``fresh=``,
+    ``hits=``, ``misses=``, ``high_water=``,
     io_preparers/array.HostBufferPool).  The
     entry has ``s``, ``bytes``, ``n`` and whatever ``more`` names, and no
     ``wall``, and reaches neither hook, so it can name no gap of a trace;

@@ -814,17 +814,21 @@ class _ReadPipeline:
         self.consuming_cost = read_req.buffer_consumer.get_consuming_cost_bytes()
         self.buf: Optional[bytearray] = None
         self.hash64: Optional[int] = None
+        self.into: Optional[memoryview] = None  # where the read lands in place
         self.read_began = 0.0  # when storage was asked, its memory acquired
+
+    async def take_memory(self) -> None:
+        """The read is being dispatched: only now is the memory it lands in
+        taken (a restored leaf's range of the restore's host arena lives
+        from here to its landing; ``acquire`` holds the read while the arena
+        has no room, until a landing has freed some).  Before the io slot: a
+        read that waits for room holds no slot, so whatever holds a range
+        gets its slot without the waiter.  The same memory on a retry."""
+        if self.read_req.into is not None:
+            self.into = await self.read_req.into.acquire()
 
     async def read_buffer(self) -> "_ReadPipeline":
         consumer = self.read_req.buffer_consumer
-        # The read is being dispatched: only now is the memory it lands in
-        # taken (a restored leaf's host buffer lives from here to its
-        # landing; ``acquire`` may hold the read until the buffer its twin
-        # in the stateful before is landing from has come back).
-        into = self.read_req.into
-        if into is not None:
-            into = await into.acquire()
         self.read_began = time.monotonic()
         read_io = ReadIO(
             path=self.read_req.path,
@@ -833,7 +837,7 @@ class _ReadPipeline:
                 if self.read_req.byte_range is not None
                 else None
             ),
-            into=into,
+            into=self.into,
             # Ask for a read-fused digest only when this consumer will
             # actually verify the whole payload against one — merged
             # spanning reads (composite consumers) and digest-less entries
@@ -848,6 +852,7 @@ class _ReadPipeline:
         await self.storage.read(read_io)
         self.buf = read_io.buf
         self.hash64 = read_io.hash64
+        self.into = None
         return self
 
     async def consume_buffer(self, executor: Optional[Executor]) -> "_ReadPipeline":
@@ -1036,19 +1041,22 @@ async def execute_read_reqs(
       then k's restore target is alive on the device, and k+1's arrays
       landing beside it would raise the restore's HBM peak.  A read that
       finishes early is parked with its bytes debited from the budget.
-    - in a restore (``HostBufferPool``) a leaf's host buffer is taken when
-      its first read is dispatched, and a buffer of k whose H2D has landed
-      is k+1's to read into.  A read of k+1 whose twin in k (the leaf of
-      its byte size) is still landing is dispatched as above and then HELD,
-      in its io slot and with its bytes debited, until that buffer has come
-      back (``host_buffer_wait``): storage is asked for it from the twin's
-      landing on, which for k's last leaves is the loader's drain, and not
-      through k's last consumes and the drain itself.  That gives up read-
-      ahead for those leaves to read into pages already faulted in; a read
-      with no twin in flight is never held.
+    - in a restore (``HostBufferPool``) a leaf's host memory is a range of
+      the restore's one bounded arena, taken when its first read is
+      dispatched, BEFORE the read's io slot and in dispatch order (within a
+      group: smallest first), and given back by the H2D lander once the
+      leaf is on the device, to whichever read comes next.  A read that
+      finds no room is HELD there, its bytes debited and no slot taken,
+      until a landing of any group, its own included, has freed some
+      (``host_buffer_wait``; the wait makes the H2D batchers flush): it
+      gives up reads in flight beside each other to read into pages already
+      faulted in.  Whatever holds a range gets on without the waiter: a
+      read under way has its slot, a landing needs the lander alone, and a
+      read of k+1 parked behind the load holds a range only once every read
+      of k has one (k+1's first is dispatched when k's last has finished),
+      so it can starve no read of k of room.
     - no read of group k+2 starts before group k is loaded, all of its
-      host buffers landed from, so at most two groups' buffers are
-      resident.
+      host ranges landed from.
 
     With no loader a group counts as loaded once it is consumed.  An error
     in any read or consume cancels everything in flight and is raised."""
@@ -1113,6 +1121,7 @@ async def execute_read_reqs(
         attempt = 0
         while True:
             try:
+                await pipeline.take_memory()
                 slot_wait = phase_stats.open_interval("io_slot_wait")
                 async with io_semaphore:
                     slot_wait.close(min_s=0.001)

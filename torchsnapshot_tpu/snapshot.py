@@ -575,16 +575,27 @@ class Snapshot:
         the most.  An in-place numpy target of k+1 is filled as its reads
         arrive, as within a stateful; no user code runs ahead.
 
-        **Host read buffers are reused** (``HostBufferPool``, this call's
-        own): a leaf uploaded through the H2D batcher takes its buffer when
-        its first read is dispatched and the batcher gives it back once the
-        transfer has landed, to the next leaf of the same byte size: the
-        moments of an optimizer read into the buffers the parameters landed
-        from.  So nothing is unmapped beside the reads, and from the second
-        stateful on the reads fault in no fresh page.  What the pool holds
-        is freed when the last stateful is loaded (or the call fails; the
-        phase ``host_pool_free``), and the pool dies with the call; its account is the ``host_pool``
-        counter and the ``host_pool`` entry of the ``restore.end`` event.
+        **Host reads land in one bounded arena** (``HostBufferPool``, this
+        call's own): a leaf uploaded through the H2D batcher takes a
+        page-aligned range of it when its first read is dispatched and the
+        batcher gives the range back once the transfer has landed, to
+        whichever read comes next, of any size and any stateful.  The arena
+        is one allocation, sized from what the plan reserved (``max(the
+        largest leaf, the batchers' in-flight cap)``, no more than the
+        largest stateful; no knob) and never touched up front, so from its
+        first turn on every read, the first stateful's included, lands in
+        pages this call has touched before, and nothing is unmapped beside
+        the reads.  A read that finds no room waits for a landing of any
+        stateful, its own included (``host_buffer_wait``), and the wait
+        makes the batchers send what they hold to the device.  Targets on a
+        backend whose ``device_put`` keeps the host memory (the CPU's) get
+        plain buffers and no arena, so no restored array pins one.  The
+        arena is dropped when the last stateful is loaded (or the call
+        fails; the phase ``host_pool_free``) and the pool dies with the
+        call; its account is the ``host_pool`` counter and the
+        ``host_pool`` entry of the ``restore.end`` event (``fresh``: bytes
+        handed out from pages never handed out before; ``high_water``: the
+        arena's bytes ever handed out).  What it buys: PERF.md section 5.
 
         On-device contract: dense and chunked array uploads are drained
         before return (H2DBatcher.drain — their bytes are ON DEVICE, with
@@ -705,7 +716,7 @@ class Snapshot:
                     for plan in plans:
                         if plan is not None:
                             plan.h2d_batch.shutdown()
-                    # Nothing reads any more: the host buffers go here, and
+                    # Nothing reads any more: the host arena goes here, and
                     # not beside a read.
                     with phase_stats.timed("host_pool_free"):
                         host_pool.close()
@@ -746,8 +757,8 @@ class Snapshot:
             phase_stats.add_counter(
                 "read_ahead", pipeline.read_ahead_s, pipeline.read_ahead_bytes
             )
-            # How often a read landed in a buffer an earlier leaf had
-            # landed from.
+            # How much was read into pages of the host arena that an earlier
+            # leaf had landed from, and how much into pages never touched.
             pooled = host_pool.stats()
             phase_stats.add_counter("host_pool", 0.0, pooled.pop("bytes"), **pooled)
             event_metadata["duration_s"] = end - begin
@@ -814,7 +825,7 @@ class Snapshot:
             # What this stateful's restore still holds dies here, before the
             # next stateful's arrays may land: the restored values, and the
             # requests.  No host buffer of an uploaded leaf is among it: each
-            # went back to the restore's pool, or was dropped, as it landed.
+            # range went back to the restore's arena as its leaf landed.
             plan.read_reqs.clear()
             plan.futures.clear()
             del resolved, restored_state_dict
@@ -831,9 +842,10 @@ class Snapshot:
         host_pool: HostBufferPool,
     ) -> Optional["_StatefulPlan"]:
         """The ``plan_read`` phase of one stateful; None where the snapshot
-        holds nothing for it.  No host buffer is allocated here: a leaf's is
-        taken when its first read is dispatched (from ``host_pool``, the
-        restore's, where it uploads through the H2D batcher)."""
+        holds nothing for it.  No host memory is allocated here: a leaf's is
+        taken when its first read is dispatched (a range of ``host_pool``'s
+        arena, the restore's, where it uploads through the H2D batcher; the
+        plan only reserves its size there)."""
         local_manifest, merged_entries = get_manifest_for_rank(metadata, rank)
 
         # Current state dict provides in-place restore targets, avoiding 2x

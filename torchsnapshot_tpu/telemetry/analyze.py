@@ -66,7 +66,7 @@ PHASE_GROUPS: Dict[str, frozenset] = {
     # What the thread that drives a restore does between storage reads
     # (snapshot.py, manager.restore_latest): opening the snapshot, planning
     # a stateful's reads, handing the restored values to the stateful,
-    # freeing the restore's pool of host buffers once the last has loaded.
+    # freeing the restore's host arena once the last has loaded.
     # Work, and leaves: none encloses a read.  The same group as the
     # profiler's <kind>_drive tags.  (plan_read would suffix-match _read;
     # the explicit entry comes first.)
@@ -76,8 +76,8 @@ PHASE_GROUPS: Dict[str, frozenset] = {
     "memory_budget": frozenset({"budget_wait"}),
     "io_concurrency": frozenset({"io_slot_wait"}),
     # Waits of the restore path on H2D: a consumer held because the
-    # batcher's unlanded window is full, a read held until the host buffer
-    # its twin is landing from has come back, and the driver's wait for the
+    # batcher's unlanded window is full, a read held until a landing has
+    # freed room in the restore's host arena, and the driver's wait for the
     # tail to land once the reads are over.  The work under them is
     # h2d_land, so they do not inflate the h2d group.
     "h2d_wait": frozenset({"h2d_window_wait", "h2d_drain", "host_buffer_wait"}),
