@@ -7,7 +7,9 @@ would otherwise meet the driver as a ``null`` under ``per_layer`` on the chip.
 One rehearsal of a toy cell on the CPU, untraced and traced, under a
 benchmark file made of the toy ``configs`` and ``workloads`` of
 ``chipbench/tests/data/BENCHMARK.tiny.json`` and the ``per_layer`` list of the
-real ``BENCHMARK.json``.  Nothing a rehearsal prints is a device number."""
+real ``BENCHMARK.json``; and the traced one once more with the library's chunk
+size forced under the toy's leaves, for the metrics that read the chunked
+path.  Nothing a rehearsal prints is a device number."""
 
 import json
 import os
@@ -16,6 +18,7 @@ import sys
 
 import pytest
 
+from torchsnapshot_tpu import knobs
 from torchsnapshot_tpu.io_preparers.array import HostBufferPool
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -25,11 +28,16 @@ CELL = "codestral22b.kill-resume"
 # No toy leaf reaches the pool's megabyte, so a rehearsal's ``host_pool``
 # counter is empty and this one reader reads nothing from it.
 NEEDS_A_MEGABYTE_LEAF = "host_reuse_pct.resume"
+# No toy leaf reaches the chunk size of 512 MiB: these two are read from the
+# third rehearsal, where it is 100,000 B and the toy's 131,072 B leaves chunk.
+NEED_A_CHUNKED_LEAF = ("chunked_bytes_pct.resume", "chunk_assemble_s")
+TOY_CHUNK_BYTES = 100_000
 
 
 @pytest.fixture(scope="module")
 def lines(tmp_path_factory):
-    """The result lines of the two rehearsals, ``[untraced, traced]``."""
+    """The result lines of the three rehearsals, ``[untraced, traced, traced
+    with small chunks]``."""
     tmp = tmp_path_factory.mktemp("benchmark_surface")
     with open(os.path.join(ROOT, "chipbench", "tests", "data", "BENCHMARK.tiny.json")) as f:
         tiny = json.load(f)
@@ -56,9 +64,12 @@ def lines(tmp_path_factory):
             [sys.executable, os.path.join(ROOT, REAL["command"][1]),
              "--benchmark", str(bench_file), "--workload", CELL, "--seed", "3000000019",
              "--seconds", "1.5", "--rehearsal", "--trace", str(trace)],
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, cwd=ROOT, env=env,
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, cwd=ROOT,
+            env=dict(env, **more),
         )
-        for trace in (0, 1)
+        for trace, more in (
+            (0, {}), (1, {}), (1, {knobs.MAX_CHUNK_SIZE_ENV_VAR: str(TOY_CHUNK_BYTES)})
+        )
     ]
     out = []
     try:
@@ -92,7 +103,7 @@ def test_per_layer_metric_finds_what_it_reads(metric, lines):
         # what its reader takes from the counter (chipbench/metrics/<name>.py)
         assert {"bytes", "fresh"} <= set(HostBufferPool().stats())
         return
-    line = lines[1]
+    line = lines[2 if metric["name"] in NEED_A_CHUNKED_LEAF else 1]
     assert line["correct"] is True and line["failed"] == 0
     got = line["metrics"].get(metric["name"])
     assert got is not None, (
@@ -100,3 +111,6 @@ def test_per_layer_metric_finds_what_it_reads(metric, lines):
         f"(a phase renamed or no longer fired?); reported: {sorted(line['metrics'])}"
     )
     assert isinstance(got["value"], (int, float)) and got["unit"] == metric["unit"]
+    if metric["name"] in NEED_A_CHUNKED_LEAF:
+        assert got["value"] > 0
+        assert metric["name"] not in lines[1]["metrics"] or lines[1]["metrics"][metric["name"]]["value"] == 0

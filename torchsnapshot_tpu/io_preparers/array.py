@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import asyncio
 import bisect
+import functools
 import logging
 import math
 import mmap
@@ -283,18 +284,26 @@ class ArrayBufferStager(BufferStager):
             self._obj = None
             self._defer_checksum()
             return data
-        if staging.is_jax_array(obj):
+        from .chunked_array import _LazyDeviceSlice
+
+        to_host = None
+        if isinstance(obj, _LazyDeviceSlice):
+            # A chunk of a device array: sliced, transferred and let go on
+            # the worker, so no slice waits in HBM for its turn.
+            to_host = obj.to_host
+        elif staging.is_jax_array(obj):
             # Enqueue the async DMA now (we are being admitted by the
             # scheduler), materialize in the executor so concurrent stagers'
             # transfers overlap.
             staging.enqueue_d2h(obj)
-            loop = asyncio.get_running_loop()
+            to_host = functools.partial(staging.to_host, obj)
+        if to_host is not None:
             if executor is not None:
-                host = await loop.run_in_executor(
-                    executor, staging.to_host, obj
+                host = await asyncio.get_running_loop().run_in_executor(
+                    executor, to_host
                 )
             else:
-                host = staging.to_host(obj)
+                host = to_host()
         else:
             host = np.asarray(obj)
             if self._is_async_snapshot:
@@ -333,14 +342,14 @@ class ArrayBufferStager(BufferStager):
         nbytes = serialization.array_nbytes(
             self._entry.shape, self._entry.dtype
         ) if self._entry.serializer == Serializer.BUFFER_PROTOCOL.value else _approx_nbytes(self._obj)
-        from .chunked_array import _LazyHostSlice
+        from .chunked_array import _LazySlice
 
         if (
             staging.is_jax_array(self._obj)
             or self._is_async_snapshot
-            # Lazy host-slice handles materialize a host buffer at staging
+            # Lazy slice handles materialize a host buffer at staging
             # time — real memory the budget must see.
-            or isinstance(self._obj, _LazyHostSlice)
+            or isinstance(self._obj, _LazySlice)
         ):
             return nbytes
         if is_framed(self._entry):

@@ -9,28 +9,30 @@ size (admission granularity for the memory budget) and per-file size, and —
 crucially for replicated state — gives the partitioner sub-array units to
 load-balance across ranks.
 
-For jax device arrays the chunk view is ``arr[start:stop]`` — a lazy slice
-whose D2H transfer the stager performs per-chunk, keeping peak host memory at
-one chunk, not the whole array.
+For jax device arrays the chunk view is a handle (``_LazyDeviceSlice``): the
+stager slices on the device and transfers per chunk, on its worker, keeping
+peak host memory at one chunk, not the whole array, and HBM at one slice a
+staging worker beside the state.
 """
 
 from __future__ import annotations
 
-from typing import Any, List, Optional, Tuple
+import time
+from typing import Any, Iterable, List, Optional, Tuple
 
 import numpy as np
 
-from .. import serialization
+from .. import phase_stats, serialization
 from ..compression import is_framed
 from ..io_types import Future, ReadReq, WriteReq
 from ..manifest import Chunk, ChunkedTensorEntry, Shard, TensorEntry
 from .array import ArrayAssembly, ArrayBufferConsumer, ArrayIOPreparer
 
 
-class _LazyHostSlice:
-    """A dim-0 slice of a host-resident jax.Array, materialized only when
-    staged (``np.asarray`` → numpy view of the cached host copy).  Exposes
-    dtype/shape so write planning never touches the data."""
+class _LazySlice:
+    """A dim-0 slice ``base[start:stop]`` of a jax.Array that is taken only
+    when it is staged.  Exposes dtype/shape so write planning never touches
+    the data."""
 
     def __init__(self, base: Any, start: int, stop: int) -> None:
         self._base = base
@@ -45,11 +47,12 @@ class _LazyHostSlice:
     def shape(self):
         return (self._stop - self._start,) + tuple(self._base.shape[1:])
 
+
+class _LazyHostSlice(_LazySlice):
+    """Of a host-resident array: ``np.asarray`` → numpy view of the cached
+    host copy."""
+
     def __array__(self, dtype=None, copy=None):
-        import time
-
-        from .. import phase_stats
-
         begin = time.monotonic()
         out = np.asarray(self._base)[self._start : self._stop]
         # Attributed as d2h: materializing the cached host copy is where a
@@ -61,6 +64,68 @@ class _LazyHostSlice:
         if dtype is not None and out.dtype != np.dtype(dtype):
             out = out.astype(dtype)
         return out
+
+
+class _LazyDeviceSlice(_LazySlice):
+    """Of a device-resident array.  ``base[start:stop]`` is no view: it runs
+    on the device and its result is a buffer of its own, so slicing every
+    chunk at plan time kept a second copy of every chunked leaf in HBM for
+    the length of the save (at Brumby widths 6.42 GB of slices beside a 7.77
+    GB state: the save, not the restore, set the process's HBM peak;
+    PERF.md, PR 31).  ``to_host`` runs on a staging worker: slice, transfer,
+    let the slice go, so the slices alive are one a worker."""
+
+    def to_host(self) -> np.ndarray:
+        from .. import staging
+
+        return staging.to_host(self._base[self._start : self._stop])
+
+    def __array__(self, dtype=None, copy=None):
+        out = self.to_host()
+        return out if dtype is None else out.astype(dtype, copy=False)
+
+
+def count_chunked(counter: str, entries: Iterable[Any]) -> None:
+    """One occurrence of the counter ``chunked_read`` (a stateful's read
+    plan) or ``chunked_write`` (a take's write plan): the ``bytes`` and
+    ``leaves`` of the plan's chunked entries and how many ``chunks`` they are
+    in.  A plan with none counts too, with zeros: ``n`` is plans."""
+    chunked = [e for e in entries if isinstance(e, ChunkedTensorEntry)]
+    phase_stats.add_counter(
+        counter,
+        0.0,
+        sum(serialization.array_nbytes(e.shape, e.dtype) for e in chunked),
+        leaves=len(chunked),
+        chunks=sum(len(e.chunks) for e in chunked),
+    )
+
+
+class _ChunkedAssembly(ArrayAssembly):
+    """A chunked leaf's assembly, with the phase ``chunk_assemble``: one
+    interval a leaf, from the arrival of the first of its chunks (a consume
+    begins) to the arrival of the last, when the buffer is whole and about
+    to be handed on (to the H2D batcher for a jax target).  Both ends are on
+    the read pipeline's loop thread.  The leaf's host buffer, a range of the
+    restore's arena, is held from before the first and until its landing:
+    ``host_buffer_wait`` and the chunks' reads come before this interval,
+    ``h2d_window_wait``, ``h2d_dispatch`` and ``h2d_land`` after it."""
+
+    _assembling: Optional[phase_stats.open_interval] = None
+
+    def chunk_arrived(self) -> None:
+        if self._assembling is None:
+            self._assembling = phase_stats.open_interval("chunk_assemble")
+
+    def finalize(self) -> None:
+        if self._assembling is not None:
+            self._assembling.close(self._nbytes)
+        super().finalize()
+
+
+class _ChunkConsumer(ArrayBufferConsumer):
+    async def consume_buffer(self, buf: Any, executor: Optional[Any] = None) -> None:
+        self._assembly.chunk_arrived()
+        await super().consume_buffer(buf, executor)
 
 
 class ChunkedArrayIOPreparer:
@@ -101,6 +166,8 @@ class ChunkedArrayIOPreparer:
             # caches the base array's host copy, so N chunk slices cost one
             # read total.
             return _LazyHostSlice(obj, start, stop)
+        if staging.is_jax_array(obj):
+            return _LazyDeviceSlice(obj, start, stop)
         return obj[start:stop]
 
     @classmethod
@@ -162,7 +229,7 @@ class ChunkedArrayIOPreparer:
             shape=entry.shape,
             replicated=entry.replicated,
         )
-        assembly = ArrayAssembly(
+        assembly = _ChunkedAssembly(
             entry=pseudo_entry, obj_out=obj_out, h2d_batch=h2d_batch
         )
         itemsize = serialization.per_element_nbytes(entry.dtype)
@@ -191,7 +258,7 @@ class ChunkedArrayIOPreparer:
                 ReadReq(
                     path=tensor_entry.location,
                     byte_range=tensor_entry.byte_range,
-                    buffer_consumer=ArrayBufferConsumer(
+                    buffer_consumer=_ChunkConsumer(
                         assembly=assembly,
                         flat_offset=flat_offset,
                         nbytes=nbytes,

@@ -313,7 +313,10 @@ def add_counter(name: str, seconds: float, nbytes: int = 0, **more: int) -> None
     ``host_pool``: one occurrence a restore, the bytes read into pages of
     the restore's host arena that had been handed out before, and ``fresh=``,
     ``hits=``, ``misses=``, ``high_water=``,
-    io_preparers/array.HostBufferPool).  The
+    io_preparers/array.HostBufferPool; ``chunked_read``: one occurrence a
+    stateful's read plan, the bytes of its chunked leaves, and ``leaves=``,
+    ``chunks=``; ``chunked_write``: the same, one occurrence a take's write
+    plan, io_preparers/chunked_array.count_chunked).  The
     entry has ``s``, ``bytes``, ``n`` and whatever ``more`` names, and no
     ``wall``, and reaches neither hook, so it can name no gap of a trace;
     ``delta()`` differences it like any other."""

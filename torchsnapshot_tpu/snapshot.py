@@ -52,6 +52,7 @@ from .event import Event
 from .event_handlers import log_event
 from .flatten import flatten, inflate
 from .io_preparers.array import H2DBatcher, HostBufferPool
+from .io_preparers.chunked_array import count_chunked
 from .io_types import Future, ReadReq, StoragePlugin, WriteReq
 from .manifest import (
     Entry,
@@ -473,6 +474,7 @@ class Snapshot:
                 )
                 entries[logical_path] = entry
                 write_reqs += obj_write_reqs
+            count_chunked("chunked_write", entries.values())
 
         with ttrace.span("partition", n_write_reqs=len(write_reqs)):
             entries, write_reqs = partition_write_reqs(entries, write_reqs, pg)
@@ -771,6 +773,9 @@ class Snapshot:
             event_metadata["slab_read_bytes"] = int(
                 phases_delta.get("slab_read", {}).get("bytes", 0)
             )
+            event_metadata["chunked_read_bytes"] = int(
+                phases_delta.get("chunked_read", {}).get("bytes", 0)
+            )
             event_metadata["bytes"] = int(
                 max(
                     (v.get("bytes", 0) for v in phases_delta.values()),
@@ -901,6 +906,7 @@ class Snapshot:
         except BaseException:
             h2d_batch.shutdown()
             raise
+        count_chunked("chunked_read", sub_manifest.values())
         tmetrics.record_entries("restore", len(sub_manifest))
         return _StatefulPlan(
             stateful_key, stateful, read_reqs, futures, container_entries, h2d_batch

@@ -56,6 +56,10 @@ PHASE_GROUPS: Dict[str, frozenset] = {
             # spans their checksum and consume_copy, so by wall (a union)
             # it adds the loop's turns between them and nothing twice.
             "slab_scatter",
+            # A chunked leaf between the arrival of its first chunk and of
+            # its last (io_preparers/chunked_array.py): the same kind of
+            # stretch, over the chunks' checksum and consume_copy.
+            "chunk_assemble",
             # Content-defined chunk-boundary scan (chunker.py): a rolling
             # hash over the staged bytes — hash-class work, same group as
             # checksum.
