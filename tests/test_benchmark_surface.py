@@ -11,14 +11,17 @@ real ``BENCHMARK.json``; and the traced one once more with the library's chunk
 size forced under the toy's leaves, for the metrics that read the chunked
 path.  Nothing a rehearsal prints is a device number."""
 
+import importlib
 import json
 import os
 import subprocess
 import sys
+import types
 
 import pytest
 
-from torchsnapshot_tpu import knobs
+from torchsnapshot_tpu import knobs, phase_stats
+from torchsnapshot_tpu.io_preparers import array as array_mod
 from torchsnapshot_tpu.io_preparers.array import HostBufferPool
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -28,6 +31,9 @@ CELL = "codestral22b.kill-resume"
 # No toy leaf reaches the pool's megabyte, so a rehearsal's ``host_pool``
 # counter is empty and this one reader reads nothing from it.
 NEEDS_A_MEGABYTE_LEAF = "host_reuse_pct.resume"
+# Nor does a toy restore make an arena, so nothing is populated: this reader is
+# held to the phase that a pool which does make one fires.
+NEEDS_AN_ARENA = "arena_populate_s"
 # No toy leaf reaches the chunk size of 512 MiB: these two are read from the
 # third rehearsal, where it is 100,000 B and the toy's 131,072 B leaves chunk.
 NEED_A_CHUNKED_LEAF = ("chunked_bytes_pct.resume", "chunk_assemble_s")
@@ -103,6 +109,10 @@ def test_per_layer_metric_finds_what_it_reads(metric, lines):
         # what its reader takes from the counter (chipbench/metrics/<name>.py)
         assert {"bytes", "fresh"} <= set(HostBufferPool().stats())
         return
+    if metric["name"] == NEEDS_AN_ARENA:
+        assert metric["name"] not in lines[1]["metrics"]  # no arena, nothing read
+        arena_populate_s_reads_the_pools_phase(metric)
+        return
     line = lines[2 if metric["name"] in NEED_A_CHUNKED_LEAF else 1]
     assert line["correct"] is True and line["failed"] == 0
     got = line["metrics"].get(metric["name"])
@@ -114,3 +124,49 @@ def test_per_layer_metric_finds_what_it_reads(metric, lines):
     if metric["name"] in NEED_A_CHUNKED_LEAF:
         assert got["value"] > 0
         assert metric["name"] not in lines[1]["metrics"] or lines[1]["metrics"][metric["name"]]["value"] == 0
+
+
+def arena_populate_s_reads_the_pools_phase(metric):
+    """A pool that makes an arena (two statefuls of two leaves behind a window
+    of two) fires the phase once, at its first take, and the reader divides
+    that phase's wall by the window's restores; where it never fired, as in a
+    library from before the population, the reader reads nothing."""
+    read = importlib.import_module("chipbench.metrics." + metric["name"]).read
+    restores = [{"op": "kill_resume", "ok": True}] * 2
+    account = types.SimpleNamespace(window_operations=lambda op: restores)
+    page = array_mod._PAGE
+    class Batcher:  # what the pool knows of an H2DBatcher
+        inflight_cap_bytes = 2 * page
+
+    pool, batcher = HostBufferPool(), Batcher()
+    pool.attach(batcher)
+    on_a_chip = types.SimpleNamespace(devices=lambda: [types.SimpleNamespace(platform="tpu")])
+    for _ in range(2):
+        pool.begin_group()
+        pool.reserve(page, on_a_chip)
+        pool.reserve(page, on_a_chip)
+    before = phase_stats.snapshot()
+    assert read({"account": account, "phases": phase_stats.delta(before)}) is None
+    pool.take(page)
+    pool.take(page)
+    phases = phase_stats.delta(before)
+    if array_mod._page_toucher() is None:  # no native library here: nothing populates
+        assert "arena_populate" not in phases and pool.stats()["populated"] == 0
+        return
+    assert phases["arena_populate"]["n"] == 1 and pool.stats()["populated"] == 2 * page
+    assert phases["arena_populate"]["bytes"] == 2 * page
+    got = read({"account": account, "phases": phases})
+    assert got == phases["arena_populate"]["wall"] / 2 and got > 0
+
+
+def test_the_population_brought_the_benchmark_one_metric_and_one_reader():
+    (entry,) = [m for m in REAL["per_layer"] if m["name"] == NEEDS_AN_ARENA]
+    resume_cells = next(m for m in REAL["end_to_end"] if m["name"] == "resume_s")["workloads"]
+    assert entry == {
+        "name": "arena_populate_s", "unit": "s", "better": "lower", "source": "program_span",
+        "layer": "H2D", "moves": "resume_s", "workloads": resume_cells,
+    }
+    # appended, behind the newest metric the benchmark had
+    names = [m["name"] for m in REAL["per_layer"]]
+    assert names.index(NEEDS_AN_ARENA) == names.index("chunk_assemble_s") + 1 == 17
+    assert os.path.exists(os.path.join(ROOT, "chipbench", "metrics", NEEDS_AN_ARENA + ".py"))

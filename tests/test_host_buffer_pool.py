@@ -2,7 +2,8 @@
 ``Snapshot.restore``): a leaf takes a page-aligned range of it when its read is
 dispatched and the lander gives the range back when the H2D has landed, to
 whichever read comes next, of any size and any stateful, which waits for room
-where there is none and makes the batchers flush.  Restores of train-state-
+where there is none and makes the batchers flush; the arena is populated in
+bulk, once, before its first range is handed out.  Restores of train-state-
 shaped trees (three statefuls, one tree) through the fs plug-in, the reads one
 at a time so that each has its number; then the pool alone."""
 
@@ -125,7 +126,11 @@ def world(monkeypatch, tmp_path):
     """The CPU backend taken for an accelerator (``accelerator``: it keeps no
     host memory, and the arena begins where it copies), and every seam of the
     pool recorded: the pools made (weakly), the arenas and the plain buffers
-    made (weakly, their sizes, and where they begin), the reads into place."""
+    made (weakly, their sizes, and where they begin), the reads into place,
+    and what was populated (``touches``: where, how much, on which thread,
+    and after how many reads into place), by the native pool's own
+    ``touch_pages`` where the library has it (``populates`` False: a library
+    that predates the symbol)."""
     w = types.SimpleNamespace(
         root=str(tmp_path),
         pools=[],
@@ -140,6 +145,8 @@ def world(monkeypatch, tmp_path):
         reads=[],
         fails=None,
         tails=None,
+        touches=[],
+        populates=True,
     )
 
     class RecordedPool(HostBufferPool):
@@ -168,6 +175,19 @@ def world(monkeypatch, tmp_path):
         return buf
 
     monkeypatch.setattr(array_mod, "_arena_memory", recorded_arena)
+
+    really_touches = array_mod._page_toucher()
+
+    def recorded_touch(buf):
+        w.touches.append(
+            (buf.ctypes.data, buf.nbytes, threading.current_thread().name, len(w.reads))
+        )
+        if really_touches is not None:
+            really_touches(buf)
+
+    monkeypatch.setattr(
+        array_mod, "_page_toucher", lambda: recorded_touch if w.populates else None
+    )
 
     def recorded_buffer(nbytes):
         buf = np.empty(nbytes, dtype=np.uint8)
@@ -276,6 +296,7 @@ def test_three_same_shaped_statefuls_are_read_into_one_arena(world):
         "hits": 2 * LEAVES,
         "misses": LEAVES,
         "high_water": STATEFUL_BYTES,
+        "populated": STATEFUL_BYTES,
     }
     # because a read that found no room waited for a landing
     assert end["phases"]["host_buffer_wait"] > 0
@@ -522,12 +543,100 @@ def test_what_is_not_uploaded_through_the_batcher_never_touches_the_pool(world):
     assert target["host"].state_dict()["w"] is in_place
     assert_equal_bits(target, saved)
     assert end["host_pool"] == dict.fromkeys(
-        ("bytes", "fresh", "hits", "misses", "high_water"), 0
+        ("bytes", "fresh", "hits", "misses", "high_water", "populated"), 0
     )
     assert not world.arenas and not world.plain
     got = Snapshot(path).read_object("0/host/w")
     np.testing.assert_array_equal(got, saved["host"].state_dict()["w"])
     assert len(world.pools) == 1 and nothing_left(world)
+
+
+POOL_ACCOUNT = ("bytes", "fresh", "hits", "misses", "high_water")
+
+
+def test_the_arena_is_populated_once_whole_before_any_read_lands_in_it(world):
+    saved = make_app(10)
+    path = take(world, "snap", saved)
+    target = make_app(0, zero=True)
+    before = phase_stats.snapshot()
+    _, end = restore(world, path, target)
+    assert_equal_bits(target, saved)
+    # one touch a restore: the whole arena, on the thread of the first take,
+    # before a single read into place had been dispatched
+    ((begin, nbytes),) = world.addresses
+    assert world.touches == [(begin, nbytes, "tpusnap-read-pipeline", 0)]
+    assert nbytes == STATEFUL_BYTES and len(world.reads) == len(KEYS) * LEAVES
+    assert end["host_pool"]["populated"] == STATEFUL_BYTES
+    # with a phase of its own: in the call's account, so not unattributed
+    populate = phase_stats.delta(before)["arena_populate"]
+    assert populate["n"] == 1 and populate["bytes"] == STATEFUL_BYTES
+    assert end["phases"]["arena_populate"] == pytest.approx(populate["wall"])
+    assert nothing_left(world)
+
+
+def test_a_library_without_the_symbol_restores_as_before_and_says_nothing(world, caplog):
+    """What is handed out for the first time is ``fresh`` whether or not its
+    pages were populated: the account of a restore is the same numbers with
+    the population and without, and without it nothing is logged."""
+    saved = make_app(11)
+    path = take(world, "snap", saved)
+    target = make_app(0, zero=True)
+    _, populated = restore(world, path, target)
+    assert_equal_bits(target, saved)
+    world.populates = False
+    del world.touches[:]
+    target = make_app(0, zero=True)
+    before = phase_stats.snapshot()
+    with caplog.at_level("WARNING"):
+        _, end = restore(world, path, target)
+    assert_equal_bits(target, saved)
+    assert not world.touches and end["host_pool"]["populated"] == 0
+    assert "arena_populate" not in end["phases"]
+    assert "arena_populate" not in phase_stats.delta(before)
+    assert not [r for r in caplog.records if r.name.startswith("torchsnapshot_tpu")]
+    assert populated["host_pool"]["populated"] == STATEFUL_BYTES
+    for key in POOL_ACCOUNT:
+        assert end["host_pool"][key] == populated["host_pool"][key], key
+    assert world.arena_sizes == [STATEFUL_BYTES] * 2 and not world.plain
+    assert nothing_left(world)
+
+
+def test_a_restore_through_a_populated_arena_is_bit_exact(world, monkeypatch):
+    """The arena arrives full of ones, so the byte that the population
+    writes to each page is there to be seen; every leaf is read over it."""
+    if array_mod._page_toucher() is None:
+        pytest.skip("the native library has no tpusnap_touch_pages")
+    arenas = []
+    recorded = array_mod._arena_memory
+
+    def full_of_ones(nbytes):
+        buf = recorded(nbytes)
+        buf[:] = 0xFF
+        arenas.append(buf)
+        return buf
+
+    monkeypatch.setattr(array_mod, "_arena_memory", full_of_ones)
+    seen = []
+    touch = array_mod._page_toucher()
+
+    def touched_and_looked_at(buf):
+        touch(buf)
+        seen.append(int((buf == 0).sum()))
+
+    monkeypatch.setattr(array_mod, "_page_toucher", lambda: touched_and_looked_at)
+    shapes = ((256, 1024), (513, 1024), (770, 1024))  # leaves that end inside a page
+    saved = make_app(12, shapes=shapes)
+    path = take(world, "snap", saved)
+    target = make_app(0, shapes=shapes, zero=True)
+    _, end = restore(world, path, target)
+    assert_equal_bits(target, saved)
+    ((begin, nbytes),) = world.addresses
+    # the first byte of the arena (which begins inside a page here) and of
+    # each page that begins in it, and not one byte more
+    assert seen == [1 + (begin + nbytes - 1) // PAGE - begin // PAGE]
+    assert end["host_pool"]["populated"] == nbytes
+    del arenas[:]
+    assert nothing_left(world)
 
 
 # ----------------------------------------------------------- the pool alone
@@ -593,6 +702,7 @@ def test_a_take_is_a_range_a_give_frees_it_and_neighbours_coalesce():
         "hits": 2,
         "misses": 5,
         "high_water": 8 * PAGE + 5 * PAGE,
+        "populated": 8 * PAGE if array_mod._page_toucher() else 0,
     }
 
 
@@ -702,7 +812,64 @@ def test_the_arena_is_sized_from_what_the_plan_reserved(
     assert first.shape == (PAGE,) and pool.stats()["fresh"] == PAGE
 
 
-def test_the_arena_is_not_touched_up_front_and_goes_when_the_restore_ends(monkeypatch):
+@pytest.fixture
+def touches(monkeypatch):
+    """The populations of pools made alone, ``(where, how much)`` each, with
+    nothing really written."""
+    seen = []
+    monkeypatch.setattr(
+        array_mod, "_page_toucher", lambda: lambda buf: seen.append((buf.ctypes.data, buf.nbytes))
+    )
+    return seen
+
+
+def test_the_first_take_populates_the_whole_arena_and_no_later_one_does(touches):
+    pool, _batcher = pool_of([4, 2, 2], [4, 2, 2], window=6)
+    assert not touches  # nothing before the first take
+    a = pool.take(2 * PAGE - 7)
+    base = a.ctypes.data
+    # all six pages, inside the take that handed out the first range, which
+    # wanted two of them
+    assert touches == [(base, 6 * PAGE)]
+    b = pool.take(4 * PAGE)  # pages never handed out: populated all the same
+    pool.give(a, recycle=True)
+    c = pool.take(PAGE)
+    plain = pool.take(4 * PAGE)  # no room and no loop: a plain buffer
+    assert len(touches) == 1 and (b.ctypes.data, c.ctypes.data) == (base + 2 * PAGE, base)
+    stats = pool.stats()
+    assert stats["populated"] == 6 * PAGE and stats["high_water"] == 10 * PAGE
+    # handed out for the first time is fresh, populated or not
+    assert stats["fresh"] == (2 * PAGE - 7) + 4 * PAGE + 4 * PAGE and stats["bytes"] == PAGE
+    assert not in_arena(plain, base, 6)
+
+
+@pytest.mark.parametrize(
+    "path",
+    ["host_memory_kept", "one_pooled_leaf", "everything_fits", "after_a_range_came_back_unfit"],
+)
+def test_no_plain_path_populates(touches, path):
+    if path == "host_memory_kept":
+        pool, _batcher = pool_of(*[[1, 2, 4, 8]] * 3, window=4, target=ON_THE_HOST)
+    elif path == "one_pooled_leaf":
+        pool, _batcher = pool_of([8], window=4)
+    elif path == "everything_fits":
+        pool, _batcher = pool_of([2, 3], [], window=20)
+    else:
+        pool, _batcher = pool_of([4, 4], [4, 4], window=8)
+        a = pool.take(4 * PAGE)
+        assert len(touches) == 1
+        del touches[:]
+        pool.give(a, recycle=False)  # no arena from here on
+    before = phase_stats.snapshot()
+    bufs = [pool.take(2 * PAGE), pool.take(PAGE)]
+    assert not touches and "arena_populate" not in phase_stats.delta(before)
+    stats = pool.stats()
+    assert stats["populated"] == (8 * PAGE if path == "after_a_range_came_back_unfit" else 0)
+    assert stats["hits"] == 0 and stats["bytes"] == 0
+    assert all(buf.dtype == np.uint8 for buf in bufs)
+
+
+def test_the_arena_goes_when_the_restore_ends_and_its_last_range_is_back(monkeypatch):
     arenas = []
 
     def recorded(nbytes):

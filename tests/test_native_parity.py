@@ -227,6 +227,46 @@ def test_read_ranges_into_parity(tmp_path):
     )
 
 
+@pytest.mark.parametrize(
+    "begins_at, nbytes",
+    [
+        # several slices (8 MiB each), neither end on a page or a slice boundary
+        pytest.param(100, (20 << 20) + 1234, id="several_slices_ends_inside_pages"),
+        pytest.param(0, 3 * 4096, id="whole_pages"),
+        pytest.param(4000, 50, id="inside_one_page"),
+        pytest.param(4090, 10, id="over_one_page_boundary"),
+        pytest.param(0, (8 << 20) + 4096, id="one_page_past_a_slice"),
+    ],
+)
+def test_touch_pages_writes_every_page_of_the_range_and_nothing_else(begins_at, nbytes):
+    """One byte a page: the first of the range and the first of every page
+    that begins inside it.  Every other byte of those pages, and the pages
+    either side, stay as they were."""
+    import mmap
+
+    native = NativeFileIO.maybe_create()
+    if native is None or not native.has_touch_pages:
+        pytest.skip("native touch_pages unavailable")
+    page = mmap.PAGESIZE
+    raw = np.empty(nbytes + 4 * page, dtype=np.uint8)
+    aligned = -raw.ctypes.data % page
+    whole = raw[aligned:]  # begins at a page boundary
+    whole[:] = 0xAB
+    begin = page + begins_at  # a canary page in front, at least one behind
+    buf = whole[begin : begin + nbytes]
+    native.touch_pages(buf)
+    assert (whole[:begin] == 0xAB).all() and (whole[begin + nbytes :] == 0xAB).all()
+    written = np.flatnonzero(buf != 0xAB)
+    expected = [0] + [
+        at - begin for at in range((begin // page + 1) * page, begin + nbytes, page)
+    ]
+    assert written.tolist() == expected and (buf[written] == 0).all()
+    # said twice changes nothing more; an empty range is nobody's page
+    native.touch_pages(buf)
+    native.touch_pages(whole[:0])
+    assert np.flatnonzero(whole != 0xAB).tolist() == [begin + at for at in expected]
+
+
 # ------------------------------------------------- staleness / degrade
 
 
@@ -281,6 +321,49 @@ def test_missing_symbols_degrade_not_crash(tmp_path, monkeypatch):
     # identical to the full-featured value.
     manifest_text = (tmp_path / "snap" / ".snapshot_metadata").read_text()
     assert "xxh64s:" in manifest_text
+
+
+def test_a_library_without_touch_pages_is_not_reported_degraded(monkeypatch, caplog):
+    """The one optional data-plane symbol: nothing falls back to Python
+    without it (a restore's host arena is populated by its reads, as before
+    the symbol existed), so a library that lacks only it logs nothing and
+    emits no ``native.degraded`` event."""
+    import ctypes
+    import logging
+
+    from torchsnapshot_tpu.event_handlers import (
+        register_event_handler,
+        unregister_event_handler,
+    )
+
+    if NativeFileIO.maybe_create() is None:
+        pytest.skip("native library unavailable")
+
+    class WithoutTouchPages:
+        def __init__(self, lib):
+            self._lib = lib
+
+        def __getattr__(self, name):
+            if name == "tpusnap_touch_pages":
+                raise AttributeError(name)
+            return getattr(self._lib, name)
+
+    real_cdll = ctypes.CDLL
+    monkeypatch.setattr(ctypes, "CDLL", lambda path: WithoutTouchPages(real_cdll(path)))
+    monkeypatch.setattr(NativeFileIO, "_instance", None)
+    monkeypatch.setattr(NativeFileIO, "_failed", False)
+    monkeypatch.setattr(NativeFileIO, "_degraded_reported", False)
+    events = []
+    register_event_handler(events.append)
+    try:
+        with caplog.at_level(logging.WARNING):
+            io = NativeFileIO.maybe_create()
+    finally:
+        unregister_event_handler(events.append)
+    assert io is not None and not io.has_touch_pages
+    assert io.has_ranged_read and io.has_fused_write and io.has_pool
+    assert not caplog.records and not NativeFileIO._degraded_reported
+    assert not [e for e in events if e.name == "native.degraded"]
 
 
 def test_native_knob_disables_plugin_capabilities(monkeypatch):

@@ -258,6 +258,7 @@ def test_the_chrome_trace_draws_each_phase_once_a_site(tmp_path):
         ("h2d_drain", "h2d_wait"),
         ("host_buffer_wait", "h2d_wait"),
         ("host_pool_free", "driver"),
+        ("arena_populate", "driver"),
     ],
 )
 def test_every_new_phase_has_a_resource_group(phase, group):

@@ -115,11 +115,14 @@ def test_rss_profiler_records_deltas():
 
     deltas: list = []
     with measure_rss_deltas(deltas, interval_ms=10.0):
-        blob = np.ones(30_000_000, np.uint8)  # ~30 MB
+        # Over glibc's largest mmap threshold (32 MiB), so that the pages are
+        # fresh whatever this process has freed before: a smaller blob can be
+        # cut from heap memory that is already resident, and RSS then stays.
+        blob = np.ones(80_000_000, np.uint8)  # ~80 MB
         time.sleep(0.08)
         del blob
     assert deltas, "sampler recorded nothing"
-    assert max(deltas) > 10_000_000, max(deltas)  # saw the ~30 MB allocation
+    assert max(deltas) > 10_000_000, max(deltas)  # saw the ~80 MB allocation
 
 
 def test_call_outside_loop_propagates_exceptions():

@@ -1732,6 +1732,57 @@ int tpusnap_read_ranges_hash(const char* path, int n, const int64_t* offsets,
   return 0;
 }
 
+// First touch of a host buffer, in bulk: one byte is written to every page
+// of [buf, buf + nbytes), in slices of CHUNK drained by the pool and the
+// caller together.  A page fault of fresh anonymous memory is what a first
+// touch costs, and many threads take faults side by side where one read
+// into such pages takes them one at a time.  The byte is written BY THE
+// KERNEL, a readv from /dev/zero of one byte a page: on the hosts measured
+// (PERF.md section 7) a page that the process alone has stored to still
+// costs the first pread into it most of a fresh page's price, and a page
+// that a read syscall has written to costs it nothing, so a plain store
+// would populate the memory and leave the reads slow.  Where /dev/zero
+// cannot be read the byte is stored from here, which is still a first touch
+// (a write, not a read: a read maps the shared zero page and the fault comes
+// again with the first write).  The caller owns the range and nothing else
+// uses it yet: the byte written is 0, the first of the range and of each
+// page that begins in it; every other byte is left as it was.
+void tpusnap_touch_pages(void* buf, int64_t nbytes) {
+  if (buf == nullptr || nbytes <= 0) return;
+  const int64_t CHUNK = 8 << 20;
+  const int64_t page = ::sysconf(_SC_PAGESIZE);
+  uint8_t* base = static_cast<uint8_t*>(buf);
+  const int zero = ::open("/dev/zero", O_RDONLY | O_CLOEXEC);
+  // One byte at begin, begin + page, ... below end.
+  auto touch = [=](int64_t begin, int64_t end) {
+    struct iovec iov[1024];  // IOV_MAX
+    for (int64_t at = begin; at < end;) {
+      int n = 0;
+      for (; n < 1024 && at < end; ++n, at += page) {
+        iov[n].iov_base = base + at;
+        iov[n].iov_len = 1;
+      }
+      if (zero < 0 || ::readv(zero, iov, n) != n) {
+        for (int i = 0; i < n; ++i) {
+          *static_cast<volatile uint8_t*>(iov[i].iov_base) = 0;
+        }
+      }
+    }
+  };
+  touch(0, 1);  // the range's first byte, wherever in its page it lies
+  // The first page boundary inside the range, as an offset from buf.
+  const int64_t first =
+      page - static_cast<int64_t>(reinterpret_cast<uintptr_t>(buf) %
+                                  static_cast<uintptr_t>(page));
+  TaskSet ts;
+  for (int64_t begin = first; begin < nbytes; begin += CHUNK) {
+    int64_t end = nbytes - begin < CHUNK ? nbytes : begin + CHUNK;
+    ts.tasks.emplace_back([=] { touch(begin, end); });
+  }
+  ts.run_all();
+  if (zero >= 0) ::close(zero);
+}
+
 // ------------------------------------------------------------ zlib encode
 // Native deflate directly into a caller-provided buffer (the compression
 // frame's payload region) — skips the Python-side copy of the compressed

@@ -397,13 +397,30 @@ def _fresh_host_buffer(nbytes: int) -> np.ndarray:
 
 
 def _arena_memory(nbytes: int) -> np.ndarray:
-    """``nbytes`` of anonymous memory that begins at a page boundary and has
-    not been touched: one allocation, one ``munmap`` when the last view of it
-    dies.  A function of its own so that a test can choose where it begins
-    (the CPU backend aliases a 64-byte-aligned range into the landed array)."""
+    """``nbytes`` of anonymous memory that begins at a page boundary, not yet
+    touched (``_make_arena`` populates it): one allocation, one
+    ``munmap`` when the last view of it dies.  A function of its own so that
+    a test can choose where it begins (the CPU backend aliases a 64-byte-
+    aligned range into the landed array)."""
     raw = np.empty(nbytes + _PAGE, dtype=np.uint8)
     begin = -raw.ctypes.data % _PAGE
     return raw[begin : begin + nbytes]
+
+
+def _page_toucher() -> Optional[Any]:
+    """What populates an arena: the native pool's ``touch_pages`` (every page
+    of a buffer written once by the kernel, a ``readv`` of one byte from
+    ``/dev/zero``, in parallel, on the threads the restore's reads run on and
+    on the caller's, the GIL released; no thread is started), or
+    None without the native library or with one that predates the symbol:
+    the reads then fault the pages in as they land, one at a time.  A
+    function of its own so that a test can see what is touched, and when."""
+    from ..native_io import NativeFileIO
+
+    native = NativeFileIO.maybe_create()
+    if native is None or not native.has_touch_pages:
+        return None
+    return native.touch_pages
 
 
 def _keeps_host_memory(target: Any) -> bool:
@@ -426,14 +443,19 @@ class HostBufferPool:
     before the first read), takes a range when the first of its reads is
     dispatched (``take``), and the batcher's lander gives the range back once
     the transfer has landed (``give``).  The arena is one allocation, made at
-    the first ``take`` and never touched up front, so a page costs its first
-    touch when the first leaf is read into it and nothing after: from the
-    arena's first turn on every read, the first stateful's included, lands
-    in pages the restore has touched before, and nothing is unmapped beside
-    the reads (a ``munmap`` holds the GIL and takes the address space's lock
-    for writing while the readers fault pages in under it).  What a first
-    touch costs against a read into a touched page, on the hosts measured,
-    is in PERF.md section 5.
+    the first ``take`` and **populated then and there**: every page of it is
+    written once, in parallel, on the native pool that the restore's reads
+    run on (idle at that moment: no read has been dispatched into it yet;
+    ``_page_toucher``, the phase ``arena_populate``, no thread started),
+    before a range of it is handed to a read.  So no read of the restore,
+    the first stateful's included, faults a fresh page, and nothing is
+    unmapped beside the reads (a ``munmap`` holds the GIL and takes the
+    address space's lock for writing while the readers fault pages in under
+    it).  What a first touch costs one reader against many threads at once,
+    and against a read into a touched page, on the hosts measured, is in
+    PERF.md sections 5 and 7.  A native library without the symbol, or none,
+    populates nothing: a page then costs its first touch when the first leaf
+    is read into it, and nothing after.
 
     **Its size** is a rule over what the plan reserved and the batchers'
     window, and no knob: ``max(the largest reserved leaf, the batchers'
@@ -447,8 +469,9 @@ class HostBufferPool:
     read).
 
     **A take** is first-fit from the lowest address over a short list of free
-    ranges, so that touched pages are used before untouched ones (what was
-    ever handed out is always a prefix of the arena: ``high_water``); a give
+    ranges, so that what was ever handed out is always a prefix of the arena
+    (``high_water``; ``fresh`` counts what is handed out for the first time,
+    populated or not); a give
     frees the range and coalesces it with its neighbours.  **A read that
     finds no room waits** (``take`` with the pipeline's loop: a future of
     that loop; the assembly's phase ``host_buffer_wait``), in the order of
@@ -470,8 +493,9 @@ class HostBufferPool:
     reserved fits the arena), and from the moment a range is given back unfit
     (``recycle=False``: its transfer failed, or the landed array may be the
     range itself).  A take that cannot wait (a piece copied in on an executor
-    thread) and finds no room is a plain buffer too.  Nothing outlives the
-    restore: ``close()`` drops the arena.
+    thread) and finds no room is a plain buffer too.  A plain buffer is
+    never populated.  Nothing outlives the restore: ``close()`` drops the
+    arena.
 
     Thread-safe: ``reserve`` and ``attach`` run on the planning thread,
     ``take`` on the read pipeline's thread or its executor, ``give`` on the
@@ -490,7 +514,9 @@ class HostBufferPool:
         # (loop, future, nbytes) of each read held for room, in dispatch order
         self._waiters: "deque[Tuple[Any, Any, int]]" = deque()
         self._plain_alive = 0  # bytes of plain buffers taken and not given back
-        self._stats = {"bytes": 0, "fresh": 0, "hits": 0, "misses": 0, "high_water": 0}
+        self._stats = dict.fromkeys(
+            ("bytes", "fresh", "hits", "misses", "high_water", "populated"), 0
+        )
         self._touched = 0  # the arena's prefix ever handed out
 
     def begin_group(self) -> None:
@@ -528,6 +554,16 @@ class HostBufferPool:
         self._arena = _arena_memory(size)
         self._base, self._size = self._arena.ctypes.data, size
         self._free = [[0, size]]
+        # All of it, before a range of it is handed to a read: the native
+        # pool is idle now.  The lock is held: a take that comes meanwhile
+        # has nothing to take yet.
+        touch = _page_toucher()
+        if touch is not None:
+            from .. import phase_stats
+
+            with phase_stats.timed("arena_populate", size):
+                touch(self._arena)
+            self._stats["populated"] = size
 
     def _fit(self, nbytes: int) -> Optional[np.ndarray]:
         """Under the lock: the lowest free range that holds ``nbytes``, taken
@@ -654,9 +690,11 @@ class HostBufferPool:
         """``fresh`` bytes handed out from pages of the arena never handed
         out before, and every byte of a plain buffer (``misses``: the takes
         that touched any); ``bytes`` handed out from pages that were
-        (``hits``: the takes that touched nothing new); and the
-        ``high_water`` of the arena's bytes ever handed out, at most its
-        size, plus the plain buffers alive."""
+        (``hits``: the takes that touched nothing new); the ``high_water``
+        of the arena's bytes ever handed out, at most its size, plus the
+        plain buffers alive; and the bytes of the arena ``populated`` before
+        any were handed out (its size, or 0: no arena, or nothing to
+        populate it with), which changes none of the others."""
         with self._lock:
             return dict(self._stats)
 

@@ -18,6 +18,8 @@ Beyond per-call GIL release, the library runs an internal C++ worker pool
   (independent per-stripe xxh64s combined over the digest stream);
 - ``read_ranges_hash`` — multi-range pread fan-out with optional fused
   per-range hashing for restore and audit;
+- ``touch_pages`` — the first touch of a restore's host arena, every page
+  written once in parallel before a read lands in it;
 - native codec encode/decode straight into/out of compression frames
   (zlib byte-identical to Python's; zstd as standard frames the
   ``zstandard`` wheel cross-decodes);
@@ -190,13 +192,14 @@ class NativeFileIO:
         if not abi_ok:
             missing.append(f"abi_version=={NATIVE_ABI_VERSION}")
 
-        def _bind(name: str, restype, argtypes) -> bool:
+        def _bind(name: str, restype, argtypes, optional: bool = False) -> bool:
             if not abi_ok:
                 return False
             try:
                 fn = getattr(lib, name)
             except AttributeError:
-                missing.append(name)
+                if not optional:  # nothing falls back to Python: not reported
+                    missing.append(name)
                 return False
             fn.restype = restype
             fn.argtypes = argtypes
@@ -239,6 +242,14 @@ class NativeFileIO:
                 ctypes.c_int64,
                 ctypes.POINTER(ctypes.c_uint64),
             ],
+        )
+        # Optional: without it a restore's host arena is populated by its
+        # reads, page by page, as it was before the symbol existed.
+        self.has_touch_pages = _bind(
+            "tpusnap_touch_pages",
+            None,
+            [ctypes.c_void_p, ctypes.c_int64],
+            optional=True,
         )
         self.has_batch_write = _bind(
             "tpusnap_write_parts_hash_batch",
@@ -545,6 +556,15 @@ class NativeFileIO:
         if rc != 0:
             raise OSError(-rc, os.strerror(-rc), path)
         return list(out) if want_hash else None
+
+    def touch_pages(self, buf) -> None:
+        """Write one byte (0, the first of each page) to every page of the
+        writable numpy buffer ``buf``, on the native worker pool and this
+        thread together, the GIL released: the first touch of fresh
+        anonymous memory, taken in parallel.  The kernel writes the byte (a
+        ``readv`` from ``/dev/zero``), so that the first ``pread`` into the
+        page finds it as a later one would.  Requires ``has_touch_pages``."""
+        self._lib.tpusnap_touch_pages(ctypes.c_void_p(buf.ctypes.data), buf.nbytes)
 
     def zlib_encode_into(self, src, dst, level: int) -> Optional[int]:
         """Deflate ``src`` directly into ``dst`` (a writable view sized to

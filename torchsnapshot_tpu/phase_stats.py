@@ -312,7 +312,7 @@ def add_counter(name: str, seconds: float, nbytes: int = 0, **more: int) -> None
     files, and ``members=``, ``reads=``, ``merged=``, batcher.py;
     ``host_pool``: one occurrence a restore, the bytes read into pages of
     the restore's host arena that had been handed out before, and ``fresh=``,
-    ``hits=``, ``misses=``, ``high_water=``,
+    ``hits=``, ``misses=``, ``high_water=``, ``populated=``,
     io_preparers/array.HostBufferPool; ``chunked_read``: one occurrence a
     stateful's read plan, the bytes of its chunked leaves, and ``leaves=``,
     ``chunks=``; ``chunked_write``: the same, one occurrence a take's write

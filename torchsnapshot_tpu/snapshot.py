@@ -584,12 +584,15 @@ class Snapshot:
         whichever read comes next, of any size and any stateful.  The arena
         is one allocation, sized from what the plan reserved (``max(the
         largest leaf, the batchers' in-flight cap)``, no more than the
-        largest stateful; no knob) and never touched up front, so from its
-        first turn on every read, the first stateful's included, lands in
-        pages this call has touched before, and nothing is unmapped beside
-        the reads.  A read that finds no room waits for a landing of any
-        stateful, its own included (``host_buffer_wait``), and the wait
-        makes the batchers send what they hold to the device.  Targets on a
+        largest stateful; no knob), and every page of it is written once, in
+        parallel on the native pool the reads run on, before the first range
+        is handed out (the phase ``arena_populate``, inside the first take;
+        skipped, silently, where the native library lacks the symbol), so no
+        read of the call, the first stateful's included, faults a fresh
+        page, and nothing is unmapped beside the reads.  A read that finds
+        no room waits for a landing of any stateful, its own included
+        (``host_buffer_wait``), and the wait makes the batchers send what
+        they hold to the device.  Targets on a
         backend whose ``device_put`` keeps the host memory (the CPU's) get
         plain buffers and no arena, so no restored array pins one.  The
         arena is dropped when the last stateful is loaded (or the call
@@ -597,7 +600,9 @@ class Snapshot:
         call; its account is the ``host_pool`` counter and the
         ``host_pool`` entry of the ``restore.end`` event (``fresh``: bytes
         handed out from pages never handed out before; ``high_water``: the
-        arena's bytes ever handed out).  What it buys: PERF.md section 5.
+        arena's bytes ever handed out; ``populated``: the arena's bytes
+        written in bulk before any were handed out).  What it buys: PERF.md
+        section 5.
 
         On-device contract: dense and chunked array uploads are drained
         before return (H2DBatcher.drain — their bytes are ON DEVICE, with

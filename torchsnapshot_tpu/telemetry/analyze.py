@@ -70,12 +70,16 @@ PHASE_GROUPS: Dict[str, frozenset] = {
     # What the thread that drives a restore does between storage reads
     # (snapshot.py, manager.restore_latest): opening the snapshot, planning
     # a stateful's reads, handing the restored values to the stateful,
-    # freeing the restore's host arena once the last has loaded.
-    # Work, and leaves: none encloses a read.  The same group as the
+    # freeing the restore's host arena once the last has loaded; and the
+    # arena's population before the first read lands in it, which the read
+    # pipeline's thread does at its first take of host memory
+    # (io_preparers/array.HostBufferPool): host work between reads all the
+    # same.  Work, and leaves: none encloses a read.  The same group as the
     # profiler's <kind>_drive tags.  (plan_read would suffix-match _read;
     # the explicit entry comes first.)
     "driver": frozenset(
-        {"restore_open", "plan_read", "load_state", "host_pool_free"}
+        {"restore_open", "plan_read", "load_state", "host_pool_free",
+         "arena_populate"}
     ),
     "memory_budget": frozenset({"budget_wait"}),
     "io_concurrency": frozenset({"io_slot_wait"}),
