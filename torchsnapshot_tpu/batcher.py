@@ -355,6 +355,42 @@ def batch_read_requests(read_reqs: List[ReadReq]) -> List[ReadReq]:
     return out
 
 
+def count_read_routes(read_reqs: List[ReadReq], entries: int) -> None:
+    """One occurrence of the counter ``read_route`` (a stateful's read
+    plan, before ``batch_read_requests``): the plan's bytes and requests by
+    the route the storage plug-in will take each by, which the plan already
+    decides.  ``sequential``: read into place under the striped minimum (a
+    leaf of a megabyte and more whose digest is the plain ``xxh64``, so
+    ``fs._read_impl`` reads and hashes it in one sequential pass, phase
+    ``fs_read``); ``striped``: read into place with the striped digest
+    (``native_io.STRIPED_MIN_BYTES`` and more: the native pool's parallel
+    ranges, phase ``native_read``); ``merged``: no place of its own to land
+    in (under a megabyte, or tiled), so merged with its neighbours where the
+    gap allows, hashed and copied after it arrived.  ``<route>_leaves``
+    counts the requests: one a leaf, except that a chunked leaf is one a
+    chunk and a sharded one one a piece, each with a route of its own.
+    ``bytes`` is the three routes' sum, ``entries`` the manifest entries the
+    plan read for."""
+    routes = ("sequential", "striped", "merged")
+    counts = dict.fromkeys(routes + tuple(r + "_leaves" for r in routes), 0)
+    for rr in read_reqs:
+        consumer = rr.buffer_consumer
+        if rr.into is None:
+            route = "merged"
+        elif getattr(consumer, "hash_algo", None) == "xxh64s":
+            route = "striped"
+        else:
+            route = "sequential"
+        if rr.byte_range is not None:
+            counts[route] += rr.byte_range[1] - rr.byte_range[0]
+        else:
+            counts[route] += consumer.get_consuming_cost_bytes()
+        counts[route + "_leaves"] += 1
+    phase_stats.add_counter(
+        "read_route", 0.0, sum(counts[r] for r in routes), entries=entries, **counts
+    )
+
+
 class BatchedBufferConsumer(BufferConsumer):
     def __init__(
         self, members: List[Tuple[int, int, BufferConsumer]], total: int

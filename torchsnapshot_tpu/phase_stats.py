@@ -316,7 +316,10 @@ def add_counter(name: str, seconds: float, nbytes: int = 0, **more: int) -> None
     io_preparers/array.HostBufferPool; ``chunked_read``: one occurrence a
     stateful's read plan, the bytes of its chunked leaves, and ``leaves=``,
     ``chunks=``; ``chunked_write``: the same, one occurrence a take's write
-    plan, io_preparers/chunked_array.count_chunked).  The
+    plan, io_preparers/chunked_array.count_chunked; ``read_route``: one
+    occurrence a stateful's read plan, its bytes, and ``sequential=``,
+    ``striped=``, ``merged=`` by the route each read will take,
+    batcher.count_read_routes).  The
     entry has ``s``, ``bytes``, ``n`` and whatever ``more`` names, and no
     ``wall``, and reaches neither hook, so it can name no gap of a trace;
     ``delta()`` differences it like any other."""

@@ -41,7 +41,7 @@ from .telemetry import metrics as tmetrics
 from .telemetry import monitor as tmonitor
 from .telemetry import sidecar as tsidecar
 from .telemetry import trace as ttrace
-from .batcher import batch_read_requests, batch_write_requests
+from .batcher import batch_read_requests, batch_write_requests, count_read_routes
 from .dist_store import (
     LinearBarrier,
     StorePeerError,
@@ -781,6 +781,11 @@ class Snapshot:
             event_metadata["chunked_read_bytes"] = int(
                 phases_delta.get("chunked_read", {}).get("bytes", 0)
             )
+            routes = phases_delta.get("read_route", {})
+            event_metadata["read_route"] = {
+                key: int(routes.get(key, 0))
+                for key in ("sequential", "striped", "merged", "entries")
+            }
             event_metadata["bytes"] = int(
                 max(
                     (v.get("bytes", 0) for v in phases_delta.values()),
@@ -907,6 +912,7 @@ class Snapshot:
                 )
                 read_reqs += entry_read_reqs
                 futures[path] = fut
+            count_read_routes(read_reqs, len(futures))
             read_reqs = batch_read_requests(read_reqs)
         except BaseException:
             h2d_batch.shutdown()

@@ -366,8 +366,11 @@ class FSStoragePlugin(StoragePlugin):
         will verify the whole payload) pay for it, and the issuer's
         ``hash_algo`` decides the shape: "xxh64s" (striped) payloads read
         AND verify in parallel on the native pool; plain "xxh64" streams
-        are order-dependent and stay sequential."""
-        from .. import integrity
+        are order-dependent and stay sequential.  The sequential native
+        reads (``fs_read``) are annotated here as ``_native_ranges``
+        annotates ``native_read``: ``_blocking_read`` records the interval
+        once this has said which phase it was."""
+        from .. import integrity, phase_stats
 
         want_hash = want_hash and integrity.checksums_enabled()
         striped = want_hash and hash_algo == "xxh64s"
@@ -402,16 +405,18 @@ class FSStoragePlugin(StoragePlugin):
                     # symbol missing) must NOT return a plain digest the
                     # consumer would compare against an xxh64s value —
                     # read unhashed and let verify() do its own pass.
-                    hash64 = self._native.read_file_into(
-                        path, byte_range, into, want_hash=True
-                    )
+                    with phase_stats.annotation("fs_read"):
+                        hash64 = self._native.read_file_into(
+                            path, byte_range, into, want_hash=True
+                        )
                     return into, hash64, "fs_read"
-                self._timed_sequential(
-                    path,
-                    byte_range,
-                    into,
-                    record=view.nbytes >= _PARALLEL_READ_MIN_BYTES,
-                )
+                with phase_stats.annotation("fs_read"):
+                    self._timed_sequential(
+                        path,
+                        byte_range,
+                        into,
+                        record=view.nbytes >= _PARALLEL_READ_MIN_BYTES,
+                    )
                 return into, None, "fs_read"
             with open(path, "rb") as f:
                 if byte_range is not None:
@@ -442,11 +447,12 @@ class FSStoragePlugin(StoragePlugin):
                         path, byte_range, memoryview(out), want_hash=True
                     )
                 return out, hash64, "native_read"
-            buf, hash64 = self._native.read_file(
-                # Same algo guard as the into-path: never hand back a plain
-                # digest for an xxh64s consumer.
-                path, byte_range, want_hash=want_hash and not striped
-            )
+            with phase_stats.annotation("fs_read"):
+                buf, hash64 = self._native.read_file(
+                    # Same algo guard as the into-path: never hand back a
+                    # plain digest for an xxh64s consumer.
+                    path, byte_range, want_hash=want_hash and not striped
+                )
             return buf, hash64, "fs_read"
         with open(path, "rb") as f:
             if byte_range is None:
