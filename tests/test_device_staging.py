@@ -7,6 +7,9 @@ TPU-native capability this suite pins: state copied inside the accelerator
 (spare HBM or pinned_host memory space), background D2H, bit-exact restore.
 """
 
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -438,6 +441,12 @@ def test_device_staging_with_slow_storage_returns_fast(tmp_path):
 
 # ----------------------------------------------------- restore H2D batching
 
+H2D_THREADS = ("tpusnap-h2d-dispatcher", "tpusnap-h2d-lander")
+
+
+def _no_h2d_thread_alive():
+    return not [t for t in threading.enumerate() if t.name in H2D_THREADS]
+
 
 def test_h2d_batcher_incremental_flush():
     from torchsnapshot_tpu.io_preparers.array import H2DBatcher
@@ -448,10 +457,10 @@ def test_h2d_batcher_incremental_flush():
     f1, f2 = Future(), Future()
     b.submit(np.arange(16, dtype=np.float32), like, f1)
     b.submit(np.arange(16, dtype=np.float32) * 2, like, f2)
-    b.flush()
+    b.drain()  # a flush is a hand-off: the drain is what waits
     np.testing.assert_array_equal(np.asarray(f1.obj), np.arange(16))
     np.testing.assert_array_equal(np.asarray(f2.obj), np.arange(16) * 2)
-    b.shutdown()  # no parked lander left for the tests that count threads
+    assert _no_h2d_thread_alive()
 
 
 def test_h2d_batcher_dtype_cast():
@@ -462,10 +471,9 @@ def test_h2d_batcher_dtype_cast():
     like = jnp.zeros(8, jnp.bfloat16)
     f = Future()
     b.submit(np.arange(8, dtype=np.float32), like, f)
-    b.flush()
+    b.drain()
     assert f.obj.dtype == jnp.bfloat16
     np.testing.assert_array_equal(np.asarray(f.obj, dtype=np.float32), np.arange(8))
-    b.shutdown()  # no parked lander left for the tests that count threads
 
 
 def test_h2d_batcher_drain_lands_and_attributes():
@@ -485,33 +493,189 @@ def test_h2d_batcher_drain_lands_and_attributes():
     b.drain()
     for i, f in enumerate(futs):
         np.testing.assert_array_equal(np.asarray(f.obj), np.full(16, float(i)))
-    assert b._unlanded_bytes == 0 and not b._inflight
+    assert b._unlanded_bytes == 0 and not b._queued
+    assert not b._dispatching and not b._landing
     stats = phase_stats.snapshot()
     assert stats.get("h2d_land", {}).get("bytes", 0) > 0
     assert stats.get("h2d_dispatch", {}).get("bytes", 0) > 0
 
 
-def test_h2d_batcher_paces_inflight_window():
-    """Dispatches past the in-flight-bytes window land earlier batches first
-    — the window is what lets landings overlap the remaining reads instead
-    of piling up behind the caller's final sync."""
+class _HeldDispatch:
+    """``H2DBatcher._dispatch`` recorded (the thread it ran on, its items'
+    first elements, the unlanded bytes it found reserved) and, while ``hold``
+    is clear, held at its entry."""
+
+    def __init__(self, monkeypatch, held=True):
+        from torchsnapshot_tpu.io_preparers.array import H2DBatcher
+
+        self.calls = []
+        self.entered = threading.Event()
+        self.hold = threading.Event()
+        if not held:
+            self.hold.set()
+        real = H2DBatcher._dispatch
+
+        def recording(batcher, items):
+            self.calls.append(
+                (
+                    threading.current_thread().name,
+                    [float(host.reshape(-1)[0]) for host, *_ in items],
+                    batcher._unlanded_bytes,
+                )
+            )
+            self.entered.set()
+            assert self.hold.wait(10)
+            return real(batcher, items)
+
+        monkeypatch.setattr(H2DBatcher, "_dispatch", recording)
+
+
+def test_h2d_flush_returns_without_a_dispatch_on_the_calling_thread(monkeypatch):
+    """``flush`` only queues: the batch's ``device_put`` runs on the
+    batcher's dispatcher, never on the thread that finalised the leaf (in a
+    restore, the read pipeline's loop thread)."""
     from torchsnapshot_tpu.io_preparers.array import H2DBatcher
     from torchsnapshot_tpu.io_types import Future
 
+    held = _HeldDispatch(monkeypatch)
+    b = H2DBatcher(flush_bytes=64)
+    f = Future()
+    begin = time.monotonic()
+    b.submit(np.full(16, 7.0, dtype=np.float32), jnp.zeros(16, jnp.float32), f)
+    # the submit flushed, and came back while the dispatch is still held
+    assert held.entered.wait(5)
+    assert time.monotonic() - begin < 5 and f.obj is None
+    held.hold.set()
+    b.drain()
+    assert [name for name, _, _ in held.calls] == ["tpusnap-h2d-dispatcher"]
+    assert threading.current_thread().name != "tpusnap-h2d-dispatcher"
+    np.testing.assert_array_equal(np.asarray(f.obj), np.full(16, 7.0))
+    assert b.threads.route() == {
+        "bytes": 64, "off_caller": 64, "on_caller": 0, "batches": 1, "ways": 1
+    }
+    assert _no_h2d_thread_alive()
+
+
+def test_h2d_what_is_flushed_while_the_dispatcher_is_busy_goes_when_it_comes_free(
+    monkeypatch,
+):
+    """Submits go on gathering behind a busy dispatcher, whether or not they
+    reach ``flush_bytes``; what was flushed meanwhile is ONE batch when it
+    comes free, and what was not waits for its own flush or the drain."""
+    from torchsnapshot_tpu.io_preparers.array import H2DBatcher
+    from torchsnapshot_tpu.io_types import Future
+
+    held = _HeldDispatch(monkeypatch)
+    b = H2DBatcher(flush_bytes=128)  # two leaves of 64 bytes a flush
+    like = jnp.zeros(16, jnp.float32)
+    futs = [Future() for _ in range(7)]
+    for i in (0, 1):
+        b.submit(np.full(16, float(i), dtype=np.float32), like, futs[i])
+    assert held.entered.wait(5)  # the first batch is with the dispatcher
+    for i in (2, 3, 4, 5, 6):  # two more flushes, and one leaf under the threshold
+        b.submit(np.full(16, float(i), dtype=np.float32), like, futs[i])
+    assert len(b._queued) == 4 and len(b._items) == 1 and len(held.calls) == 1
+    held.hold.set()
+    deadline = time.monotonic() + 5
+    while len(held.calls) < 2 and time.monotonic() < deadline:
+        time.sleep(0.005)
+    # the dispatcher, free again, took both flushes as one batch; nobody has
+    # flushed the seventh leaf
+    assert [firsts for _, firsts, _ in held.calls] == [[0.0, 1.0], [2.0, 3.0, 4.0, 5.0]]
+    assert len(b._items) == 1 and futs[6].obj is None
+    b.drain()
+    assert [firsts for _, firsts, _ in held.calls][2:] == [[6.0]]
+    for i, f in enumerate(futs):
+        np.testing.assert_array_equal(np.asarray(f.obj), np.full(16, float(i)))
+    assert {name for name, _, _ in held.calls} == {"tpusnap-h2d-dispatcher"}
+
+
+def test_h2d_batcher_paces_inflight_window(monkeypatch):
+    """Dispatches past the in-flight-bytes window land earlier batches first
+    — the window is what lets landings overlap the remaining reads instead
+    of piling up behind the caller's final sync.  With the dispatcher in
+    between the reservation still holds (no dispatch ever finds more than
+    the cap reserved), the wait is the dispatcher's, and the thread that
+    flushes is never held."""
+    import jax as jax_mod
+
+    from torchsnapshot_tpu import phase_stats
+    from torchsnapshot_tpu.io_preparers.array import H2DBatcher
+    from torchsnapshot_tpu.io_types import Future
+
+    held = _HeldDispatch(monkeypatch)
+    landing = threading.Event()  # the lander is held: the window stays shut
+    real_ready = jax_mod.block_until_ready
+
+    def slow_landing(x):
+        if threading.current_thread().name == "tpusnap-h2d-lander":
+            assert landing.wait(10)
+        return real_ready(x)
+
+    monkeypatch.setattr(jax_mod, "block_until_ready", slow_landing)
+    before = phase_stats.snapshot()
     b = H2DBatcher(flush_bytes=64, inflight_cap_bytes=64)
     like = jnp.zeros(16, jnp.float32)  # 64 bytes: every submit flushes
-    futs = [Future() for _ in range(3)]
+    futs = [Future() for _ in range(4)]
+    begin = time.monotonic()
     for i, f in enumerate(futs):
         b.submit(np.full(16, float(i), dtype=np.float32), like, f)
-    assert b._unlanded_bytes <= 64
+    assert time.monotonic() - begin < 2  # no submit waited for the window
+    assert held.entered.wait(5)
+    held.hold.set()
+    time.sleep(0.1)
+    # one batch of no more than the cap is out, unlanded; the rest wait behind
+    # the window, on the host, however much has queued up meanwhile
+    assert len(held.calls) == 1 and b._unlanded_bytes == 64 and len(b._queued) == 2
+    landing.set()
     b.drain()
+    assert b._unlanded_bytes == 0
+    assert [reserved for _, _, reserved in held.calls] == [64] * 4
+    waited = phase_stats.delta(before).get("h2d_window_wait", {})
+    assert waited.get("n", 0) >= 1 and waited["wall"] >= 0.04
     for i, f in enumerate(futs):
         np.testing.assert_array_equal(np.asarray(f.obj), np.full(16, float(i)))
 
 
+def test_h2d_drain_waits_for_queued_batches_and_leaves_no_thread(monkeypatch):
+    from torchsnapshot_tpu.io_preparers.array import H2DBatcher
+    from torchsnapshot_tpu.io_types import Future
+
+    held = _HeldDispatch(monkeypatch)
+    b = H2DBatcher(flush_bytes=64)
+    like = jnp.zeros(16, jnp.float32)
+    futs = [Future() for _ in range(4)]
+    b.submit(np.full(16, 0.0, dtype=np.float32), like, futs[0])
+    assert held.entered.wait(5)
+    for i in (1, 2, 3):
+        b.submit(np.full(16, float(i), dtype=np.float32), like, futs[i])
+    assert len(b._queued) == 3
+    drained = threading.Event()
+    drainer = threading.Thread(target=lambda: (b.drain(), drained.set()), daemon=True)
+    drainer.start()
+    assert not drained.wait(0.1)  # a batch dispatching, three queued: not yet
+    held.hold.set()
+    assert drained.wait(10)
+    drainer.join(5)
+    for i, f in enumerate(futs):
+        np.testing.assert_array_equal(np.asarray(f.obj), np.full(16, float(i)))
+    assert b._unlanded_bytes == 0 and not b._queued and not b._landing
+    assert _no_h2d_thread_alive()
+    # its threads are gone: what is flushed now is sent by the thread that
+    # flushes (nothing handed over is ever left undone), and counted as such
+    late = Future()
+    b.submit(np.full(16, 9.0, dtype=np.float32), like, late)
+    np.testing.assert_array_equal(np.asarray(late.obj), np.full(16, 9.0))
+    assert held.calls[-1][0] == threading.current_thread().name
+    route = b.threads.route()
+    assert route["on_caller"] == 64 and route["off_caller"] == 4 * 64
+    assert route["bytes"] == 5 * 64 and route["ways"] == 1
+
+
 def test_h2d_batcher_bad_item_fails_alone(caplog):
     """One bad item must not sink the batch: good arrays restore, the bad
-    one's error surfaces with correct attribution (advisor r4 finding)."""
+    one's error surfaces with correct attribution (advisor r4 finding), at
+    the next flush and at the drain."""
     from torchsnapshot_tpu.io_preparers.array import H2DBatcher
     from torchsnapshot_tpu.io_types import Future
 
@@ -532,8 +696,9 @@ def test_h2d_batcher_bad_item_fails_alone(caplog):
     b.submit(np.ones((8, 4), dtype=np.float32), good_sharded, f_sharded)
     b.submit(np.ones(7, dtype=np.float32), _Bad(), f_bad)
     with caplog.at_level("WARNING", logger="torchsnapshot_tpu"):
-        with pytest.raises(Exception):
-            b.flush()
+        b.flush()  # the hand-off itself raises nothing
+        with pytest.raises(Exception) as first:
+            b.drain()
     # The failed batch's first exception is in the log before the per-item
     # retry runs (an HBM OOM would otherwise vanish with a retry that
     # succeeds); chip_smoke.py fails on any library warning.
@@ -543,12 +708,44 @@ def test_h2d_batcher_bad_item_fails_alone(caplog):
     np.testing.assert_array_equal(np.asarray(f_plain.obj), np.ones(8))
     np.testing.assert_array_equal(np.asarray(f_sharded.obj), np.ones((8, 4)))
     assert f_bad.obj is None
-    b.drain()
+    # sticky: the same error at every later flush and drain
+    for call in (b.flush, b.drain):
+        with pytest.raises(Exception) as again:
+            call()
+        assert again.value is first.value
+    assert _no_h2d_thread_alive()
+
+
+def test_h2d_dispatch_error_surfaces_at_the_next_flush_and_sends_nothing_more():
+    from torchsnapshot_tpu.io_preparers.array import H2DBatcher
+    from torchsnapshot_tpu.io_types import Future
+
+    class _Bad:
+        dtype = np.float32
+        sharding = NamedSharding(_mesh8(), P("x"))
+
+    b = H2DBatcher(flush_bytes=16)
+    like = jnp.zeros(8, jnp.float32)
+    f_bad, f_next, f_last = Future(), Future(), Future()
+    b.submit(np.ones(7, dtype=np.float32), _Bad(), f_bad)  # flushed: fails on the dispatcher
+    with b._cond:
+        assert b._cond.wait_for(lambda: b._error is not None, 10)
+    # the submit that flushes next meets it; its leaf stays on the host
+    with pytest.raises(Exception) as raised:
+        b.submit(np.ones(8, dtype=np.float32), like, f_next)
+    assert raised.value is b._error
+    with pytest.raises(Exception):
+        b.drain()
+    assert f_bad.obj is None and f_next.obj is None
+    assert b._unlanded_bytes == 0 and not b._dispatching and not b._landing
+    assert b.threads.route()["bytes"] == 0
+    assert _no_h2d_thread_alive()
 
 
 def test_h2d_batcher_lander_error_surfaces(monkeypatch):
     """A landing failure must not wedge the batcher: the error surfaces at
-    drain, byte accounting stays exact, and shutdown still joins cleanly."""
+    the next flush and at drain, byte accounting stays exact, and shutdown
+    still joins cleanly."""
     import jax as jax_mod
 
     from torchsnapshot_tpu.io_preparers.array import H2DBatcher
@@ -567,14 +764,16 @@ def test_h2d_batcher_lander_error_surfaces(monkeypatch):
     b = H2DBatcher(flush_bytes=64, inflight_cap_bytes=1 << 30)
     like = jnp.zeros(16, jnp.float32)
     f1, f2 = Future(), Future()
-    # The sticky error surfaces at the first flush/drain AFTER the lander
-    # hits it — which flush that is depends on landing timing.
+    b.submit(np.ones(16, dtype=np.float32), like, f1)  # landing fails
+    with b._cond:
+        assert b._cond.wait_for(lambda: b._error is not None, 10)
     with pytest.raises(RuntimeError, match="forced landing failure"):
-        b.submit(np.ones(16, dtype=np.float32), like, f1)  # landing fails
-        b.submit(np.ones(16, dtype=np.float32), like, f2)
+        b.submit(np.ones(16, dtype=np.float32), like, f2)  # the next flush
+    with pytest.raises(RuntimeError, match="forced landing failure"):
         b.drain()
-    assert b._unlanded_bytes == 0
+    assert b._unlanded_bytes == 0 and not b._landing
     b.shutdown()  # idempotent, returns without hanging
+    assert _no_h2d_thread_alive()
 
 
 def test_h2d_batcher_mixed_targets():
@@ -591,8 +790,7 @@ def test_h2d_batcher_mixed_targets():
     f1, f2 = Future(), Future()
     b.submit(np.ones((8, 4), dtype=np.float32), sharded_like, f1)
     b.submit(np.full(8, 3.0, dtype=np.float32), plain_like, f2)
-    b.flush()
+    b.drain()
     np.testing.assert_array_equal(np.asarray(f1.obj), np.ones((8, 4)))
     assert f1.obj.sharding.is_equivalent_to(sharded_like.sharding, 2)
     np.testing.assert_array_equal(np.asarray(f2.obj), np.full(8, 3.0))
-    b.shutdown()  # no parked lander left for the tests that count threads

@@ -32,7 +32,7 @@ from torchsnapshot_tpu.event_handlers import (
 from torchsnapshot_tpu.integrity import ChecksumError
 from torchsnapshot_tpu.io_preparers import array as array_mod
 from torchsnapshot_tpu.io_preparers.array import H2DBatcher, HostBufferPool
-from torchsnapshot_tpu.io_types import StoragePlugin
+from torchsnapshot_tpu.io_types import Future, StoragePlugin
 
 KEYS = ("a_params", "b_mu", "c_nu")  # loaded in the order of their names
 MIB = 1 << 20
@@ -41,7 +41,11 @@ PAGE = array_mod._PAGE
 SHAPES = ((256, 1024), (512, 1024), (768, 1024))
 LEAVES = len(SHAPES)
 STATEFUL_BYTES = sum(4 * rows * cols for rows, cols in SHAPES)
-PIPELINE_THREADS = ("tpusnap-read-pipeline", "tpusnap-h2d-lander")
+PIPELINE_THREADS = (
+    "tpusnap-read-pipeline",
+    "tpusnap-h2d-dispatcher",
+    "tpusnap-h2d-lander",
+)
 
 
 def make_app(seed, shapes=SHAPES, keys=KEYS, zero=False):
@@ -739,6 +743,79 @@ def test_a_take_with_no_room_flushes_the_batchers_and_waits_its_turn():
         assert not any(from_thread) and c.ctypes.data == base + 8 * PAGE
 
     asyncio.run(read_pipeline())
+
+
+@pytest.mark.parametrize("dispatcher", ["idle", "busy"])
+def test_a_read_with_no_room_is_served_by_a_batch_that_was_only_queued(
+    monkeypatch, dispatcher
+):
+    """The liveness argument with the flush a hand-off: a read that finds no
+    room flushes the batchers, which now only queues, and still the leaf
+    gathered there is sent, lands and frees its range, whether the dispatcher
+    was idle (nothing had reached ``flush_bytes``) or busy with the batch
+    before (then the queued one goes when it comes free: a waiter never waits
+    on a batch that nobody will dispatch).  While a batch is only queued it
+    holds its range of the arena and nothing on the device."""
+    monkeypatch.setattr(
+        array_mod, "_arena_memory", lambda nbytes: memory_at(nbytes, 16)[1]
+    )
+    pool = HostBufferPool()
+    batcher = H2DBatcher(host_pool=pool, flush_bytes=1 << 30, inflight_cap_bytes=8 * PAGE)
+    pool.begin_group()
+    for _ in range(3):
+        pool.reserve(4 * PAGE, ON_A_CHIP)
+    like = jnp.zeros(PAGE, jnp.float32)  # four pages of float32
+    puts, gate = [], threading.Event()
+    real_dispatch = H2DBatcher._dispatch
+
+    def held_dispatch(self, items):
+        puts.append(threading.current_thread().name)
+        assert gate.wait(10)
+        return real_dispatch(self, items)
+
+    monkeypatch.setattr(H2DBatcher, "_dispatch", held_dispatch)
+    if dispatcher == "idle":
+        gate.set()
+
+    async def read_pipeline():
+        loop = asyncio.get_running_loop()
+        a, b = pool.take(4 * PAGE, loop), pool.take(4 * PAGE, loop)
+        assert a.ctypes.data + 4 * PAGE == b.ctypes.data  # the arena is both
+        futures = [Future(), Future()]
+        for lease, fut, fill in ((a, futures[0], 1.0), (b, futures[1], 2.0)):
+            lease.view(np.float32)[:] = fill
+            if dispatcher == "busy" and lease is b:
+                # the first leaf is with the dispatcher, held; this one is
+                # flushed behind it by the wait below
+                batcher.flush()
+                await asyncio.sleep(0.05)
+                assert puts == ["tpusnap-h2d-dispatcher"]
+            batcher.submit(lease.view(np.float32), like, fut, lease)
+        assert not batcher._dispatching or dispatcher == "busy"
+        coming = pool.take(4 * PAGE, loop)  # no room: the wait flushes the batcher
+        assert isinstance(coming, asyncio.Future) and pool.waiting()
+        if dispatcher == "busy":
+            await asyncio.sleep(0.05)
+            # queued, not sent: its range is held, nothing of it is on the device
+            assert not coming.done() and len(batcher._queued) == 1
+            # (the window holds the first leaf's reservation and no more)
+            assert futures[1].obj is None and batcher._unlanded_bytes == 4 * PAGE
+            gate.set()
+        got = await asyncio.wait_for(coming, 10)
+        assert got.ctypes.data in (a.ctypes.data, b.ctypes.data)
+        return futures
+
+    try:
+        futures = asyncio.run(read_pipeline())
+        batcher.drain()
+        for fut, fill in zip(futures, (1.0, 2.0)):
+            np.testing.assert_array_equal(np.asarray(fut.obj), np.full(PAGE, fill))
+        assert set(puts) == {"tpusnap-h2d-dispatcher"}
+        assert pool.h2d_threads.route()["off_caller"] == 8 * PAGE
+    finally:
+        gate.set()
+        pool.close()
+    assert not [t for t in threading.enumerate() if t.name in PIPELINE_THREADS]
 
 
 def test_a_give_after_the_waiting_pipeline_is_gone_is_only_a_give():

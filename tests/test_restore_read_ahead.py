@@ -36,7 +36,11 @@ LEAVES = 3
 SHAPE = (512, 1024)  # 2 MiB of float32: over the size from which a read lands in place
 READ_S = 0.03  # one storage read
 LOAD_S = 0.15  # one load_state_dict: several reads long, so the gate binds
-PIPELINE_THREADS = ("tpusnap-read-pipeline", "tpusnap-h2d-lander")
+PIPELINE_THREADS = (
+    "tpusnap-read-pipeline",
+    "tpusnap-h2d-dispatcher",
+    "tpusnap-h2d-lander",
+)
 
 
 class Log:
@@ -260,9 +264,9 @@ def world(monkeypatch, request):
             lent_to[lease.ctypes.data] = key_of_batcher(self)
         return real_submit(self, host, like, fut, lease)
 
-    def recording_dispatch(self, items, batch_bytes):
+    def recording_dispatch(self, items):
         log.add("h2d_dispatch", key_of_batcher(self))
-        return real_dispatch(self, items, batch_bytes)
+        return real_dispatch(self, items)
 
     monkeypatch.setattr(H2DBatcher, "submit", recording_submit)
     monkeypatch.setattr(H2DBatcher, "_dispatch", recording_dispatch)
@@ -431,6 +435,82 @@ def test_nothing_is_consumed_or_sent_to_the_device_ahead_of_the_load(world):
         for what in ("consume_begin", "h2d_submit", "h2d_dispatch"):
             assert log.times(what, ahead), (what, ahead)
             assert log.first(what, ahead) >= loaded, (what, this, ahead)
+    # "h2d_dispatch" is still where the device_put is made: on the dispatcher,
+    # which sends only what the pipeline has submitted, so the gate above
+    # holds it too
+    assert {name for what, _, _, name in log.rows if what == "h2d_dispatch"} == {
+        "tpusnap-h2d-dispatcher"
+    }
+
+
+def test_the_device_put_runs_on_the_dispatcher_and_the_account_says_so(world):
+    """A flush on the pipeline's thread is a hand-off: every batch's
+    ``device_put`` runs on the restore's dispatcher, and the
+    ``h2d_dispatch_route`` counter, once a restore and in ``restore.end``
+    beside ``host_pool``, says how much went that way."""
+    target = make_app(world.log, zero=True)
+    delta, (end,) = restore(world, target)
+    rows = world.log.rows
+    assert {name for what, _, _, name in rows if what == "h2d_submit"} == {
+        "tpusnap-read-pipeline"
+    }
+    assert {name for what, _, _, name in rows if what == "h2d_dispatch"} == {
+        "tpusnap-h2d-dispatcher"
+    }
+    state_bytes = len(KEYS) * LEAVES * int(np.prod(SHAPE)) * 4
+    route = end["h2d_dispatch_route"]
+    assert sorted(route) == ["batches", "bytes", "off_caller", "on_caller", "ways"]
+    assert route["bytes"] == route["off_caller"] == state_bytes
+    assert route["on_caller"] == 0 and route["ways"] == 1
+    assert route["batches"] == len(world.log.times("h2d_dispatch"))
+    counter = delta["h2d_dispatch_route"]
+    assert counter["n"] == 1 and "wall" not in counter
+    assert {k: counter[k] for k in route} == route
+    assert_equal_bits(target, world.saved)
+
+
+def test_a_restore_starts_its_h2d_threads_once_before_its_first_read(world, monkeypatch):
+    """A thread's start under read load costs its starter dearly on some
+    hosts (PERF.md section 5), so the dispatcher and the lander are started
+    once a restore (the parent started a lander a stateful, from the
+    pipeline's thread), by the thread that called ``restore``, before the
+    pipeline exists; none is started later and none is left behind.  A
+    restore with nothing to upload starts neither."""
+    started = []
+    real_start = threading.Thread.start
+
+    def recording_start(self):
+        if self.name.startswith("tpusnap-"):
+            started.append(
+                (self.name, threading.current_thread().name, len(world.log.times("read_dispatch")))
+            )
+        return real_start(self)
+
+    monkeypatch.setattr(threading.Thread, "start", recording_start)
+    target = make_app(world.log, zero=True)
+    restore(world, target)
+    me = threading.current_thread().name
+    h2d = [row for row in started if row[0].startswith("tpusnap-h2d-")]
+    assert sorted(h2d) == [
+        ("tpusnap-h2d-dispatcher", me, 0),
+        ("tpusnap-h2d-lander", me, 0),
+    ]
+    assert len(h2d) <= len(KEYS)  # the parent's count: a lander a stateful
+    names = [name for name, _, _ in started]
+    assert names.index("tpusnap-h2d-lander") < names.index("tpusnap-read-pipeline")
+    assert no_pipeline_thread_alive()
+    assert_equal_bits(target, world.saved)
+    # numpy targets are filled in place: nothing is uploaded, no thread is started
+    del started[:]
+    host = {
+        key: StateDict({k: np.zeros(v.shape, v.dtype) for k, v in sd.state_dict().items()})
+        for key, sd in world.saved.items()
+    }
+    Snapshot(world.url).restore(host)
+    assert not [name for name, _, _ in started if name.startswith("tpusnap-h2d-")]
+    for key in KEYS:
+        for name, want in world.saved[key].state_dict().items():
+            np.testing.assert_array_equal(host[key].state_dict()[name], np.asarray(want))
 
 
 def test_the_look_ahead_is_one_stateful(world):

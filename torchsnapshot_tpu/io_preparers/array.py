@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import asyncio
 import bisect
+import contextlib
 import functools
 import logging
 import math
@@ -28,7 +29,8 @@ import threading
 import weakref
 from collections import deque
 from concurrent.futures import Executor
-from typing import Any, Dict, List, Optional, Tuple
+from queue import SimpleQueue
+from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -497,9 +499,13 @@ class HostBufferPool:
     never populated.  Nothing outlives the restore: ``close()`` drops the
     arena.
 
+    The pool also owns the restore's upload threads (``h2d_threads``:
+    it outlives the statefuls, each of which has a batcher of its own):
+    ``start_threads`` before the first read, ``close`` joins them.
+
     Thread-safe: ``reserve`` and ``attach`` run on the planning thread,
     ``take`` on the read pipeline's thread or its executor, ``give`` on the
-    lander."""
+    lander (on the dispatcher for a leaf that was never sent)."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -514,6 +520,8 @@ class HostBufferPool:
         # (loop, future, nbytes) of each read held for room, in dispatch order
         self._waiters: "deque[Tuple[Any, Any, int]]" = deque()
         self._plain_alive = 0  # bytes of plain buffers taken and not given back
+        # The restore's dispatcher and lander: every attached batcher's.
+        self.h2d_threads = H2DThreads()
         self._stats = dict.fromkeys(
             ("bytes", "fresh", "hits", "misses", "high_water", "populated"), 0
         )
@@ -541,6 +549,17 @@ class HostBufferPool:
 
     def _live_batchers(self) -> List["H2DBatcher"]:
         return [b for b in (ref() for ref in self._batchers) if b is not None]
+
+    def start_threads(self) -> None:
+        """Every stateful is planned and no read has been issued: the
+        dispatcher and the lander start now, where a batcher has a leaf to
+        upload, and not under read load from the thread that issues reads (a
+        thread's start costs its starter 0.2-0.5 s there: PERF.md section
+        5)."""
+        with self._lock:
+            wanted = any(b.expects_uploads for b in self._live_batchers())
+        if wanted:
+            self.h2d_threads.start()
 
     def _make_arena(self) -> None:
         # Under the lock, at the first take: every stateful is planned.
@@ -680,7 +699,10 @@ class HostBufferPool:
             free.insert(i, [offset, size])
 
     def close(self) -> None:
-        """The restore is over, nothing reads any more: the arena goes."""
+        """The restore is over, nothing reads any more: the dispatcher and
+        the lander run what they were handed and are joined, then the arena
+        goes."""
+        self.h2d_threads.close()
         with self._lock:
             arena, self._arena = self._arena, None
             self._free, self._plain = [], True
@@ -717,6 +739,127 @@ def _may_alias(out: Any, buf: np.ndarray) -> bool:
         return True
 
 
+class _Worker:
+    """A daemon thread that runs, in the order given, the jobs it is handed.
+    Started by ``start`` or by the first ``hand``.  Once closed it starts no
+    more: a job handed then runs on the thread that hands it, so that nothing
+    handed is ever left undone."""
+
+    def __init__(self, name: str) -> None:
+        self._name = name
+        self._lock = threading.Lock()
+        self._jobs: "SimpleQueue[Optional[Callable[[], None]]]" = SimpleQueue()
+        self._thread: Optional[threading.Thread] = None
+        self._closed = False
+
+    def _start(self) -> None:
+        # Under the lock.
+        if self._thread is None:
+            self._thread = threading.Thread(
+                target=self._run, name=self._name, daemon=True
+            )
+            self._thread.start()
+
+    def start(self) -> None:
+        with self._lock:
+            if not self._closed:
+                self._start()
+
+    def hand(self, job: Callable[[], None]) -> None:
+        with self._lock:
+            if not self._closed:
+                self._start()
+                self._jobs.put(job)
+                return
+        job()
+
+    def is_current(self) -> bool:
+        return threading.current_thread() is self._thread
+
+    def _run(self) -> None:
+        for job in iter(self._jobs.get, None):
+            try:
+                job()
+            except BaseException:  # noqa: BLE001 -- a job keeps its own errors
+                logger.exception("a job of %s raised", self._name)
+
+    def close(self) -> None:
+        """Run what was handed, then end and join the thread (idempotent)."""
+        with self._lock:
+            self._closed = True
+            thread, self._thread = self._thread, None
+        if thread is not None:
+            self._jobs.put(None)
+            thread.join()
+
+
+class H2DThreads:
+    """The two threads that send one restore's leaves to the device: the
+    **dispatcher** (``tpusnap-h2d-dispatcher``) runs each batch's
+    ``device_put`` and the **lander** (``tpusnap-h2d-lander``) its
+    ``block_until_ready``, so batch N+1 is copied while batch N lands and
+    neither runs on the thread that issues the reads.  One pair serves every
+    batcher of a restore (its ``HostBufferPool`` owns it, starts it before
+    the first read is issued and joins it when the restore ends); a batcher
+    without a pool has a pair of its own.
+
+    One dispatcher, not several: beside sixteen threads that read and hash
+    as a restore's io slots do, two threads' ``device_put`` calls land three
+    to four times what one thread's do (``tools/h2d_ceiling_probe.py``,
+    PERF.md section 7), but in a restore a batch split two ways shortened
+    the dispatch's wall and not the call (PERF.md section 6, PR 34): the
+    dispatcher is not what a restore waits for.  What does pay is that a
+    busy dispatcher's backlog goes as one call (``H2DBatcher``): a call a
+    leaf from this thread was slower than the calls it replaced.
+
+    ``route()`` is the account of where the ``device_put`` calls ran (the
+    counter ``h2d_dispatch_route``): ``bytes`` dispatched, of them
+    ``off_caller`` on the dispatcher and ``on_caller`` on the thread that
+    called ``flush`` or ``drain`` (only once the threads are closed: the
+    fall-back that leaves nothing handed over undone), in ``batches`` calls,
+    at the most ``ways`` of them at once."""
+
+    def __init__(self) -> None:
+        self.dispatcher = _Worker("tpusnap-h2d-dispatcher")
+        self.lander = _Worker("tpusnap-h2d-lander")
+        self._lock = threading.Lock()
+        self._route = dict.fromkeys(
+            ("bytes", "off_caller", "on_caller", "batches", "ways"), 0
+        )
+        self._putting = 0  # device_put calls under way
+
+    def start(self) -> None:
+        self.dispatcher.start()
+        self.lander.start()
+
+    def close(self) -> None:
+        # The dispatcher first: what it still sends, the lander still lands.
+        self.dispatcher.close()
+        self.lander.close()
+
+    @contextlib.contextmanager
+    def putting(self, nbytes: int) -> Iterator[None]:
+        """Around one ``device_put`` call of ``nbytes``; counted where the
+        call returns (a call that raises sent nothing)."""
+        side = "off_caller" if self.dispatcher.is_current() else "on_caller"
+        with self._lock:
+            self._putting += 1
+            self._route["ways"] = max(self._route["ways"], self._putting)
+        try:
+            yield
+            with self._lock:
+                self._route["bytes"] += nbytes
+                self._route[side] += nbytes
+                self._route["batches"] += 1
+        finally:
+            with self._lock:
+                self._putting -= 1
+
+    def route(self) -> Dict[str, int]:
+        with self._lock:
+            return dict(self._route)
+
+
 class H2DBatcher:
     """Cross-array H2D upload batching + landing pacing for the restore path.
 
@@ -727,17 +870,30 @@ class H2DBatcher:
     accumulate up to ``flush_bytes`` (bounding the extra host-memory
     residency beyond the scheduler's budget), then flush incrementally.
 
-    Dispatched batches land EAGERLY on a dedicated lander thread; a bounded
+    **A flush is a hand-off.**  ``flush`` queues what has gathered for the
+    dispatcher thread (``H2DThreads``) and returns: no ``device_put`` runs on
+    the thread that finalised the leaf, which is the read pipeline's loop
+    thread, the only one that issues reads and hands out io slots.  The
+    dispatcher takes what is queued as one batch (no more than the in-flight
+    cap admits, but for a single leaf that is larger), waits for window room
+    (``h2d_window_wait``), reserves the batch's bytes, makes the ONE batched
+    ``device_put`` (``h2d_dispatch``), sets each future and hands the batch
+    to the lander; what is flushed while it is busy gathers into the batch it
+    takes when it comes free.  A queued batch holds host memory (ranges of
+    the pool's arena), never HBM.
+
+    Dispatched batches land EAGERLY on the lander thread; a bounded
     unlanded-bytes window (default 2× ``flush_bytes``) backpressures new
     dispatches so batch N's landing overlaps the reads feeding batch N+1
     instead of every transfer piling up behind the caller's final
     ``block_until_ready`` (r04 bench: 159 s of unattributed restore wall —
     the reference's read scheduler overlaps read and consume end-to-end,
     /root/reference/torchsnapshot/scheduler.py:386-447).  Landings are
-    attributed to the byte-carrying ``h2d_land`` phase; dispatch CPU time to
+    attributed to the byte-carrying ``h2d_land`` phase; the dispatch to
     ``h2d_dispatch``.  The owner calls :meth:`drain` after the read pipeline
     finishes: on return every submitted array is ON DEVICE, not in flight,
-    and the lander thread has exited.
+    nothing of this batcher is queued or running on either thread, and a
+    batcher's own threads have exited.
 
     With a ``host_pool`` (``Snapshot.restore``'s), a buffer submitted with
     its ``lease`` (the pool's range under ``host``) goes back to the pool
@@ -748,14 +904,24 @@ class H2DBatcher:
     back unfit.  While a read waits for room in the pool
     (``HostBufferPool.waiting``) nothing gathers here: the pool flushes every
     batcher when the wait begins, and a leased buffer submitted during it is
-    dispatched at once, whatever ``flush_bytes`` says.  Nothing here keeps a
-    host buffer past its landing.
+    flushed at once, whatever ``flush_bytes`` says; a flush always leaves a
+    dispatcher run scheduled, so no waiter waits on a batch that nobody will
+    send.  Nothing here keeps a host buffer past its landing.
+
+    **Errors.**  A dispatch or landing failure must not wedge the batcher:
+    the first one is kept, the byte accounting stays exact, the batches
+    behind it are settled (a bad item fails alone and its batch-mates still
+    restore; after a failure nothing more is sent and every lease goes back
+    unfit, so a read waiting for room gets on and meets the error), and the
+    error is raised, sticky, by the next ``flush`` and by ``drain``.
 
     Thread-safety: ``submit``/``flush`` may run on the read pipeline's loop
-    or executor threads, ``drain`` on the caller thread.  Because landings
-    run on the lander (never on the flushing thread), a backpressure wait
-    in ``flush`` lasts only until the lander frees window room — and the
-    window bounds unlanded host-buffer residency, which the scheduler's
+    or executor threads and on the pool's behalf (a read's wait for room),
+    ``drain`` on the caller thread; ``_send`` runs on the dispatcher and
+    ``_land`` on the lander (on the flushing thread only once the threads
+    are closed).  A back-pressure wait lasts only until the lander frees
+    window room, and holds the dispatcher, never a thread that reads — and
+    the window bounds unlanded host-buffer residency, which the scheduler's
     read budget stops tracking the moment a consume completes.
     """
 
@@ -774,16 +940,19 @@ class H2DBatcher:
         self.inflight_cap_bytes = (
             inflight_cap_bytes if inflight_cap_bytes is not None else 2 * flush_bytes
         )
+        # The restore's two threads, or a pair of this batcher's own.
+        self.threads = host_pool.h2d_threads if host_pool is not None else H2DThreads()
+        # Whether a planned leaf will be submitted here: the pool starts its
+        # threads before the first read only for a batcher that has work.
+        self.expects_uploads = False
         if host_pool is not None:
             host_pool.attach(self)
-        self._lock = threading.Lock()
-        self._cond = threading.Condition(self._lock)
-        # (landing arrays, their bytes, (array, lease) of the pool's buffers)
-        self._inflight: "deque[Tuple[List[Any], int, List[_Lease]]]" = deque()
+        self._cond = threading.Condition()
+        self._queued: List[_Item] = []  # flushed, not yet taken by the dispatcher
+        self._dispatching = False  # a dispatcher run of this batcher is scheduled
+        self._landing = 0  # batches handed to the lander and not yet settled
         self._unlanded_bytes = 0  # dispatched, not yet landed
-        self._lander: Optional[Any] = None
-        self._lander_stop = False
-        self._lander_error: Optional[BaseException] = None
+        self._error: Optional[BaseException] = None
 
     def submit(
         self,
@@ -792,7 +961,7 @@ class H2DBatcher:
         fut: Future,
         lease: Optional[np.ndarray] = None,
     ) -> None:
-        with self._lock:
+        with self._cond:
             self._items.append((host, like, fut, lease))
             self._bytes += host.nbytes
             should_flush = self._bytes >= self._flush_bytes
@@ -800,43 +969,81 @@ class H2DBatcher:
             self.flush()
 
     def flush(self) -> None:
+        """Hand what has gathered to the dispatcher and return at once;
+        raises the sticky error of an earlier dispatch or landing."""
+        with self._cond:
+            self._raise_error()
+            if not self._items:
+                return
+            self._queued += self._items
+            self._items, self._bytes = [], 0
+            if self._dispatching:
+                return  # its run takes them when it comes free
+            self._dispatching = True
+        self.threads.dispatcher.hand(self._dispatch_queued)
+
+    def _dispatch_queued(self) -> None:
+        """One dispatcher run: what is queued goes as one batch, as far as
+        the in-flight cap reaches (a leaf larger than the cap goes alone),
+        then what was queued meanwhile, until nothing is."""
+        while True:
+            with self._cond:
+                n = nbytes = 0
+                for host, *_ in self._queued:
+                    if n and nbytes + host.nbytes > self.inflight_cap_bytes:
+                        break
+                    n, nbytes = n + 1, nbytes + host.nbytes
+                items, self._queued = self._queued[:n], self._queued[n:]
+                if not items:
+                    self._dispatching = False
+                    self._cond.notify_all()
+                    return
+            try:
+                self._send(items)
+            except BaseException as e:  # noqa: BLE001 -- raised by flush/drain
+                with self._cond:
+                    if self._error is None:
+                        self._error = e
+
+    def _send(self, items: List[_Item]) -> None:
         from .. import phase_stats
 
-        with self._lock:
-            items, self._items, self._bytes = self._items, [], 0
-        if not items:
-            return
         batch_bytes = sum(host.nbytes for host, *_ in items)
         # Backpressure: wait for the lander to free window room, and RESERVE
-        # this batch's bytes in the same critical section — otherwise N
-        # concurrent flushers all pass the check against the
-        # still-unincremented counter and overshoot the window by N batches.
-        # The wait lasts only for the EXCESS over the window (landing of
-        # older batches started the moment they were dispatched), and a full
-        # window stalling the producer is the point — reads must not run
-        # unboundedly ahead of a slow H2D link.
-        # The wait is h2d_window_wait when it lasted: it can hold a consumer
-        # on the read pipeline's loop thread, and with it the pipeline.
+        # this batch's bytes in the same critical section.  The wait lasts
+        # only for the EXCESS over the window (landing of older batches
+        # started the moment they were dispatched), and a full window
+        # stalling the dispatcher is the point: what is read gathers behind
+        # it in the arena, and the arena's bound then holds the reads, so
+        # they cannot run unboundedly ahead of a slow H2D link.  The wait is
+        # h2d_window_wait when it lasted.
         window_wait = None
         with self._cond:
-            self._raise_lander_error()
             while (
-                self._unlanded_bytes > 0
+                self._error is None
+                and self._unlanded_bytes > 0
                 and self._unlanded_bytes + batch_bytes > self.inflight_cap_bytes
             ):
                 if window_wait is None:
                     window_wait = phase_stats.open_interval("h2d_window_wait")
                 self._cond.wait(timeout=1.0)
-                self._raise_lander_error()
-            self._unlanded_bytes += batch_bytes  # reserved
+            failed_before = self._error is not None
+            if not failed_before:
+                self._unlanded_bytes += batch_bytes  # reserved
         if window_wait is not None:
             window_wait.close(min_s=0.001)
+        if failed_before:
+            # Nothing more goes to the device; the ranges go back unfit, so a
+            # read that waits for one gets on and meets the error.
+            self._settle_unsent(items)
+            return
         try:
-            outs, failed = self._dispatch(items, batch_bytes)
+            outs, failed = self._dispatch(items)
         except BaseException:
             with self._cond:
                 self._unlanded_bytes -= batch_bytes
                 self._cond.notify_all()
+            self._settle_unsent(items)
             raise
         landed_bytes = sum(
             host.nbytes for (host, *_), out in zip(items, outs) if out is not None
@@ -854,9 +1061,12 @@ class H2DBatcher:
             # window).
             self._unlanded_bytes -= batch_bytes - landed_bytes
             if good:
-                self._inflight.append((good, landed_bytes, leases))
-                self._ensure_lander()
+                self._landing += 1
             self._cond.notify_all()
+        if good:
+            self.threads.lander.hand(
+                functools.partial(self._land, good, landed_bytes, leases)
+            )
         if failed:
             # A failed batch retries per item so one bad array (dtype/
             # sharding mismatch) fails alone with correct blame and its
@@ -864,86 +1074,71 @@ class H2DBatcher:
             self._dispatch_per_item(failed)
 
     def drain(self) -> None:
-        """Flush the tail and block until every dispatched transfer LANDS
-        (attributed to ``h2d_land``).  After this, restored arrays are
-        device-resident — the caller's own block_until_ready sees ~0 s.
+        """Flush the tail and block until every transfer handed over has been
+        dispatched and LANDS (attributed to ``h2d_land``).  After this,
+        restored arrays are device-resident — the caller's own
+        block_until_ready sees ~0 s.
 
-        On a landing failure the error still surfaces here, but only after
-        the remaining dispatched batches finish their landing attempts:
-        drain exits quiescent (byte accounting settled, lander joined)
-        whether it raises or not, so callers never observe mid-landing
-        counters or a still-running lander thread after an error."""
+        On a dispatch or landing failure the error still surfaces here, but
+        only after the remaining batches have been settled: drain exits
+        quiescent (nothing queued, byte accounting settled, no job of this
+        batcher on either thread, its own threads joined) whether it raises
+        or not, so callers never observe mid-landing counters or a running
+        thread of the batcher's own after an error."""
         try:
             self.flush()
         finally:
             # The lander decrements unlanded bytes even for failed
             # landings, so this loop terminates regardless of errors.
             with self._cond:
-                while self._unlanded_bytes > 0 or self._inflight:
+                while self._dispatching or self._landing:
                     self._cond.wait(timeout=1.0)
             self.shutdown()
-        self._raise_lander_error()
+        self._raise_error()
 
     def shutdown(self) -> None:
-        """Stop and join the lander thread (idempotent; never raises the
-        landing error — callers check via drain).  Owners call this from a
-        ``finally`` so an aborted read pipeline doesn't leak a parked
-        thread per restore in a long-lived trainer."""
-        with self._cond:
-            self._lander_stop = True
-            self._cond.notify_all()
-            lander = self._lander
-            self._lander = None
-        if lander is not None:
-            lander.join()
-        self._lander_stop = False  # reusable after drain/shutdown
+        """End and join this batcher's own threads, once they have run what
+        they were handed (idempotent; never raises the sticky error —
+        callers check via drain).  A restore's threads are its pool's, and
+        end with it (``HostBufferPool.close``)."""
+        if self.host_pool is None:
+            self.threads.close()
 
-    def _raise_lander_error(self) -> None:
-        # Sticky: a batcher with a failed landing keeps raising (it is
-        # per-restore and discarded after; clearing would let a drain
+    def _raise_error(self) -> None:
+        # Sticky: a batcher with a failed dispatch or landing keeps raising
+        # (it is per-restore and discarded after; clearing would let a drain
         # following a flush-consumed error report clean).
-        if self._lander_error is not None:
-            raise self._lander_error
+        if self._error is not None:
+            raise self._error
 
-    def _ensure_lander(self) -> None:
-        # Called under the lock.
-        if self._lander is None:
-            self._lander = threading.Thread(
-                target=self._land_loop, name="tpusnap-h2d-lander", daemon=True
-            )
-            self._lander.start()
-
-    def _land_loop(self) -> None:
+    def _land(self, outs: List[Any], nbytes: int, leases: List[_Lease]) -> None:
+        """On the lander: one dispatched batch lands, its buffers go back to
+        the pool, the window opens.  A landing failure keeps the accounting
+        exact and is kept for the next flush/drain; the batches behind it
+        still settle, so backpressure waiters and drain() always make
+        progress."""
         import jax
 
         from .. import phase_stats
 
-        while True:
-            with self._cond:
-                while not self._inflight and not self._lander_stop:
-                    self._cond.wait()
-                if not self._inflight:  # stop requested and queue empty
-                    return
-                outs, nbytes, leases = self._inflight.popleft()
-            # A landing failure must not wedge the batcher: record the first
-            # error, keep the byte accounting exact, and KEEP LANDING the
-            # remaining batches so backpressure waiters and drain() always
-            # make progress (the error surfaces at the next flush/drain).
-            err: Optional[BaseException] = None
-            try:
-                with phase_stats.timed("h2d_land", nbytes):
-                    jax.block_until_ready(outs)
-            except BaseException as e:  # noqa: BLE001
-                err = e
-            # Before the window opens: whoever it lets through finds the
-            # buffers this batch landed from.
+        err: Optional[BaseException] = None
+        try:
+            with phase_stats.timed("h2d_land", nbytes):
+                jax.block_until_ready(outs)
+        except BaseException as e:  # noqa: BLE001
+            err = e
+        # Before the window opens: whoever it lets through finds the
+        # buffers this batch landed from.
+        try:
             self._settle(leases, landed=err is None)
-            outs = leases = None
-            with self._cond:
-                self._unlanded_bytes -= nbytes
-                if err is not None and self._lander_error is None:
-                    self._lander_error = err
-                self._cond.notify_all()
+        except BaseException as e:  # noqa: BLE001
+            err = err or e
+        with self._cond:
+            self._unlanded_bytes -= nbytes
+            self._landing -= 1
+            if err is not None and self._error is None:
+                self._error = err
+            self._cond.notify_all()
 
     def _settle(self, leases: List[_Lease], landed: bool) -> None:
         """Give the pool's buffers back: to be used again where the transfer
@@ -953,9 +1148,12 @@ class H2DBatcher:
                 lease, recycle=landed and out is not None and not _may_alias(out, lease)
             )
 
-    def _dispatch(
-        self, items: List[_Item], batch_bytes: int
-    ) -> Tuple[List[Any], List[_Item]]:
+    def _settle_unsent(self, items: List[_Item]) -> None:
+        self._settle(
+            [(None, lease) for *_, lease in items if lease is not None], landed=False
+        )
+
+    def _dispatch(self, items: List[_Item]) -> Tuple[List[Any], List[_Item]]:
         """Dispatch the batch in ONE batched ``device_put``, each buffer onto
         its target's own sharding (device, layout and memory kind preserved
         exactly, as _device_put_like does per item); returns (outs, failed)
@@ -986,13 +1184,15 @@ class H2DBatcher:
         outs: List[Any] = [None] * len(items)
         if not bufs:
             return outs, failed
+        nbytes = sum(b.nbytes for b in bufs)
         # Manual phase accounting, recorded only for DISPATCHED bytes:
         # timed() commits in its finally, so a failed batch would charge its
         # bytes to h2d_dispatch and the per-item retry would charge again.
         dispatch = phase_stats.open_interval("h2d_dispatch")
         try:
-            for i, out in zip(idx, jax.device_put(bufs, shardings)):
-                outs[i] = out
+            with self.threads.putting(nbytes):
+                for i, out in zip(idx, jax.device_put(bufs, shardings)):
+                    outs[i] = out
         except Exception:
             dispatch.drop()
             # An HBM OOM looks like this: the per-item retry may well
@@ -1001,13 +1201,13 @@ class H2DBatcher:
                 "batched device_put of %d arrays (%d bytes) failed; "
                 "retrying them one by one",
                 len(bufs),
-                batch_bytes,
+                nbytes,
                 exc_info=True,
             )
             outs = [None] * len(items)
             failed.extend(items[i] for i in idx)
         else:
-            dispatch.close(sum(b.nbytes for b in bufs))
+            dispatch.close(nbytes)
         return outs, failed
 
     def _dispatch_per_item(self, items: List[_Item]) -> None:
@@ -1022,7 +1222,8 @@ class H2DBatcher:
         for host, like, fut, lease in items:
             out = None
             try:
-                fut.obj = out = _device_put_like(host, like)
+                with self.threads.putting(host.nbytes):
+                    fut.obj = out = _device_put_like(host, like)
                 outs.append(out)
                 nbytes += host.nbytes
             except Exception as e:
@@ -1080,13 +1281,16 @@ class ArrayAssembly:
         self._nbytes = serialization.array_nbytes(entry.shape, entry.dtype)
         if (
             h2d_batch is not None
-            and h2d_batch.host_pool is not None
             and not self._inplace
             and staging.is_jax_array(obj_out)
-            and self._nbytes >= _INTO_PLACE_MIN_BYTES
         ):
-            self._pool = h2d_batch.host_pool
-            self._pool.reserve(self._nbytes, obj_out)
+            h2d_batch.expects_uploads = True  # finalize() will submit there
+            if (
+                h2d_batch.host_pool is not None
+                and self._nbytes >= _INTO_PLACE_MIN_BYTES
+            ):
+                self._pool = h2d_batch.host_pool
+                self._pool.reserve(self._nbytes, obj_out)
 
     @property
     def host(self) -> np.ndarray:

@@ -319,7 +319,12 @@ def add_counter(name: str, seconds: float, nbytes: int = 0, **more: int) -> None
     plan, io_preparers/chunked_array.count_chunked; ``read_route``: one
     occurrence a stateful's read plan, its bytes, and ``sequential=``,
     ``striped=``, ``merged=`` by the route each read will take,
-    batcher.count_read_routes).  The
+    batcher.count_read_routes; ``h2d_dispatch_route``: one occurrence a
+    restore, the bytes its H2D batchers sent to the device, and
+    ``off_caller=`` those whose ``device_put`` ran on the restore's
+    dispatcher thread, ``on_caller=`` those on the thread that flushed,
+    ``batches=`` the calls, ``ways=`` the most at once,
+    io_preparers/array.H2DThreads).  The
     entry has ``s``, ``bytes``, ``n`` and whatever ``more`` names, and no
     ``wall``, and reaches neither hook, so it can name no gap of a trace;
     ``delta()`` differences it like any other."""

@@ -604,6 +604,14 @@ class Snapshot:
         written in bulk before any were handed out).  What it buys: PERF.md
         section 5.
 
+        **Uploads run on two threads of the call's own** (``H2DThreads``,
+        the pool's): a flush on the pipeline's thread only queues, the
+        dispatcher makes each batch's ``device_put`` and the lander waits
+        for it to land, both started before the first read is issued and
+        joined when the call ends; the ``h2d_dispatch_route`` counter and
+        the ``restore.end`` entry of that name say how many bytes went that
+        way (``off_caller``).
+
         On-device contract: dense and chunked array uploads are drained
         before return (H2DBatcher.drain — their bytes are ON DEVICE, with
         the landing wall attributed to ``h2d_land``).  **Sharded-array
@@ -697,6 +705,9 @@ class Snapshot:
                                 )
                             )
                     leaves = sum(len(plan.futures) for plan in plans if plan)
+                    # The H2D dispatcher and lander, before the first read
+                    # is issued: never started under read load.
+                    host_pool.start_threads()
                     pipeline = ReadAhead(
                         [plan.read_reqs if plan else [] for plan in plans],
                         storage,
@@ -717,14 +728,10 @@ class Snapshot:
                     finally:
                         pipeline.close()
                 finally:
-                    # Idempotent after a drain; on an abort it stops each
-                    # lander thread (a long-lived trainer must not leak one
-                    # parked thread per failed restore).
-                    for plan in plans:
-                        if plan is not None:
-                            plan.h2d_batch.shutdown()
-                    # Nothing reads any more: the host arena goes here, and
-                    # not beside a read.
+                    # Nothing reads any more: the H2D threads are joined
+                    # here, abort or not (a long-lived trainer must not leak
+                    # a parked thread per failed restore), and the host
+                    # arena goes, not beside a read.
                     with phase_stats.timed("host_pool_free"):
                         host_pool.close()
                 phases_delta = phase_stats.delta(phases_before)
@@ -768,12 +775,22 @@ class Snapshot:
             # leaf had landed from, and how much into pages never touched.
             pooled = host_pool.stats()
             phase_stats.add_counter("host_pool", 0.0, pooled.pop("bytes"), **pooled)
+            # Where the batches' device_put calls ran: on the dispatcher
+            # (off_caller) or on the thread that flushed (on_caller).
+            routed = host_pool.h2d_threads.route()
+            phase_stats.add_counter(
+                "h2d_dispatch_route",
+                0.0,
+                routed["bytes"],
+                **{k: v for k, v in routed.items() if k != "bytes"},
+            )
             event_metadata["duration_s"] = end - begin
             event_metadata["phases"] = phase_stats.walls_between(begin, end)
             event_metadata["unattributed_s"] = unattributed_s
             event_metadata["read_ahead_s"] = pipeline.read_ahead_s
             event_metadata["read_ahead_bytes"] = pipeline.read_ahead_bytes
             event_metadata["host_pool"] = host_pool.stats()
+            event_metadata["h2d_dispatch_route"] = routed
             event_metadata["leaves"] = leaves
             event_metadata["slab_read_bytes"] = int(
                 phases_delta.get("slab_read", {}).get("bytes", 0)
@@ -898,25 +915,21 @@ class Snapshot:
         # behind its read.
         host_pool.begin_group()
         h2d_batch = H2DBatcher(host_pool=host_pool)
-        try:
-            read_reqs: List[ReadReq] = []
-            futures: Dict[str, Future] = {}
-            container_entries: Manifest = {}
-            for path, entry in sub_manifest.items():
-                if is_container_entry(entry):
-                    container_entries[path] = entry
-                    continue
-                obj_out = target_flattened.get(path)
-                entry_read_reqs, fut = io_preparer.prepare_read(
-                    entry, obj_out, h2d_batch=h2d_batch
-                )
-                read_reqs += entry_read_reqs
-                futures[path] = fut
-            count_read_routes(read_reqs, len(futures))
-            read_reqs = batch_read_requests(read_reqs)
-        except BaseException:
-            h2d_batch.shutdown()
-            raise
+        read_reqs: List[ReadReq] = []
+        futures: Dict[str, Future] = {}
+        container_entries: Manifest = {}
+        for path, entry in sub_manifest.items():
+            if is_container_entry(entry):
+                container_entries[path] = entry
+                continue
+            obj_out = target_flattened.get(path)
+            entry_read_reqs, fut = io_preparer.prepare_read(
+                entry, obj_out, h2d_batch=h2d_batch
+            )
+            read_reqs += entry_read_reqs
+            futures[path] = fut
+        count_read_routes(read_reqs, len(futures))
+        read_reqs = batch_read_requests(read_reqs)
         count_chunked("chunked_read", sub_manifest.values())
         tmetrics.record_entries("restore", len(sub_manifest))
         return _StatefulPlan(
