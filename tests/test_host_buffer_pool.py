@@ -529,6 +529,102 @@ def test_chunked_leaves_go_through_the_pool(world):
     assert nothing_left(world)
 
 
+STAGES = array_mod._STAGES
+
+
+def assert_turns_add_up(end):
+    """What every restore's ``arena_turn`` holds to, whatever went through it:
+    the eight stages are the turn, no byte of the arena is lent twice at once,
+    and the ranges that completed a turn are what the pool handed out less
+    those it dropped."""
+    turn, pool = end["arena_turn"], end["host_pool"]
+    assert turn["turn_bs"] == pytest.approx(
+        sum(turn[stage + "_bs"] for stage in STAGES), rel=1e-12
+    )
+    assert all(turn[stage + "_s"] >= 0 and turn[stage + "_bs"] >= 0 for stage in STAGES)
+    assert turn["turn_bs"] <= turn["arena"] * turn["lent_s"]
+    assert turn["ranges"] + turn["dropped"] <= pool["hits"] + pool["misses"]
+    assert turn["bytes"] <= pool["bytes"] + pool["fresh"]
+    return turn
+
+
+@pytest.mark.parametrize("leaves", ["dense", "chunked"])
+def test_every_pooled_leaf_completes_a_turn_through_the_arena(world, leaves):
+    """Three statefuls through one arena of one stateful's bytes: each pooled
+    leaf's range is stamped from its grant to its give, by the pipeline's loop
+    (adopted, read began, read back), the assembly (submitted), the dispatcher
+    (sent, put) and the lander's give, and the counter, once a restore and in
+    ``restore.end``, holds the stages' seconds and byte-seconds."""
+    shapes = SHAPES if leaves == "dense" else ((1024, 1024),) * LEAVES
+    saved = make_app(11, shapes=shapes)
+    with knobs.override_max_chunk_size_bytes((512 << 20) if leaves == "dense" else MIB):
+        path = take(world, "snap", saved)
+    target = make_app(0, shapes=shapes, zero=True)
+    before = phase_stats.snapshot()
+    _, end = restore(world, path, target)
+    assert_equal_bits(target, saved)
+    state_bytes = sum(leaf.nbytes for leaf in restored_leaves(saved))
+    turn = assert_turns_add_up(end)
+    assert not world.plain and turn["dropped"] == 0
+    assert turn["ranges"] == len(KEYS) * LEAVES
+    assert turn["bytes"] == state_bytes == end["host_pool"]["bytes"] + end["host_pool"]["fresh"]
+    assert turn["arena"] == world.arena_sizes[0] == state_bytes // len(KEYS)
+    # every range was read into, uploaded and landed from: time in each
+    assert turn["read_s"] > 0 and turn["land_s"] > 0 and turn["lent_s"] > 0
+    assert turn["gather_s"] + turn["dispatch_s"] > 0
+    # and nothing lasted longer than the arena was lent, range by range
+    assert sum(turn[stage + "_s"] for stage in STAGES) <= turn["ranges"] * turn["lent_s"]
+    counter = phase_stats.delta(before)["arena_turn"]
+    assert counter["n"] == 1 and counter["s"] == 0 and "wall" not in counter
+    assert {k: counter[k] for k in turn} == turn
+    assert nothing_left(world)
+
+
+def test_a_read_parked_behind_the_loader_shows_under_parked(world, monkeypatch):
+    """The first stateful's ``load_state_dict`` takes a fifth of a second:
+    the second's reads have their ranges (the first's have landed), are back,
+    and wait for the loader with the ranges in hand."""
+    saved = make_app(12)
+    path = take(world, "snap", saved)
+    target = make_app(0, zero=True)
+    slow = target[KEYS[0]]
+    really_loads = slow.load_state_dict
+
+    def load_slowly(state_dict):
+        time.sleep(0.2)
+        really_loads(state_dict)
+
+    monkeypatch.setattr(slow, "load_state_dict", load_slowly)
+    _, end = restore(world, path, target)
+    assert_equal_bits(target, saved)
+    turn = assert_turns_add_up(end)
+    assert turn["ranges"] == len(KEYS) * LEAVES and turn["dropped"] == 0
+    # three leaves of the second stateful, most of the fifth of a second each
+    assert turn["parked_s"] > 0.3
+    assert turn["parked_bs"] > 0.1 * STATEFUL_BYTES
+    wait_pct = 100.0 * sum(
+        turn[stage + "_bs"] for stage in ("grant", "slot", "parked", "gather")
+    ) / turn["turn_bs"]
+    assert 0 < wait_pct <= 100
+
+
+def test_a_range_whose_landing_failed_is_dropped_and_the_rest_still_add_up(
+    world, monkeypatch
+):
+    """``recycle=False``: the arena ends there, the range is in no stage."""
+    saved = make_app(13)
+    path = take(world, "snap", saved)
+    target = make_app(0, zero=True)
+    monkeypatch.setattr(array_mod, "_may_alias", lambda out, buf: True)
+    _, end = restore(world, path, target)
+    assert_equal_bits(target, saved)
+    turn = assert_turns_add_up(end)
+    # the first range given back "may be the landed array itself": dropped, and
+    # from then on plain buffers, which have no turn
+    assert turn["dropped"] >= 1 and turn["ranges"] == 0 and turn["bytes"] == 0
+    assert world.plain
+
+
 def test_what_is_not_uploaded_through_the_batcher_never_touches_the_pool(world):
     """Numpy targets (filled in place), leaves under a megabyte and
     ``read_object`` (no target: the buffer is the result)."""

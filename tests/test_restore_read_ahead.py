@@ -459,9 +459,9 @@ def test_the_device_put_runs_on_the_dispatcher_and_the_account_says_so(world):
     }
     state_bytes = len(KEYS) * LEAVES * int(np.prod(SHAPE)) * 4
     route = end["h2d_dispatch_route"]
-    assert sorted(route) == ["batches", "bytes", "off_caller", "on_caller", "ways"]
+    assert sorted(route) == ["batches", "bytes", "off_caller", "on_caller"]
     assert route["bytes"] == route["off_caller"] == state_bytes
-    assert route["on_caller"] == 0 and route["ways"] == 1
+    assert route["on_caller"] == 0 and route["batches"] >= 1
     assert route["batches"] == len(world.log.times("h2d_dispatch"))
     counter = delta["h2d_dispatch_route"]
     assert counter["n"] == 1 and "wall" not in counter

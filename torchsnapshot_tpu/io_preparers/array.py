@@ -20,17 +20,17 @@ from __future__ import annotations
 
 import asyncio
 import bisect
-import contextlib
 import functools
 import logging
 import math
 import mmap
 import threading
+import time
 import weakref
 from collections import deque
 from concurrent.futures import Executor
 from queue import SimpleQueue
-from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -387,6 +387,45 @@ _Lease = Tuple[Any, np.ndarray]
 
 _PAGE = mmap.PAGESIZE
 
+# The clock of every stamp of a range's turn through the arena: the one the
+# phases use, so a turn's stamps and the phases' intervals are on one clock.
+# A name of this module so that a test can put its own in.
+_now = time.monotonic
+
+# A range's turn through the arena, from ``HostBufferPool._fit`` to
+# ``HostBufferPool.give``, is cut into these stages by the stamps below, in
+# this order: stamp k ends stage k and begins stage k+1, the grant begins the
+# first and the give ends the last, so the stages of a turn add up to it.
+_STAGES = (
+    "grant",  # fitted -> adopted by its assembly (a waiter: the loop's _grant)
+    "slot",  # -> its first read handed to storage (io_slot_wait, range in hand)
+    "read",  # -> its last read taken off by the loop thread (storage, hash, queue)
+    "parked",  # -> its consume begins (0 unless parked behind the loader)
+    "consume",  # -> submitted to the batcher (digest, chunk_assemble, loop turns)
+    "gather",  # -> its batch taken by the dispatcher, window room had
+    "dispatch",  # -> the batch's device_put has returned
+    "land",  # -> given back (the batch's block_until_ready, the settle loop)
+)
+# The stamp that ends each stage but the last, which the give ends.
+_STAMPS = (
+    "adopted", "read_began", "read_back", "consume_began", "submitted", "sent", "put",
+)  # fmt: skip
+
+
+class _Turn:
+    """One range's stamps.  Made under the pool's lock (``_fit``), written
+    without it by whoever holds the range (an attribute write each: the
+    assembly on the pipeline's thread, the batcher on the dispatcher), folded
+    into the pool's totals under the lock again (``give``).  A stamp never
+    made reads None and its stage 0."""
+
+    __slots__ = ("size", "nbytes", "granted", *_STAMPS)
+
+    def __init__(self, size: int, nbytes: int) -> None:
+        self.size, self.nbytes, self.granted = size, nbytes, _now()
+        for stamp in _STAMPS:
+            setattr(self, stamp, None)
+
 
 def _pages(nbytes: int) -> int:
     """``nbytes`` rounded up to whole pages."""
@@ -503,6 +542,22 @@ class HostBufferPool:
     it outlives the statefuls, each of which has a batcher of its own):
     ``start_threads`` before the first read, ``close`` joins them.
 
+    **A range's turn is stamped and accounted** (``turn_stats``; the counter
+    ``arena_turn``).  The arena's size is fixed by the rule above, so a
+    restore streams at ``bytes lent / turn``, and what can still shorten it
+    is the turn.  Each range out has one record (``_Turn``, kept where the
+    range's size was: ``_lent``): ``_fit`` makes it, whoever holds the range
+    stamps it at each hand-over without the lock (the assembly finds it once,
+    ``turn_of``, and the read pipeline stamps through ``IntoPlace``; the
+    batcher finds it by the lease it is handed), and ``give``, which holds
+    the lock already, folds it into totals by stage (``_STAGES``) in seconds
+    and in byte-seconds (the leaf's bytes times the stage's length).  The
+    stages of a turn add up to it, and ``turn_bs`` can be no more than the
+    arena's size times ``lent_s`` (no byte is lent twice at once): their
+    ratio is the arena's occupancy.  A range given back unfit, or never
+    adopted by a leaf, is ``dropped`` and in no stage; a plain buffer has no
+    turn.
+
     Thread-safe: ``reserve`` and ``attach`` run on the planning thread,
     ``take`` on the read pipeline's thread or its executor, ``give`` on the
     lander (on the dispatcher for a leaf that was never sent)."""
@@ -516,7 +571,7 @@ class HostBufferPool:
         self._arena: Optional[np.ndarray] = None
         self._base = self._size = 0
         self._free: List[List[int]] = []  # [offset, size], by offset
-        self._lent: Dict[int, int] = {}  # offset -> size of each range out
+        self._lent: Dict[int, _Turn] = {}  # offset -> each range out, its turn
         # (loop, future, nbytes) of each read held for room, in dispatch order
         self._waiters: "deque[Tuple[Any, Any, int]]" = deque()
         self._plain_alive = 0  # bytes of plain buffers taken and not given back
@@ -526,6 +581,13 @@ class HostBufferPool:
             ("bytes", "fresh", "hits", "misses", "high_water", "populated"), 0
         )
         self._touched = 0  # the arena's prefix ever handed out
+        # The turns that ended in a give of a landed range, by stage.
+        self._turns: Dict[str, float] = dict.fromkeys(
+            ("bytes", "ranges", "dropped", "lent_s"), 0
+        )
+        for stage in _STAGES:
+            self._turns[stage + "_s"] = self._turns[stage + "_bs"] = 0.0
+        self._first_grant: Optional[float] = None
 
     def begin_group(self) -> None:
         """The reservations that follow are one stateful's."""
@@ -597,7 +659,9 @@ class HostBufferPool:
             del self._free[i]
         else:
             self._free[i] = [offset + size, room - size]
-        self._lent[offset] = size
+        self._lent[offset] = turn = _Turn(size, nbytes)
+        if self._first_grant is None:
+            self._first_grant = turn.granted
         fresh = min(nbytes, max(0, offset + size - self._touched))
         self._touched = max(self._touched, offset + size)
         self._count(nbytes, fresh)
@@ -658,12 +722,13 @@ class HostBufferPool:
         granted: List[Tuple[Any, Any, np.ndarray]] = []
         with self._lock:
             offset = buf.ctypes.data - self._base
-            size = self._lent.pop(offset, None)
-            if size is None:
+            turn = self._lent.pop(offset, None)
+            if turn is None:
                 self._plain_alive -= buf.nbytes  # a plain buffer: dropped
                 return
+            self._fold(turn, landed=recycle)
             if recycle:
-                self._release(offset, size)
+                self._release(offset, turn.size)
             else:
                 self._plain = True
             while self._waiters:
@@ -680,6 +745,33 @@ class HostBufferPool:
                 loop.call_soon_threadsafe(self._grant, coming, lease)
             except RuntimeError:  # the pipeline was aborted, its loop closed
                 self.give(lease, recycle=True)
+
+    def turn_of(self, buf: np.ndarray) -> Optional[_Turn]:
+        """The record of the range under ``buf`` while it is out, for its
+        holder to stamp (None for a plain buffer).  Takes no lock: a lookup
+        in a dict that only ``_fit`` and ``give`` change, and whoever holds
+        a range is between the two."""
+        return self._lent.get(buf.ctypes.data - self._base)
+
+    def _fold(self, turn: _Turn, landed: bool) -> None:
+        """Under the lock, in ``give``: a turn is over.  One that a leaf
+        went through (adopted, landed) adds each stage's seconds and
+        byte-seconds to the totals; any other is ``dropped``."""
+        totals = self._turns
+        if not landed or turn.adopted is None:
+            totals["dropped"] += 1
+            return
+        given = _now()
+        totals["bytes"] += turn.nbytes
+        totals["ranges"] += 1
+        totals["lent_s"] = given - self._first_grant  # (gives come in order)
+        at = turn.granted
+        stamps = [getattr(turn, stamp) for stamp in _STAMPS] + [given]
+        for stage, stamp in zip(_STAGES, stamps):
+            if stamp is not None and stamp > at:
+                totals[stage + "_s"] += stamp - at
+                totals[stage + "_bs"] += turn.nbytes * (stamp - at)
+                at = stamp
 
     def _grant(self, coming: "asyncio.Future[np.ndarray]", lease: np.ndarray) -> None:
         if coming.done():  # cancelled with its read
@@ -719,6 +811,21 @@ class HostBufferPool:
         populate it with), which changes none of the others."""
         with self._lock:
             return dict(self._stats)
+
+    def turn_stats(self) -> Dict[str, float]:
+        """The turns through the arena that ended with a landed range given
+        back: their ``bytes`` and count (``ranges``); ``<stage>_s`` the
+        seconds each stage of ``_STAGES`` lasted, summed over the ranges, and
+        ``<stage>_bs`` the same weighted by each range's bytes;
+        ``turn_bs`` the sum of the eight, so the byte-seconds lent: at most
+        ``arena`` (its size) times ``lent_s`` (the last such give less the
+        first grant); ``dropped`` the ranges given back unfit or unused.
+        Zeros where no arena was made."""
+        with self._lock:
+            out = dict(self._turns)
+            out["arena"] = self._size
+        out["turn_bs"] = sum(out[stage + "_bs"] for stage in _STAGES)
+        return out
 
 
 def _may_alias(out: Any, buf: np.ndarray) -> bool:
@@ -816,17 +923,14 @@ class H2DThreads:
     counter ``h2d_dispatch_route``): ``bytes`` dispatched, of them
     ``off_caller`` on the dispatcher and ``on_caller`` on the thread that
     called ``flush`` or ``drain`` (only once the threads are closed: the
-    fall-back that leaves nothing handed over undone), in ``batches`` calls,
-    at the most ``ways`` of them at once."""
+    fall-back that leaves nothing handed over undone), in ``batches``
+    calls."""
 
     def __init__(self) -> None:
         self.dispatcher = _Worker("tpusnap-h2d-dispatcher")
         self.lander = _Worker("tpusnap-h2d-lander")
         self._lock = threading.Lock()
-        self._route = dict.fromkeys(
-            ("bytes", "off_caller", "on_caller", "batches", "ways"), 0
-        )
-        self._putting = 0  # device_put calls under way
+        self._route = dict.fromkeys(("bytes", "off_caller", "on_caller", "batches"), 0)
 
     def start(self) -> None:
         self.dispatcher.start()
@@ -837,27 +941,43 @@ class H2DThreads:
         self.dispatcher.close()
         self.lander.close()
 
-    @contextlib.contextmanager
-    def putting(self, nbytes: int) -> Iterator[None]:
-        """Around one ``device_put`` call of ``nbytes``; counted where the
-        call returns (a call that raises sent nothing)."""
+    def count_put(self, nbytes: int) -> None:
+        """One ``device_put`` call of ``nbytes`` has returned on this thread
+        (a call that raised sent nothing and is not counted)."""
         side = "off_caller" if self.dispatcher.is_current() else "on_caller"
         with self._lock:
-            self._putting += 1
-            self._route["ways"] = max(self._route["ways"], self._putting)
-        try:
-            yield
-            with self._lock:
-                self._route["bytes"] += nbytes
-                self._route[side] += nbytes
-                self._route["batches"] += 1
-        finally:
-            with self._lock:
-                self._putting -= 1
+            self._route["bytes"] += nbytes
+            self._route[side] += nbytes
+            self._route["batches"] += 1
 
     def route(self) -> Dict[str, int]:
         with self._lock:
             return dict(self._route)
+
+
+# A landing that stalls says so (the counter ``h2d_land_slow``): half a second
+# and more at under 0.5 GB/s.  The slowest ceiling a healthy landing was
+# measured at is 5.4 GB/s (one large bf16 leaf, tools/h2d_ceiling_probe.py,
+# PERF.md section 7), and the largest single landing of any cell, 713 MB,
+# takes 0.13 s at it: a tenth of that rate over half a second is no healthy
+# landing of any size, and every stall seen (3-7 s, PERF.md section 5) is.
+_SLOW_LANDING_S = 0.5
+_SLOW_LANDING_BYTES_PER_S = 0.5e9
+
+
+def _note_slow_landing(seconds: float, nbytes: int, leaves: int) -> None:
+    from .. import phase_stats
+
+    if seconds >= _SLOW_LANDING_S and nbytes < seconds * _SLOW_LANDING_BYTES_PER_S:
+        phase_stats.add_counter("h2d_land_slow", seconds, nbytes)
+        logger.info(
+            "a slow H2D landing: %d bytes in %d leaves took %.3f s "
+            "(%.3f GB/s) to be on the device",
+            nbytes,
+            leaves,
+            seconds,
+            nbytes / seconds / 1e9,
+        )
 
 
 class H2DBatcher:
@@ -907,6 +1027,18 @@ class H2DBatcher:
     flushed at once, whatever ``flush_bytes`` says; a flush always leaves a
     dispatcher run scheduled, so no waiter waits on a batch that nobody will
     send.  Nothing here keeps a host buffer past its landing.
+
+    **What it tells the record.**  A leased buffer's range has a record of
+    its turn through the arena (``HostBufferPool.turn_of``): the dispatcher
+    stamps it when it takes the batch with window room had (``sent``: until
+    then the leaf gathered under ``flush_bytes``, queued behind a busy
+    dispatcher, or waited for the window) and when the batch's
+    ``device_put`` has returned (``put``); the lander's ``give`` ends the
+    turn.  A leaf's own landing inside its batch is one number with its
+    batch-mates': every range of a batch comes back when the whole batch has
+    landed.  A landing that stalls (``_SLOW_LANDING_S`` and more, at under
+    ``_SLOW_LANDING_BYTES_PER_S``) is counted, ``h2d_land_slow``, and logged
+    at INFO with its bytes, leaves and seconds.
 
     **Errors.**  A dispatch or landing failure must not wedge the batcher:
     the first one is kept, the byte accounting stays exact, the batches
@@ -1032,6 +1164,10 @@ class H2DBatcher:
                 self._unlanded_bytes += batch_bytes  # reserved
         if window_wait is not None:
             window_wait.close(min_s=0.001)
+        # The ranges of this batch, for their turn's stamps: gathered and
+        # queued until here, in the device_put call until it returns.
+        turns = self._turns_of(items)
+        self._stamp(turns, "sent")
         if failed_before:
             # Nothing more goes to the device; the ranges go back unfit, so a
             # read that waits for one gets on and meets the error.
@@ -1045,6 +1181,7 @@ class H2DBatcher:
                 self._cond.notify_all()
             self._settle_unsent(items)
             raise
+        self._stamp(turns, "put")
         landed_bytes = sum(
             host.nbytes for (host, *_), out in zip(items, outs) if out is not None
         )
@@ -1072,6 +1209,21 @@ class H2DBatcher:
             # sharding mismatch) fails alone with correct blame and its
             # batch-mates still restore.
             self._dispatch_per_item(failed)
+
+    def _turns_of(self, items: List[_Item]) -> List[_Turn]:
+        """The arena's records of the leased buffers among ``items``."""
+        if self.host_pool is None:
+            return []
+        found = (
+            self.host_pool.turn_of(lease) for *_, lease in items if lease is not None
+        )
+        return [turn for turn in found if turn is not None]
+
+    @staticmethod
+    def _stamp(turns: List[_Turn], stamp: str) -> None:
+        at = _now()
+        for turn in turns:
+            setattr(turn, stamp, at)
 
     def drain(self) -> None:
         """Flush the tail and block until every transfer handed over has been
@@ -1122,9 +1274,11 @@ class H2DBatcher:
         from .. import phase_stats
 
         err: Optional[BaseException] = None
+        began = _now()
         try:
             with phase_stats.timed("h2d_land", nbytes):
                 jax.block_until_ready(outs)
+            _note_slow_landing(_now() - began, nbytes, len(outs))
         except BaseException as e:  # noqa: BLE001
             err = e
         # Before the window opens: whoever it lets through finds the
@@ -1190,9 +1344,9 @@ class H2DBatcher:
         # bytes to h2d_dispatch and the per-item retry would charge again.
         dispatch = phase_stats.open_interval("h2d_dispatch")
         try:
-            with self.threads.putting(nbytes):
-                for i, out in zip(idx, jax.device_put(bufs, shardings)):
-                    outs[i] = out
+            for i, out in zip(idx, jax.device_put(bufs, shardings)):
+                outs[i] = out
+            self.threads.count_put(nbytes)
         except Exception:
             dispatch.drop()
             # An HBM OOM looks like this: the per-item retry may well
@@ -1222,8 +1376,8 @@ class H2DBatcher:
         for host, like, fut, lease in items:
             out = None
             try:
-                with self.threads.putting(host.nbytes):
-                    fut.obj = out = _device_put_like(host, like)
+                fut.obj = out = _device_put_like(host, like)
+                self.threads.count_put(host.nbytes)
                 outs.append(out)
                 nbytes += host.nbytes
             except Exception as e:
@@ -1237,8 +1391,10 @@ class H2DBatcher:
         landed = False
         try:
             if outs:
+                began = _now()
                 with phase_stats.timed("h2d_land", nbytes):
                     jax.block_until_ready(outs)
+                _note_slow_landing(_now() - began, nbytes, len(outs))
             landed = True
         finally:
             self._settle(leases, landed)
@@ -1275,6 +1431,7 @@ class ArrayAssembly:
         # dispatches reads: one of them makes the buffer.
         self._host_lock = threading.Lock()
         self._lease: Optional[np.ndarray] = None  # the pool's buffer under _host
+        self._turn: Optional[_Turn] = None  # the arena's record of that range
         self._pool: Optional[HostBufferPool] = None
         # the pool's promise of a range, while the first read waits for room
         self._coming: Optional["asyncio.Future[np.ndarray]"] = None
@@ -1305,6 +1462,8 @@ class ArrayAssembly:
     def _adopt(self, lease: np.ndarray) -> None:
         # Under _host_lock.
         self._lease = lease
+        self._turn = self._pool.turn_of(lease)
+        self.stamp("adopted")
         self._host = lease.view(
             serialization.string_to_dtype(self.entry.dtype)
         ).reshape(self.entry.shape)
@@ -1343,6 +1502,15 @@ class ArrayAssembly:
             elif self._lease is not lease:
                 self._pool.give(lease, recycle=True)
 
+    def stamp(self, stamp: str) -> None:
+        """A hand-over of this leaf's range of the arena, for the record of
+        its turn (``_STAGES``; nothing for a leaf with no range): of several
+        reads into the leaf the first ``read_began`` stands, and the last of
+        every other stamp."""
+        turn = self._turn
+        if turn is not None and (stamp != "read_began" or turn.read_began is None):
+            setattr(turn, stamp, _now())
+
     def expect(self, n: int) -> None:
         self._pending = n
         if n == 0:  # degenerate zero-size array
@@ -1369,7 +1537,8 @@ class ArrayAssembly:
 
     def finalize(self) -> None:
         out = self.host
-        lease, self._host, self._lease = self._lease, None, None
+        self.stamp("submitted")
+        lease, self._host, self._lease, self._turn = self._lease, None, None, None
         target = self.obj_out
         if self._inplace:
             self.fut.obj = target
@@ -1399,7 +1568,8 @@ class IntoPlace:
     plan knows that much; the memory is there from ``acquire()``, which the
     read pipeline awaits when it dispatches the read, before the read's io
     slot, and the consumer lets go of it (``release``) once it has seen the
-    read arrive."""
+    read arrive.  It is also the pipeline's handle on the leaf's range of
+    the restore's arena, for the stamps of the range's turn (``stamp``)."""
 
     def __init__(self, assembly: ArrayAssembly, offset: int, nbytes: int) -> None:
         self._assembly = assembly
@@ -1416,6 +1586,11 @@ class IntoPlace:
             flat = memoryview(self._assembly.flat_u8())
             self._view = flat[self._offset : self._offset + self._nbytes]
         return self._view
+
+    def stamp(self, stamp: str) -> None:
+        """The read pipeline's hand-overs of this read (``read_began``,
+        ``read_back``, ``consume_began``), told to the leaf's range."""
+        self._assembly.stamp(stamp)
 
     def holds(self, buf: Any) -> bool:
         """Whether ``buf`` is the view this read was given to land in."""

@@ -185,6 +185,10 @@ class IntoView:
     async def acquire(self) -> memoryview:
         return self._view
 
+    def stamp(self, stamp: str) -> None:
+        """Nothing to tell: the memory is no range of a restore's arena
+        (``io_preparers/array.IntoPlace.stamp``)."""
+
 
 class StoragePlugin(abc.ABC):
     """Async storage backend contract (reference io_types.py:80-120)."""

@@ -323,8 +323,16 @@ def add_counter(name: str, seconds: float, nbytes: int = 0, **more: int) -> None
     restore, the bytes its H2D batchers sent to the device, and
     ``off_caller=`` those whose ``device_put`` ran on the restore's
     dispatcher thread, ``on_caller=`` those on the thread that flushed,
-    ``batches=`` the calls, ``ways=`` the most at once,
-    io_preparers/array.H2DThreads).  The
+    ``batches=`` the calls, io_preparers/array.H2DThreads; ``arena_turn``:
+    one occurrence a restore, the bytes of the ranges of its host arena
+    that completed a turn, and ``ranges=``, ``dropped=``, ``turn_bs=``,
+    ``<stage>_s=`` and ``<stage>_bs=`` for each of the eight stages of a
+    turn, ``arena=``, ``lent_s=``, io_preparers/array.HostBufferPool;
+    ``restore_overlap``: one occurrence a restore, the seconds in which a
+    storage read and an H2D dispatch or landing were both under way, and
+    ``reads_s=``, ``h2d_s=``, ``neither_s=``, snapshot._restore_overlap;
+    ``h2d_land_slow``: one occurrence a landing that stalled, its seconds
+    and bytes, io_preparers/array._note_slow_landing).  The
     entry has ``s``, ``bytes``, ``n`` and whatever ``more`` names, and no
     ``wall``, and reaches neither hook, so it can name no gap of a trace;
     ``delta()`` differences it like any other."""
@@ -431,6 +439,28 @@ def union_s(intervals: List[Tuple[float, float]]) -> float:
     return sum(end - begin for begin, end in _merge(intervals))
 
 
+def overlap_s(
+    a: List[Tuple[float, float]], b: List[Tuple[float, float]]
+) -> float:
+    """Seconds covered by at least one interval of ``a`` AND at least one of
+    ``b``: the intersection of the two unions, by one walk over both merged
+    lists (``Snapshot.restore``'s ``restore_overlap``: how long reads and H2D
+    were under way at once)."""
+    a, b = _merge(a), _merge(b)
+    i = j = 0
+    both = 0.0
+    while i < len(a) and j < len(b):
+        begin, end = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if end > begin:
+            both += end - begin
+        # The one that ends first can meet nothing further of the other.
+        if a[i][1] <= b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return both
+
+
 def snapshot() -> Dict[str, Dict[str, float]]:
     with _lock:
         out = {k: dict(v) for k, v in _stats.items()}
@@ -466,19 +496,32 @@ def attributed_wall_s(
     return union_s(_clipped(ivs, begin, end))
 
 
+def intervals_between(
+    begin: float, end: float
+) -> Dict[str, List[Tuple[float, float]]]:
+    """Each phase's intervals clipped to ``[begin, end]``, for the phases
+    that were active in it: what a call that holds its intervals
+    (``hold``) reduces once it has ended, by phase (``walls_between``) or
+    across phases (``overlap_s`` over two groups of them).  Same exactness
+    as ``attributed_wall_s``."""
+    with _lock:
+        live = {phase: list(ivs) for phase, ivs in _intervals.items()}
+    out: Dict[str, List[Tuple[float, float]]] = {}
+    for phase, ivs in live.items():
+        clipped = _clipped(ivs, begin, end)
+        if clipped:
+            out[phase] = clipped
+    return out
+
+
 def walls_between(begin: float, end: float) -> Dict[str, float]:
     """Each phase's wall-union clipped to ``[begin, end]``, for the phases
     that were active in it: one call's own account, where ``delta()``
     differences process-wide totals.  Same exactness as
     ``attributed_wall_s``."""
-    with _lock:
-        live = {phase: list(ivs) for phase, ivs in _intervals.items()}
-    out: Dict[str, float] = {}
-    for phase, ivs in live.items():
-        clipped = _clipped(ivs, begin, end)
-        if clipped:
-            out[phase] = union_s(clipped)
-    return out
+    return {
+        phase: union_s(ivs) for phase, ivs in intervals_between(begin, end).items()
+    }
 
 
 def reset() -> None:

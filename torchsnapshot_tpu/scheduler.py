@@ -827,9 +827,18 @@ class _ReadPipeline:
         if self.read_req.into is not None:
             self.into = await self.read_req.into.acquire()
 
+    def stamp(self, stamp: str) -> None:
+        """A hand-over of this read (``read_began``, ``read_back``,
+        ``consume_began``), for the record of its leaf's turn through the
+        restore's host arena (``io_preparers/array.HostBufferPool``): told
+        through the handle the read lands by, nothing for a read with none."""
+        if self.read_req.into is not None:
+            self.read_req.into.stamp(stamp)
+
     async def read_buffer(self) -> "_ReadPipeline":
         consumer = self.read_req.buffer_consumer
         self.read_began = time.monotonic()
+        self.stamp("read_began")
         read_io = ReadIO(
             path=self.read_req.path,
             byte_range=(
@@ -1057,6 +1066,12 @@ async def execute_read_reqs(
       so it can starve no read of k of room.
     - no read of group k+2 starts before group k is loaded, all of its
       host ranges landed from.
+    - a range's turn through that arena is stamped where this loop hands it
+      on (``_ReadPipeline.stamp``): ``read_began`` when storage is asked,
+      ``read_back`` when this thread takes the finished read off (so a
+      finished read that waits for this thread counts with its read), and
+      ``consume_began`` when a read parked behind the loader is let go; the
+      pool accounts the stages between (the counter ``arena_turn``).
 
     With no loader a group counts as loaded once it is consumed.  An error
     in any read or consume cancels everything in flight and is raised."""
@@ -1214,6 +1229,7 @@ async def execute_read_reqs(
             loaded = len(loader._loaded())
         for pipeline in [p for p in parked if p.group <= loaded]:
             parked.remove(pipeline)
+            pipeline.stamp("consume_began")  # was parked from read_back to here
             consume(pipeline)
 
     # read_starved: one interval per stretch in which the pipeline is alive
@@ -1271,6 +1287,9 @@ async def execute_read_reqs(
                     pipeline = task.result()  # raises on storage failure
                     pipelines.pop(task)
                     unread[pipeline.group] -= 1
+                    # Taken off: until here a finished read waited for this
+                    # thread, which its range's turn counts with the read.
+                    pipeline.stamp("read_back")
                     if pipeline.group <= loaded:
                         consume(pipeline)
                     else:

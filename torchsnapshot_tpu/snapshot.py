@@ -37,6 +37,7 @@ from typing import Any, Dict, List, Optional, Set, Tuple
 import numpy as np
 
 from . import io_preparer, knobs, phase_stats, retry as retry_policy, staging
+from .telemetry import analyze as tanalyze
 from .telemetry import metrics as tmetrics
 from .telemetry import monitor as tmonitor
 from .telemetry import sidecar as tsidecar
@@ -612,6 +613,15 @@ class Snapshot:
         the ``restore.end`` entry of that name say how many bytes went that
         way (``off_caller``).
 
+        **The call accounts for its arena's turn and its own overlap**: the
+        ``arena_turn`` counter (each range of the arena stamped from its
+        grant to its give, by stage, in seconds and byte-seconds:
+        ``HostBufferPool.turn_stats``), ``restore_overlap`` (the seconds in
+        which storage reads and H2D were both under way, each one's wall and
+        what neither covers: ``_restore_overlap``, over the intervals the
+        call holds) and ``h2d_land_slow`` (a landing that stalled, counted
+        and logged where it happens) are each an entry of ``restore.end``.
+
         On-device contract: dense and chunked array uploads are drained
         before return (H2DBatcher.drain — their bytes are ON DEVICE, with
         the landing wall attributed to ``h2d_land``).  **Sharded-array
@@ -762,10 +772,19 @@ class Snapshot:
             # This one call's account: each phase's wall inside it, and what
             # no phase covers, as a number of its own (a counter: it has no
             # interval, so it can name no gap of a trace).
+            held = phase_stats.intervals_between(begin, end)
             unattributed_s = max(
-                0.0, end - begin - phase_stats.attributed_wall_s(begin, end)
+                0.0,
+                end
+                - begin
+                - phase_stats.union_s([iv for ivs in held.values() for iv in ivs]),
             )
             phase_stats.add_counter("restore_unattributed", unattributed_s)
+            # The call split by which of reads and H2D were under way: the
+            # same intervals, taken as two unions by the groups analyze.py
+            # classifies phases into, and what both cover at once.
+            overlap = _restore_overlap(held, end - begin)
+            _add_counter_of("restore_overlap", overlap)
             # What the pipeline read ahead of this thread: a counter too,
             # since the reads' own phases already draw those stretches.
             phase_stats.add_counter(
@@ -774,22 +793,30 @@ class Snapshot:
             # How much was read into pages of the host arena that an earlier
             # leaf had landed from, and how much into pages never touched.
             pooled = host_pool.stats()
-            phase_stats.add_counter("host_pool", 0.0, pooled.pop("bytes"), **pooled)
+            _add_counter_of("host_pool", pooled)
             # Where the batches' device_put calls ran: on the dispatcher
             # (off_caller) or on the thread that flushed (on_caller).
             routed = host_pool.h2d_threads.route()
-            phase_stats.add_counter(
-                "h2d_dispatch_route",
-                0.0,
-                routed["bytes"],
-                **{k: v for k, v in routed.items() if k != "bytes"},
-            )
+            _add_counter_of("h2d_dispatch_route", routed)
+            # Each range's turn through the arena, by stage: what holds the
+            # bytes the arena lends, and for how long.
+            turned = host_pool.turn_stats()
+            _add_counter_of("arena_turn", turned)
+            slow = phases_delta.get("h2d_land_slow", {})
             event_metadata["duration_s"] = end - begin
-            event_metadata["phases"] = phase_stats.walls_between(begin, end)
+            event_metadata["phases"] = {
+                phase: phase_stats.union_s(ivs) for phase, ivs in held.items()
+            }
             event_metadata["unattributed_s"] = unattributed_s
+            event_metadata["restore_overlap"] = overlap
+            event_metadata["arena_turn"] = turned
+            event_metadata["h2d_land_slow"] = {
+                "s": slow.get("s", 0.0),
+                "n": int(slow.get("n", 0)),
+            }
             event_metadata["read_ahead_s"] = pipeline.read_ahead_s
             event_metadata["read_ahead_bytes"] = pipeline.read_ahead_bytes
-            event_metadata["host_pool"] = host_pool.stats()
+            event_metadata["host_pool"] = pooled
             event_metadata["h2d_dispatch_route"] = routed
             event_metadata["leaves"] = leaves
             event_metadata["slab_read_bytes"] = int(
@@ -1764,6 +1791,47 @@ class PendingSnapshot:
             logger.warning(
                 "PendingSnapshot done-callback %r failed", fn, exc_info=True
             )
+
+
+def _add_counter_of(name: str, stats: Dict[str, Any]) -> None:
+    """One occurrence of the counter ``name`` from an account's dict: its
+    ``s`` and ``bytes`` (0 where it has none) and every other key as is."""
+    more = dict(stats)
+    phase_stats.add_counter(name, more.pop("s", 0.0), more.pop("bytes", 0), **more)
+
+
+def _restore_overlap(
+    held: Dict[str, List[Tuple[float, float]]], call_s: float
+) -> Dict[str, float]:
+    """One restore call of ``call_s`` seconds, split by which of its two
+    streaming stages were under way, from the intervals it kept (``held``:
+    by phase, clipped to the call).  **Reads** are the phases that
+    ``analyze.classify_phase`` puts in ``storage_io`` (``native_read``,
+    ``fs_read``, any plug-in's ``*_read``, the chunk cache's and the peers';
+    ``plan_read`` is the driver's there) but for the writes among them (a
+    restore writes its telemetry sidecar, after its last read: ``fs_write``
+    is storage work and no read), and **h2d** the group of that name
+    (``h2d_dispatch``, ``h2d_land``): ``reads_s`` and ``h2d_s`` each union's
+    wall, ``s`` the seconds both were under way, ``neither_s`` the call less
+    the union of both (the head before the first read, the arena's
+    population, and every stretch in which only the loop thread, the driver
+    or nobody worked)."""
+    reads: List[Tuple[float, float]] = []
+    h2d: List[Tuple[float, float]] = []
+    for phase, intervals in held.items():
+        group = tanalyze.classify_phase(phase)
+        if group == "storage_io" and "write" not in phase:
+            reads += intervals
+        elif group == "h2d":
+            h2d += intervals
+    reads_s, h2d_s = phase_stats.union_s(reads), phase_stats.union_s(h2d)
+    both_s = phase_stats.overlap_s(reads, h2d)
+    return {
+        "s": both_s,
+        "reads_s": reads_s,
+        "h2d_s": h2d_s,
+        "neither_s": max(0.0, call_s - (reads_s + h2d_s - both_s)),
+    }
 
 
 def _accepts_strict(stateful: Stateful) -> bool:

@@ -40,7 +40,11 @@ from . import trace as ttrace
 
 # Leaf-phase → resource-group classification.  Storage phases are matched
 # by suffix so every backend (fs/mem/gcs/s3) lands in storage_io without
-# this table needing to know plugin names.
+# this table needing to know plugin names.  Read, through classify_phase,
+# by this module's reports, by the profiler and the post-mortem, by the
+# phase-registry lint rule, and by Snapshot.restore: its restore_overlap
+# counter takes a call's reads as the storage_io group and its uploads as
+# the h2d group, so a phase moved between groups moves that split too.
 PHASE_GROUPS: Dict[str, frozenset] = {
     "d2h": frozenset({"d2h", "device_stage"}),
     "serialize": frozenset(
