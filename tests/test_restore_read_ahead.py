@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from torchsnapshot_tpu import RNGState, Snapshot, StateDict, knobs, phase_stats
+from torchsnapshot_tpu import RNGState, Snapshot, StateDict, integrity, knobs, phase_stats
 from torchsnapshot_tpu import scheduler as scheduler_mod
 from torchsnapshot_tpu import snapshot as snapshot_mod
 from torchsnapshot_tpu.event_handlers import (
@@ -90,11 +90,25 @@ def stateful_of(path):
     return parts[1] if len(parts) >= 3 and parts[1] in KEYS else None
 
 
-class SlowStorage(StoragePlugin):
-    """The memory plug-in with reads that take ``READ_S`` and can fail."""
+def land_in_place(read_io):
+    """What the native fs plug-in does with a read that brings a view: the
+    bytes land there, the view comes back as the read's buffer, and the
+    digest the request asks for comes with it."""
+    if read_io.into is not None:
+        read_io.into[:] = read_io.buf
+        read_io.buf = read_io.into
+    if read_io.want_hash:
+        read_io.hash64 = integrity._hash64(read_io.buf, read_io.hash_algo)
 
-    def __init__(self, inner, log, fail_key=None, fail_after=None, on_read=None):
-        self._inner, self._log = inner, log
+
+class SlowStorage(StoragePlugin):
+    """The memory plug-in with reads that take ``READ_S`` and can fail, and
+    (``in_place``) land in the view they bring with their digest fused."""
+
+    def __init__(
+        self, inner, log, fail_key=None, fail_after=None, on_read=None, in_place=False
+    ):
+        self._inner, self._log, self._in_place = inner, log, in_place
         self._fail_key, self._fail_after, self._on_read = fail_key, fail_after, on_read
 
     async def read(self, read_io):
@@ -112,6 +126,8 @@ class SlowStorage(StoragePlugin):
                 await asyncio.sleep(0.005)
             raise ValueError(f"injected read failure in {key}")
         await self._inner.read(read_io)
+        if self._in_place:
+            land_in_place(read_io)
         self._log.add("read_end", key)
 
     async def write(self, write_io):
@@ -254,6 +270,17 @@ def world(monkeypatch, request):
         log.add("consume_begin", stateful_of(self.read_req.path))
         return await real_consume(self, executor)
 
+    real_landed = scheduler_mod._ReadPipeline.consume_landed
+
+    def recording_landed(self):
+        key = stateful_of(self.read_req.path)
+        log.add("consume_begin", key)  # either way: consume_buffer comes next
+        landed = real_landed(self)
+        if landed:
+            log.add("consume_inline", key)
+        return landed
+
+    monkeypatch.setattr(scheduler_mod._ReadPipeline, "consume_landed", recording_landed)
     monkeypatch.setattr(scheduler_mod._ReadPipeline, "take_memory", recording_take)
     monkeypatch.setattr(scheduler_mod._ReadPipeline, "consume_buffer", recording_consume)
     real_submit, real_dispatch = H2DBatcher.submit, H2DBatcher._dispatch
@@ -441,6 +468,40 @@ def test_nothing_is_consumed_or_sent_to_the_device_ahead_of_the_load(world):
     assert {name for what, _, _, name in log.rows if what == "h2d_dispatch"} == {
         "tpusnap-h2d-dispatcher"
     }
+
+
+def test_a_read_landed_in_place_ahead_of_the_load_is_parked_then_consumed_inline(world):
+    """The short path changes nothing of the gate: a read of k+1 that landed
+    in place with its digest and is back before k is loaded is parked, its
+    range held, and consumed (on the pipeline's thread, in the turn that
+    lets it go: no task, no executor) only once k is loaded."""
+    world.storage_args.update(in_place=True)
+    target = make_app(world.log, zero=True)
+    delta, (end,) = restore(world, target)
+    log = world.log
+    for this, ahead in zip(KEYS, KEYS[1:]):
+        loaded = log.last("load_end", this)
+        assert log.last("read_end", ahead) < loaded, (this, ahead)  # back, and parked
+        for what in ("consume_begin", "consume_inline", "h2d_submit"):
+            assert len(log.times(what, ahead)) == LEAVES, (what, ahead)
+            assert log.first(what, ahead) >= loaded, (what, this, ahead)
+    inline = [name for what, _, _, name in log.rows if what == "consume_inline"]
+    assert len(inline) == len(KEYS) * LEAVES and set(inline) == {"tpusnap-read-pipeline"}
+    loop = end["read_loop"]
+    assert sorted(loop) == ["handed", "inline", "max_pending", "taken", "turns"]
+    assert (loop["inline"], loop["handed"], loop["taken"]) == (len(KEYS) * LEAVES, 0, 12)
+    assert 1 <= loop["turns"] and 1 <= loop["max_pending"] <= LEAVES
+    counter = delta["read_loop"]
+    assert counter["n"] == 1 and {k: counter[k] for k in loop} == loop
+    # parked from read_back to consume_began: the stage is there, and the
+    # stages still add up
+    turned = end["arena_turn"]
+    assert turned["parked_s"] > 0 and turned["ranges"] == len(KEYS) * LEAVES
+    assert turned["turn_bs"] == pytest.approx(
+        sum(turned[stage + "_bs"] for stage in array_mod._STAGES)
+    )
+    assert_equal_bits(target, world.saved)
+    assert no_pipeline_thread_alive()
 
 
 def test_the_device_put_runs_on_the_dispatcher_and_the_account_says_so(world):
@@ -709,3 +770,107 @@ def test_groups_complete_in_order_under_any_budget(budget):
     # no wake-up was lost: the pipeline's own timeout is 5 s
     assert time.monotonic() - began < 3.0
     assert pipeline.read_ahead_s >= 0 and pipeline.read_ahead_bytes <= 1500
+
+
+def test_the_stamps_of_a_turn_come_in_order_on_the_short_path_and_add_up(monkeypatch):
+    """A hand-driven pool behind the real pipeline, under a clock that moves
+    one second a reading: two leaves of group 0 consumed inline in the turn
+    they are taken off, one of group 1 parked until group 0 is loaded.  Every
+    stamp is made, in the order of ``_STAMPS`` (``consume_began`` only for the
+    parked one), and the eight ``_bs`` are ``turn_bs``."""
+    from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer, HostBufferPool
+    from torchsnapshot_tpu.manifest import TensorEntry
+
+    page = array_mod._PAGE
+    ticks = iter(range(5000, 10**6))
+    monkeypatch.setattr(array_mod, "_now", lambda: float(next(ticks)))
+    on_a_chip = types.SimpleNamespace(
+        devices=lambda: [types.SimpleNamespace(platform="tpu")]
+    )
+    monkeypatch.setattr(array_mod.staging, "is_jax_array", lambda obj: obj is on_a_chip)
+    monkeypatch.setattr(array_mod, "_INTO_PLACE_MIN_BYTES", page)
+    pool = HostBufferPool()
+
+    class Window:  # what the pool knows of an H2DBatcher
+        expects_uploads, inflight_cap_bytes = True, 8 * page
+
+        def flush(self):
+            pass
+
+    window = Window()
+    pool.attach(window)
+    batcher = types.SimpleNamespace(host_pool=pool, expects_uploads=False, submitted=[])
+    batcher.submit = lambda *item: batcher.submitted.append(item)
+    MemoryStoragePlugin.reset()
+
+    class Landing(MemoryStoragePlugin):
+        async def read(self, read_io):
+            await super().read(read_io)
+            land_in_place(read_io)
+
+    storage = Landing(root="turn_in_order")
+    groups, wanted = [], {}
+    for names in (["a", "b"], ["c"]):
+        pool.begin_group()
+        group = []
+        for name in names:
+            data = np.random.RandomState(ord(name)).bytes(2 * page)
+            storage._files[name] = data
+            entry = TensorEntry(
+                location=name, serializer="buffer_protocol", dtype="uint8", shape=[2 * page],
+                replicated=False, checksum=integrity.digest(data),
+            )  # fmt: skip
+            reqs, _fut = ArrayIOPreparer.prepare_read(entry, on_a_chip, h2d_batch=batcher)
+            group += reqs
+            wanted[name] = data
+        groups.append(group)
+    parked = []
+
+    def land(item):
+        """The H2D side, by hand: the stamps so far are all made and in
+        order, then ``sent``, ``put`` and the give."""
+        host, _like, _fut, lease = item
+        assert host.tobytes() in wanted.values()
+        turn = pool.turn_of(lease)
+        stamps = [getattr(turn, stamp) for stamp in array_mod._STAMPS[:5]]
+        if stamps[3] is None:  # consume_began: only a parked read's
+            del stamps[3]
+        else:
+            parked.append(lease)
+        assert None not in stamps
+        assert [turn.granted, *stamps] == sorted([turn.granted, *stamps])
+        turn.sent = turn.put = array_mod._now()
+        pool.give(lease, recycle=True)
+
+    before = phase_stats.snapshot()
+    pipeline = scheduler_mod.ReadAhead(groups, storage, 1 << 30, rank=0)
+    try:
+        pipeline.wait_consumed(0)
+        # the arena is group 0's two leaves: c, dispatched once they are back,
+        # waits for room until one has landed
+        a, b = batcher.submitted
+        land(a)
+        deadline = time.monotonic() + 5.0
+        while time.monotonic() < deadline:
+            turns = list(pool._lent.values())
+            if len(turns) == 2 and all(turn.read_back is not None for turn in turns):
+                break
+            time.sleep(0.005)
+        time.sleep(0.05)
+        assert len(batcher.submitted) == 2  # c is back and parked, not consumed
+        pipeline.mark_loaded(0)
+        pipeline.wait_consumed(1)
+        pipeline.mark_loaded(1)
+    finally:
+        pipeline.close()
+    loop = phase_stats.delta(before)["read_loop"]
+    assert (loop["inline"], loop["handed"], loop["taken"]) == (3, 0, 3)
+    land(b)
+    land(batcher.submitted[2])
+    assert len(parked) == 1
+    stats = pool.turn_stats()
+    assert stats["ranges"] == 3 and stats["dropped"] == 0 and stats["bytes"] == 6 * page
+    assert stats["turn_bs"] == sum(stats[stage + "_bs"] for stage in array_mod._STAGES)
+    assert stats["parked_s"] > 0 and stats["consume_s"] > 0 and stats["read_s"] > 0
+    assert stats["grant_s"] > 0  # c's range was fitted by a's give, adopted by the loop
+    pool.close()

@@ -502,3 +502,276 @@ def test_progress_table_fires_while_budget_blocked_on_hung_storage():
     finally:
         sched_logger.removeHandler(handler)
         sched_logger.setLevel(prior_level)
+
+
+# ------------------------------------- the read pipeline's loop, leaf by leaf
+#
+# What the loop thread does for one read does not depend on how many reads are
+# pending (one done-callback a task, one event), and a read that landed in
+# place with its digest in hand is consumed in the turn it is taken off: no
+# task, no executor (the counter ``read_loop``).
+
+
+class _LandingStorage(MemoryStoragePlugin):
+    """The memory plug-in doing what the native fs plug-in does: a read that
+    brings a view lands in it and hands that view back, and (``fuse``) the
+    digest the request asks for comes with it.  Reads of ``held`` paths wait
+    for ``gate``; ``reads`` counts the reads asked for."""
+
+    def __init__(self, root, fuse=True, held=()):
+        super().__init__(root)
+        self.fuse, self.held, self.reads = fuse, set(held), 0
+        self.gate = None
+
+    async def read(self, read_io):
+        from torchsnapshot_tpu import integrity
+
+        self.reads += 1
+        if read_io.path in self.held:
+            if self.gate is None:
+                self.gate = asyncio.Event()
+            await self.gate.wait()
+        await super().read(read_io)
+        if read_io.into is not None:
+            read_io.into[:] = read_io.buf
+            read_io.buf = read_io.into
+        if self.fuse and read_io.want_hash:
+            read_io.hash64 = integrity._hash64(read_io.buf, read_io.hash_algo)
+
+
+class _SpyExecutor:
+    """In place of the pipeline's executor: counts what is sent to it."""
+
+    submits = 0
+
+    def __init__(self, max_workers=None):
+        from concurrent.futures import ThreadPoolExecutor
+
+        self._inner = ThreadPoolExecutor(max_workers=max_workers)
+
+    def submit(self, fn, /, *args, **kwargs):
+        type(self).submits += 1
+        return self._inner.submit(fn, *args, **kwargs)
+
+    def shutdown(self, *args, **kwargs):
+        self._inner.shutdown(*args, **kwargs)
+
+
+@pytest.fixture
+def spy_executor(monkeypatch):
+    from torchsnapshot_tpu import scheduler as sched_mod
+
+    _SpyExecutor.submits = 0
+    monkeypatch.setattr(sched_mod, "_PhaseInheritingExecutor", _SpyExecutor)
+    return _SpyExecutor
+
+
+_LEAF = 1 << 20  # from this size a read lands in place (array._INTO_PLACE_MIN_BYTES)
+
+
+def _leaf_reads(storage, names, nbytes=_LEAF, corrupt=(), checksum=True):
+    """One leaf a name in ``storage``, and the plan that restores each into a
+    fresh host array: ``(read_reqs, {name: (future, bytes)})``."""
+    import numpy as np
+
+    from torchsnapshot_tpu import integrity
+    from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer
+    from torchsnapshot_tpu.manifest import TensorEntry
+
+    read_reqs, want = [], {}
+    for k, name in enumerate(names):
+        data = np.random.RandomState(k).bytes(nbytes)
+        stored = bytearray(data)
+        if name in corrupt:
+            stored[nbytes // 2] ^= 0x01
+        storage._files[name] = bytes(stored)
+        entry = TensorEntry(
+            location=name, serializer="buffer_protocol", dtype="uint8", shape=[nbytes],
+            replicated=False, checksum=integrity.digest(data) if checksum else None,
+        )  # fmt: skip
+        reqs, fut = ArrayIOPreparer.prepare_read(entry)
+        read_reqs += reqs
+        want[name] = (fut, data)
+    return read_reqs, want
+
+
+def _read_loop_of(run):
+    from torchsnapshot_tpu import phase_stats
+
+    before = phase_stats.snapshot()
+    run()
+    return phase_stats.delta(before).get("read_loop")
+
+
+def test_a_pending_read_is_registered_once_however_many_turns_pass():
+    """1,000 reads held behind a gate while 24 others come back one by one
+    (each only once the one before it is consumed, so each in a turn of its
+    own): the pipeline registers one done-callback a task, when it makes it,
+    and a turn registers nothing on a task that cannot finish (before, every
+    turn added and removed one on every pending task)."""
+    from torchsnapshot_tpu import knobs
+    from torchsnapshot_tpu.scheduler import execute_read_reqs
+
+    registrations = []
+
+    class _CountingTask(asyncio.Task):
+        def add_done_callback(self, fn, *, context=None):
+            if self.get_coro().__name__ != "execute_read_reqs":  # (the loop's own)
+                registrations.append(self)
+            super().add_done_callback(fn, context=context)
+
+    MemoryStoragePlugin.reset()
+    held = [f"held{i}" for i in range(1000)]
+    free = [f"free{i}" for i in range(24)]
+    sink: dict = {}
+
+    class _OneByOne(_LandingStorage):
+        async def read(self, read_io):
+            if read_io.path in free:
+                while len(sink) < free.index(read_io.path):
+                    await asyncio.sleep(0.001)
+            await super().read(read_io)
+
+    storage = _OneByOne("registered_once", held=held)
+    for name in held + free:
+        storage._files[name] = name.encode()
+
+    class _LastOpensTheGate(_CollectConsumer):
+        async def consume_buffer(self, buf, executor=None):
+            await super().consume_buffer(buf, executor)
+            if len(sink) == len(free):
+                storage.gate.set()  # every free read consumed: let the rest go
+
+    # smallest first: the held reads are dispatched first and wait, pending,
+    # through every turn the free ones take
+    read_reqs = [
+        ReadReq(path=n, buffer_consumer=_LastOpensTheGate(sink, n, cost=1)) for n in held
+    ] + [ReadReq(path=n, buffer_consumer=_LastOpensTheGate(sink, n, cost=2)) for n in free]
+    loop = asyncio.new_event_loop()
+    loop.set_task_factory(lambda loop, coro, **kw: _CountingTask(coro, loop=loop, **kw))
+    stats = {}
+    try:
+        with knobs.override_max_per_rank_io_concurrency(2000):
+            stats = _read_loop_of(
+                lambda: loop.run_until_complete(
+                    execute_read_reqs([read_reqs], storage, 1 << 30, 0)
+                )
+            )
+    finally:
+        loop.close()
+    assert len(sink) == 1024
+    tasks = 2 * 1024  # a read and a consume each
+    assert len(registrations) == tasks == len(set(registrations))
+    assert stats["taken"] == tasks and stats["handed"] == 1024 and stats["inline"] == 0
+    assert stats["max_pending"] >= 1000
+    # each free read was taken off and consumed in turns of its own while
+    # the thousand waited
+    assert stats["turns"] >= 2 * len(free)
+
+
+def test_a_read_landed_in_place_with_its_digest_never_reaches_the_executor(spy_executor):
+    MemoryStoragePlugin.reset()
+    storage = _LandingStorage("inline")
+    read_reqs, want = _leaf_reads(storage, [f"leaf{i}" for i in range(6)])
+    stats = _read_loop_of(lambda: sync_execute_read_reqs(read_reqs, storage, 1 << 30, 0))
+    assert spy_executor.submits == 0
+    assert stats["inline"] == 6 and stats["handed"] == 0
+    assert stats["taken"] == 6 and stats["n"] == 1 and 1 <= stats["turns"] <= 6
+    for fut, data in want.values():
+        assert fut.obj.tobytes() == data
+    # no checksum on the entry, or checksums off: nothing to check, inline too
+    read_reqs, want = _leaf_reads(storage, ["bare0", "bare1"], checksum=False)
+    stats = _read_loop_of(lambda: sync_execute_read_reqs(read_reqs, storage, 1 << 30, 0))
+    assert (stats["inline"], stats["handed"], spy_executor.submits) == (2, 0, 0)
+    from torchsnapshot_tpu import knobs
+
+    unfused = _LandingStorage("inline", fuse=False)
+    read_reqs, want = _leaf_reads(unfused, ["off0"], corrupt=["off0"])
+    with knobs.override_env(knobs.CHECKSUM_ENV_VAR, "0"):
+        stats = _read_loop_of(lambda: sync_execute_read_reqs(read_reqs, unfused, 1 << 30, 0))
+    assert (stats["inline"], stats["handed"], spy_executor.submits) == (1, 0, 0)
+
+
+def test_a_wrong_fused_digest_fails_inline_and_leaves_the_budget_whole(
+    monkeypatch, spy_executor, caplog
+):
+    import logging
+
+    from torchsnapshot_tpu.integrity import ChecksumError
+
+    MemoryStoragePlugin.reset()
+    names = [f"leaf{i}" for i in range(8)]
+    # the corrupt leaf comes back while four others are still being read
+    storage = _LandingStorage("inline_corrupt", held=names[4:])
+    read_reqs, want = _leaf_reads(storage, names, corrupt=["leaf2"])
+    budgets = _install_budget_probe(monkeypatch)
+    with caplog.at_level(logging.ERROR, logger="asyncio"):
+        with pytest.raises(ChecksumError, match="Checksum mismatch for leaf2"):
+            sync_execute_read_reqs(read_reqs, storage, 1 << 30, 0)
+    assert spy_executor.submits == 0  # found on the loop thread, by a compare
+    assert storage.reads == 8  # all dispatched: the held four were cancelled
+    assert not any("Task was destroyed" in r.message for r in caplog.records)
+    assert not any("never retrieved" in r.message for r in caplog.records)
+    (budget,) = budgets
+    assert budget.remaining == budget.initial, "budget not fully re-credited"
+    assert budget.inflight == 0
+    assert want["leaf2"][0].obj is None  # never handed on
+
+
+@pytest.mark.parametrize("case", ["no_fused_digest", "framed", "merged_slab"])
+def test_a_read_with_work_left_is_handed_on_and_verified_as_before(case, spy_executor):
+    """The short path is taken read by read from what the read shows: a
+    checksum with no digest fused into the read is hashed on the executor, a
+    framed payload decoded there, a merged slab read gathers its members."""
+    import numpy as np
+
+    from torchsnapshot_tpu import batcher, compression, integrity
+    from torchsnapshot_tpu.integrity import ChecksumError
+    from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer
+    from torchsnapshot_tpu.manifest import TensorEntry
+
+    def plan(corrupt):
+        MemoryStoragePlugin.reset()
+        storage = _LandingStorage(f"handed_{case}", fuse=case != "no_fused_digest")
+        if case == "no_fused_digest":
+            return (storage, *_leaf_reads(storage, ["leaf"], 2 * _LEAF, corrupt=["leaf"] * corrupt))
+        if case == "framed":
+            data = bytes(range(256)) * (8 * _LEAF // 256)
+            frame, codec = compression.encode(data, "zlib")
+            frame = bytearray(frame)
+            entry = TensorEntry(
+                location="leaf", serializer="buffer_protocol", dtype="uint8", shape=[len(data)],
+                replicated=False, checksum=integrity.digest(bytes(frame)), codec=codec,
+                compressed_nbytes=len(frame),
+            )  # fmt: skip
+            frame[len(frame) // 2] ^= corrupt
+            storage._files["leaf"] = bytes(frame)
+            reqs, fut = ArrayIOPreparer.prepare_read(entry)
+            return storage, reqs, {"leaf": (fut, data)}
+        members = [np.random.RandomState(k).bytes(1000) for k in range(3)]
+        slab = bytearray(b"".join(members))
+        reqs, want = [], {}
+        for k, data in enumerate(members):
+            entry = TensorEntry(
+                location="batched/slab", serializer="buffer_protocol", dtype="uint8",
+                shape=[1000], replicated=False, byte_range=[1000 * k, 1000 * (k + 1)],
+                checksum=integrity.digest(data),
+            )  # fmt: skip
+            member_reqs, fut = ArrayIOPreparer.prepare_read(entry)
+            reqs += member_reqs
+            want[k] = (fut, data)
+        slab[1500] ^= corrupt
+        storage._files["batched/slab"] = bytes(slab)
+        return storage, batcher.batch_read_requests(reqs), want
+
+    storage, read_reqs, want = plan(corrupt=0)
+    assert len(read_reqs) == 1
+    stats = _read_loop_of(lambda: sync_execute_read_reqs(read_reqs, storage, 1 << 30, 0))
+    assert (stats["inline"], stats["handed"], stats["taken"]) == (0, 1, 2)
+    for fut, data in want.values():
+        assert fut.obj.tobytes() == data
+    if case != "merged_slab":  # (its members are under the executor's megabyte)
+        assert spy_executor.submits == 1
+    storage, read_reqs, _ = plan(corrupt=1)
+    with pytest.raises(ChecksumError, match="Checksum mismatch for"):
+        sync_execute_read_reqs(read_reqs, storage, 1 << 30, 0)

@@ -1650,6 +1650,34 @@ class ArrayBufferConsumer(BufferConsumer):
 
         self.hash_algo = integrity.hash_algo_of(checksum)
 
+    def consume_landed(self, buf: BufferType) -> bool:
+        """The short way, for the read pipeline's loop thread when it takes
+        a finished read off: if nothing is left to compute, finish here and
+        say True.  Nothing is left when ``buf`` is the view the read was
+        given to land in (so no codec: a framed payload has no place), and
+        the digest came with the read (``precomputed_hash64``: what is left
+        of ``integrity.verify`` is a string compare, and a mismatch raises
+        ``ChecksumError`` with the location as ever) or there is none to
+        check (the entry has no known checksum, or checksums are off).
+        Else False, and nothing was done: ``consume_buffer`` hashes, decodes
+        and copies on the executor."""
+        from .. import integrity
+
+        if self._into is None or not self._into.holds(buf) or self._codec is not None:
+            return False
+        if (
+            self.precomputed_hash64 is None
+            and self.hash_algo is not None
+            and integrity.checksums_enabled()
+        ):
+            return False  # a whole pass over the bytes: the executor's
+        integrity.verify(
+            buf, self._checksum, self._location, precomputed=self.precomputed_hash64
+        )
+        self._into.release()
+        self._assembly.piece_done()
+        return True
+
     async def consume_buffer(
         self, buf: BufferType, executor: Optional[Executor] = None
     ) -> None:

@@ -123,6 +123,12 @@ class _ChunkedAssembly(ArrayAssembly):
 
 
 class _ChunkConsumer(ArrayBufferConsumer):
+    def consume_landed(self, buf: Any) -> bool:
+        # The chunk has arrived either way (``consume_buffer`` comes next if
+        # this says False, and a second call opens nothing).
+        self._assembly.chunk_arrived()
+        return super().consume_landed(buf)
+
     async def consume_buffer(self, buf: Any, executor: Optional[Any] = None) -> None:
         self._assembly.chunk_arrived()
         await super().consume_buffer(buf, executor)

@@ -332,7 +332,12 @@ def add_counter(name: str, seconds: float, nbytes: int = 0, **more: int) -> None
     storage read and an H2D dispatch or landing were both under way, and
     ``reads_s=``, ``h2d_s=``, ``neither_s=``, snapshot._restore_overlap;
     ``h2d_land_slow``: one occurrence a landing that stalled, its seconds
-    and bytes, io_preparers/array._note_slow_landing).  The
+    and bytes, io_preparers/array._note_slow_landing; ``read_loop``: one
+    occurrence a read pipeline, ``turns=`` of its loop, completions
+    ``taken=`` off, reads consumed ``inline=`` on the loop thread, consumes
+    ``handed=`` on as a task, ``max_pending=`` the most tasks alive at once
+    (summed like the rest: over ``n`` it is the mean of the maxima),
+    scheduler.execute_read_reqs).  The
     entry has ``s``, ``bytes``, ``n`` and whatever ``more`` names, and no
     ``wall``, and reaches neither hook, so it can name no gap of a trace;
     ``delta()`` differences it like any other."""

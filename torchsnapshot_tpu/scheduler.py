@@ -47,7 +47,7 @@ import threading
 import time
 from collections import deque
 from concurrent.futures import Executor, ThreadPoolExecutor
-from typing import Awaitable, Callable, List, Optional, Sequence, Tuple
+from typing import Awaitable, Callable, Dict, List, Optional, Sequence, Tuple
 
 import psutil
 
@@ -58,6 +58,7 @@ from .telemetry import metrics as tmetrics
 from .telemetry import monitor as tmonitor
 from .telemetry import trace as ttrace
 from .io_types import (
+    BufferConsumer,
     ReadIO,
     ReadReq,
     ScatterBuffer,
@@ -864,8 +865,7 @@ class _ReadPipeline:
         self.into = None
         return self
 
-    async def consume_buffer(self, executor: Optional[Executor]) -> "_ReadPipeline":
-        assert self.buf is not None
+    def _hand_digest(self) -> BufferConsumer:
         consumer = self.read_req.buffer_consumer
         if self.hash64 is not None and getattr(consumer, "accepts_hash64", False):
             # The plugin hashed exactly the bytes of this request fused with
@@ -873,7 +873,26 @@ class _ReadPipeline:
             # against it without a second pass.  Composite consumers (merged
             # spanning reads) never opt in — their sub-payloads are slices.
             consumer.precomputed_hash64 = self.hash64
-        await consumer.consume_buffer(self.buf, executor)
+        return consumer
+
+    def consume_landed(self) -> bool:
+        """Consume the read here and now, on the calling (the loop's) thread,
+        if its consumer has a ``consume_landed`` and that says nothing is
+        left to compute (the bytes landed in the view the read was given and
+        the digest came with the read, or there is none to check): True, and
+        the consumer is done, its leaf submitted if this was its last piece.
+        False, and nothing was done: ``consume_buffer`` is the way.  A digest
+        that does not match raises ``ChecksumError`` from here."""
+        assert self.buf is not None
+        landed = getattr(self._hand_digest(), "consume_landed", None)
+        if landed is None or not landed(self.buf):
+            return False
+        self.buf = None
+        return True
+
+    async def consume_buffer(self, executor: Optional[Executor]) -> "_ReadPipeline":
+        assert self.buf is not None
+        await self._hand_digest().consume_buffer(self.buf, executor)
         self.buf = None
         return self
 
@@ -893,7 +912,8 @@ class ReadAhead:
     flight and joins the thread.
 
     After ``close()``, ``read_ahead_s`` and ``read_ahead_bytes`` say how
-    much was read ahead: see ``_read_ahead``."""
+    much was read ahead (see ``_read_ahead``) and ``read_loop`` what the
+    pipeline's loop did (the counter of that name, ``execute_read_reqs``)."""
 
     def __init__(
         self,
@@ -916,6 +936,7 @@ class ReadAhead:
         self._wake: Optional[asyncio.Event] = None
         self.read_ahead_s = 0.0
         self.read_ahead_bytes = 0
+        self.read_loop: Dict[str, int] = {}
         # The coroutine, not the groups, goes to the thread: it drops its
         # own reference to the requests once they are queued, so nothing
         # here keeps a loaded group's host buffers alive.
@@ -1073,6 +1094,28 @@ async def execute_read_reqs(
       ``consume_began`` when a read parked behind the loader is let go; the
       pool accounts the stages between (the counter ``arena_turn``).
 
+    **What a turn of the loop costs.**  Each read and each consume that is a
+    task registers its completion once, when it is made (a done-callback that
+    queues the task and sets the one event, the same that the loader's
+    ``mark_loaded`` and the reporter's interval set); a turn awaits that
+    event and takes the queued completions off in the order they came, so
+    what the loop does for one read does not grow with the reads pending
+    (``io_tasks``, ``consume_tasks`` and ``pipelines`` are the record of what
+    is live, for the failure path).  **A read with nothing left to compute
+    is consumed in the turn it is taken off**, or in the turn that lets it go
+    if it was parked: where the consumer's ``consume_landed`` says the bytes
+    landed in the view the read was given and the digest came with the read
+    or none is to be checked (``io_preparers/array.ArrayBufferConsumer``),
+    the digests are compared, the leaf's piece counted and, if it was the
+    last, the leaf submitted, all on this thread: no task, no executor.
+    Everything else (a checksum to hash, a frame to decode, a merged slab
+    read, objects, sharded pieces) is consumed as a task as before.  The
+    counter ``read_loop`` (and ``ReadAhead.read_loop``, the ``restore.end``
+    entry) says what the loop did: ``turns``, completions ``taken`` off,
+    reads consumed ``inline``, consumes ``handed`` on as a task (``inline +
+    handed`` is the requests), the most tasks alive at once
+    (``max_pending``), ``n`` pipelines.
+
     With no loader a group counts as loaded once it is consumed.  An error
     in any read or consume cancels everything in flight and is raised."""
     all_reqs = [rr for group in read_groups for rr in group]
@@ -1109,6 +1152,8 @@ async def execute_read_reqs(
     consume_tasks: set = set()
     # task -> pipeline, for re-crediting un-consumed pipelines on failure
     pipelines: dict = {}
+    # The counter ``read_loop``: what this loop did, for every request.
+    loop_stats = {"turns": 0, "taken": 0, "inline": 0, "handed": 0, "max_pending": 0}
     reporter = _ProgressReporter(
         rank=rank, total=n_reqs, verb="read", budget=budget
     )
@@ -1194,6 +1239,22 @@ async def execute_read_reqs(
                 return None
         return None
 
+    # Completions, in the order they came: each task registers ``finished``
+    # once, when it is made, and the main loop waits for the one event (the
+    # loader's ``mark_loaded`` sets the same one).
+    finished_tasks: "deque[asyncio.Task]" = deque()
+    wake = loader._attach() if loader is not None else asyncio.Event()
+
+    def finished(task: "asyncio.Task") -> None:
+        finished_tasks.append(task)
+        wake.set()
+
+    def start(coro: Awaitable[_ReadPipeline], tasks: set, pipeline: _ReadPipeline) -> None:
+        task = asyncio.ensure_future(coro)
+        task.add_done_callback(finished)
+        tasks.add(task)
+        pipelines[task] = pipeline
+
     def dispatch_io() -> None:
         while (queue := next_for_io()) is not None:
             pipeline = queue[0]
@@ -1203,34 +1264,62 @@ async def execute_read_reqs(
                 queue.popleft()
                 budget.remaining -= pipeline.consuming_cost
                 budget.inflight += 1
-                task = asyncio.ensure_future(_read(pipeline))
-                io_tasks.add(task)
-                pipelines[task] = pipeline
+                start(_read(pipeline), io_tasks, pipeline)
             else:
                 break
+        loop_stats["max_pending"] = max(
+            loop_stats["max_pending"], len(io_tasks) + len(consume_tasks)
+        )
+
+    def consumed_one(pipeline: _ReadPipeline) -> None:
+        budget.remaining += pipeline.consuming_cost
+        budget.inflight -= 1
+        unconsumed[pipeline.group] -= 1
+        reporter.io_done += 1
+        reporter.bytes_done += pipeline.consuming_cost
+        tmetrics.record_io_bytes("read", pipeline.consuming_cost)
 
     def consume(pipeline: _ReadPipeline) -> None:
-        task = asyncio.ensure_future(pipeline.consume_buffer(executor))
-        consume_tasks.add(task)
-        pipelines[task] = pipeline
+        """Here and now if the read left nothing to compute (landed in
+        place, its digest in hand or none wanted: no task, no executor), as
+        a task otherwise."""
+        try:
+            landed = pipeline.consume_landed()
+        except BaseException:
+            # In no container the failure path knows: re-credited here.
+            pipeline.buf = None
+            budget.remaining += pipeline.consuming_cost
+            budget.inflight -= 1
+            raise
+        if landed:
+            loop_stats["inline"] += 1
+            consumed_one(pipeline)
+        else:
+            loop_stats["handed"] += 1
+            start(pipeline.consume_buffer(executor), consume_tasks, pipeline)
 
     def note_progress() -> None:
         """Groups consumed are told to the loader; groups it has loaded
         let the parked reads of the next one be consumed."""
         nonlocal consumed, loaded
-        told = consumed
-        while consumed < n_groups and unconsumed[consumed] == 0:
-            consumed += 1
-        if loader is None:
-            loaded = consumed
-        else:
-            if consumed != told:
-                loader._group_consumed(consumed)
-            loaded = len(loader._loaded())
-        for pipeline in [p for p in parked if p.group <= loaded]:
-            parked.remove(pipeline)
-            pipeline.stamp("consume_began")  # was parked from read_back to here
-            consume(pipeline)
+        while True:
+            told, let_go = consumed, loaded
+            while consumed < n_groups and unconsumed[consumed] == 0:
+                consumed += 1
+            if loader is None:
+                loaded = consumed
+            else:
+                if consumed != told:
+                    loader._group_consumed(consumed)
+                loaded = len(loader._loaded())
+            if loaded == let_go or not parked:
+                return
+            # Only a group newly loaded lets a parked read go, and a read
+            # consumed here and now may have been its group's last: again.
+            for pipeline in [p for p in parked if p.group <= loaded]:
+                parked.remove(pipeline)
+                pipeline.stamp("consume_began")  # was parked from read_back to here
+                consume(pipeline)
 
     # read_starved: one interval per stretch in which the pipeline is alive
     # (a consume is pending: running, or parked behind the loader) and no
@@ -1252,14 +1341,21 @@ async def execute_read_reqs(
         "read_pipeline", cat="scheduler", n_reqs=n_reqs, n_groups=n_groups
     )
     read_span.__enter__()
-    wake = loader._attach() if loader is not None else None
-    wake_task: Optional["asyncio.Task"] = None
+    # The reporter's interval: the event is set at least that often.
+    ticker: Optional[asyncio.TimerHandle] = None
+    call_later = asyncio.get_running_loop().call_later
+
+    def tick() -> None:
+        nonlocal ticker
+        wake.set()
+        ticker = call_later(reporter._interval_s, tick)
+
     try:
+        if reporter._interval_s:
+            ticker = call_later(reporter._interval_s, tick)
         note_progress()  # leading groups with nothing to read
         dispatch_io()
         while consumed < n_groups:
-            if wake is not None and wake_task is None:
-                wake_task = asyncio.ensure_future(wake.wait())
             # Mirror of the write path's budget_wait attribution: the
             # consuming budget is binding only when the queue head is
             # inadmissible WHILE read slots sit idle — a head queued
@@ -1268,21 +1364,15 @@ async def execute_read_reqs(
             blocked = (
                 phase_stats.open_interval("budget_wait") if budget_bound else None
             )
-            waiting = io_tasks | consume_tasks
-            if wake_task is not None:
-                waiting.add(wake_task)
-            done, _ = await asyncio.wait(
-                waiting,
-                timeout=reporter._interval_s or None,
-                return_when=asyncio.FIRST_COMPLETED,
-            )
+            await wake.wait()
+            wake.clear()
             if blocked is not None:
                 blocked.close()
-            for task in done:
-                if task is wake_task:
-                    wake.clear()
-                    wake_task = None
-                elif task in io_tasks:
+            loop_stats["turns"] += 1
+            while finished_tasks:
+                task = finished_tasks.popleft()
+                loop_stats["taken"] += 1
+                if task in io_tasks:
                     io_tasks.discard(task)
                     pipeline = task.result()  # raises on storage failure
                     pipelines.pop(task)
@@ -1298,15 +1388,10 @@ async def execute_read_reqs(
                     consume_tasks.discard(task)
                     pipeline = task.result()  # raises on consume failure
                     pipelines.pop(task)
-                    budget.remaining += pipeline.consuming_cost
-                    budget.inflight -= 1
-                    unconsumed[pipeline.group] -= 1
-                    reporter.io_done += 1
-                    reporter.bytes_done += pipeline.consuming_cost
-                    tmetrics.record_io_bytes("read", pipeline.consuming_cost)
+                    consumed_one(pipeline)
             # No local of this frame keeps a consumed request (and, of a
             # leaf not uploaded through a pool, its host buffer) past the turn.
-            done = waiting = task = pipeline = None
+            task = pipeline = None
             note_progress()
             dispatch_io()
             track_starved()
@@ -1316,7 +1401,9 @@ async def execute_read_reqs(
                 staging=len(io_tasks),
                 inflight_io=len(consume_tasks) + len(parked),
             )
+        phase_stats.add_counter("read_loop", 0.0, 0, **loop_stats)
         if loader is not None:
+            loader.read_loop = loop_stats
             loader.read_ahead_s, loader.read_ahead_bytes = _read_ahead(
                 reads, loader._loaded()
             )
@@ -1342,8 +1429,8 @@ async def execute_read_reqs(
             budget.inflight -= 1
         raise
     finally:
-        if wake_task is not None:
-            wake_task.cancel()
+        if ticker is not None:
+            ticker.cancel()
         executor.shutdown()
         # Success or error, the read pipeline is over: zero its gauges.
         tmetrics.record_scheduler_idle("read")

@@ -620,7 +620,12 @@ class Snapshot:
         which storage reads and H2D were both under way, each one's wall and
         what neither covers: ``_restore_overlap``, over the intervals the
         call holds) and ``h2d_land_slow`` (a landing that stalled, counted
-        and logged where it happens) are each an entry of ``restore.end``.
+        and logged where it happens) are each an entry of ``restore.end``,
+        and so is ``read_loop``: what the read pipeline's loop thread did
+        for the call's requests (``turns``, completions ``taken`` off, reads
+        consumed ``inline`` in the turn they were taken off, consumes
+        ``handed`` on as a task, ``max_pending`` tasks alive at once;
+        ``scheduler.execute_read_reqs`` records the counter of that name).
 
         On-device contract: dense and chunked array uploads are drained
         before return (H2DBatcher.drain — their bytes are ON DEVICE, with
@@ -814,6 +819,7 @@ class Snapshot:
                 "s": slow.get("s", 0.0),
                 "n": int(slow.get("n", 0)),
             }
+            event_metadata["read_loop"] = dict(pipeline.read_loop)
             event_metadata["read_ahead_s"] = pipeline.read_ahead_s
             event_metadata["read_ahead_bytes"] = pipeline.read_ahead_bytes
             event_metadata["host_pool"] = pooled
