@@ -171,24 +171,38 @@ def test_the_stages_add_up_to_the_turn_and_no_byte_is_lent_twice(clock):
     assert reader("arena_h2d_side_pct.resume")(run) == 100.0 * h2d / stats["turn_bs"]
 
 
-def test_a_waiters_grant_is_over_zero_and_a_direct_takes_is_zero(clock):
+@pytest.mark.parametrize("waiter", ["future", "callback"])
+def test_a_waiters_grant_is_over_zero_and_a_direct_takes_is_zero(clock, waiter):
     """A take that finds room is adopted in the same breath; one that waits
     is fitted by the lander's give and adopted when the pipeline's loop gets
-    to it: ``grant`` is what lies between."""
+    to it: ``grant`` is what lies between.  The waiter is a future of the
+    loop, or (the read pipeline's slot hand-over) a callback the give hands
+    the loop, one handle, with the range."""
     pool, _batcher = pool_of([2, 2], [2, 2], window=2)
 
     async def pipeline():
         loop = asyncio.get_running_loop()
         direct = pool.take(2 * PAGE, loop)
         pool.turn_of(direct).adopted = clock()
-        coming = pool.take(2 * PAGE, loop)
-        assert isinstance(coming, asyncio.Future)
+        granted = []
+        if waiter == "future":
+            coming = pool.take(2 * PAGE, loop)
+            assert isinstance(coming, asyncio.Future)
+        else:
+            coming = loop.create_future()
+
+            def grant(lease):
+                granted.append(threading.current_thread())
+                coming.set_result(lease)
+
+            assert pool.take(2 * PAGE, loop, granted=grant) is None
         clock.tick(1.0)  # (the waiter's record is not made yet: nothing is lent)
         lander = threading.Thread(target=pool.give, args=(direct, True))
         lander.start()
         lander.join(5)
         clock.tick(0.5)  # fitted by the give, and the loop busy elsewhere
         lease = await asyncio.wait_for(coming, 5)
+        assert granted == ([] if waiter == "future" else [threading.current_thread()])
         pool.turn_of(lease).adopted = clock()
         clock.tick(0.25)
         pool.give(lease, recycle=True)
@@ -479,6 +493,19 @@ def test_a_restore_with_no_arena_records_zeros_and_the_arena_readers_say_nothing
     run = run_of(delta)
     for name in ARENA_READERS:
         assert reader(name)(run) is None, name
+
+
+def test_an_fs_restore_runs_every_read_on_the_slot_hand_over_and_says_so(tmp_path):
+    """The fs plug-in reads blocking: every request of the restore is read
+    by the slot hand-over (``read_loop``'s ``direct``), on the plug-in's
+    workers, each of its wake-ups posted for one finished read or more."""
+    delta, end = restore_with_its_account(tmp_path)
+    loop = end["read_loop"]
+    requests = loop["inline"] + loop["handed"]
+    assert requests == 4 and loop["direct"] == requests
+    assert 1 <= loop["wakes"] <= requests and loop["taken"] == requests + loop["handed"]
+    counter = delta["read_loop"]
+    assert counter["n"] == 1 and {k: counter[k] for k in loop} == loop
 
 
 def test_every_restore_records_its_overlap_and_the_event_agrees_with_its_phases(tmp_path):

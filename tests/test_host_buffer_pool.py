@@ -5,7 +5,8 @@ whichever read comes next, of any size and any stateful, which waits for room
 where there is none and makes the batchers flush; the arena is populated in
 bulk, once, before its first range is handed out.  Restores of train-state-
 shaped trees (three statefuls, one tree) through the fs plug-in, the reads one
-at a time so that each has its number; then the pool alone."""
+at a time so that each has its number, as tasks and on the slot hand-over (the
+plug-in's blocking entry forwarded); then the pool alone."""
 
 import asyncio
 import functools
@@ -95,21 +96,28 @@ class Faulty(StoragePlugin):
         self._inner, self._world = inner, world
 
     async def read(self, read_io):
-        world = self._world
         if read_io.into is None:
             return await self._inner.read(read_io)
+        kept = self._numbered(read_io)
+        await self._inner.read(read_io)
+        self._tail(read_io, kept)
+
+    def _numbered(self, read_io):
+        """The read into place counted; it raises if it is the one that
+        fails, and what the one that ``tails`` keeps of its destination."""
+        world = self._world
         m = len(world.reads)
         world.reads.append(read_io.path)
         if world.fails is not None and m == world.fails:
             raise ValueError(f"injected failure of read {m}")
-        kept = None
         if world.tails is not None and m == world.tails:
-            half = read_io.into.nbytes // 2
-            kept = bytes(read_io.into[half:])
-        await self._inner.read(read_io)
+            return bytes(read_io.into[read_io.into.nbytes // 2 :])
+        return None
+
+    def _tail(self, read_io, kept):
         if kept is not None:
             assert read_io.buf is read_io.into
-            read_io.into[half:] = kept
+            read_io.into[read_io.into.nbytes - len(kept) :] = kept
             read_io.hash64 = None
 
     async def write(self, write_io):
@@ -125,8 +133,24 @@ class Faulty(StoragePlugin):
         await self._inner.close()
 
 
-@pytest.fixture
-def world(monkeypatch, tmp_path):
+class BlockingFaulty(Faulty):
+    """``Faulty`` over the fs plug-in's blocking entry, which it forwards:
+    the read pipeline runs its reads on the slot hand-over, on the fs
+    plug-in's own workers."""
+
+    def read_executor(self):
+        return self._inner.read_executor()
+
+    def read_blocking(self, read_io):
+        if read_io.into is None:
+            return self._inner.read_blocking(read_io)
+        kept = self._numbered(read_io)
+        self._inner.read_blocking(read_io)
+        self._tail(read_io, kept)
+
+
+@pytest.fixture(params=["task", "hand_over"])
+def world(monkeypatch, tmp_path, request):
     """The CPU backend taken for an accelerator (``accelerator``: it keeps no
     host memory, and the arena begins where it copies), and every seam of the
     pool recorded: the pools made (weakly), the arenas and the plain buffers
@@ -151,6 +175,7 @@ def world(monkeypatch, tmp_path):
         tails=None,
         touches=[],
         populates=True,
+        hand_over=request.param == "hand_over",
     )
 
     class RecordedPool(HostBufferPool):
@@ -217,7 +242,9 @@ def world(monkeypatch, tmp_path):
     monkeypatch.setattr(
         snapshot_mod,
         "url_to_storage_plugin",
-        lambda path, options=None: Faulty(real_plugin(path, options), w),
+        lambda path, options=None: (Faulty if request.param == "task" else BlockingFaulty)(
+            real_plugin(path, options), w
+        ),
     )
     # (no retry of a read: the warning's exception, kept by the log capture,
     # would hold the pool through its traceback)
@@ -262,6 +289,9 @@ def restore(world, path, target, within_s=60.0):
         error = raised.pop()  # (a kept traceback would hold the pool)
         raise error.with_traceback(None)
     (end,) = ends
+    # every read on the slot hand-over where the plug-in reads blocking, none elsewhere
+    loop = end["read_loop"]
+    assert loop["direct"] == (loop["inline"] + loop["handed"] if world.hand_over else 0)
     return phase_stats.delta(before).get("host_pool"), end
 
 

@@ -488,8 +488,12 @@ def test_a_read_landed_in_place_ahead_of_the_load_is_parked_then_consumed_inline
     inline = [name for what, _, _, name in log.rows if what == "consume_inline"]
     assert len(inline) == len(KEYS) * LEAVES and set(inline) == {"tpusnap-read-pipeline"}
     loop = end["read_loop"]
-    assert sorted(loop) == ["handed", "inline", "max_pending", "taken", "turns"]
+    assert sorted(loop) == [
+        "direct", "handed", "inline", "max_pending", "taken", "turns", "wakes"
+    ]  # fmt: skip
     assert (loop["inline"], loop["handed"], loop["taken"]) == (len(KEYS) * LEAVES, 0, 12)
+    # a plug-in with no blocking read: every read a task, as before
+    assert loop["direct"] == loop["wakes"] == 0
     assert 1 <= loop["turns"] and 1 <= loop["max_pending"] <= LEAVES
     counter = delta["read_loop"]
     assert counter["n"] == 1 and {k: counter[k] for k in loop} == loop
@@ -772,18 +776,56 @@ def test_groups_complete_in_order_under_any_budget(budget):
     assert pipeline.read_ahead_s >= 0 and pipeline.read_ahead_bytes <= 1500
 
 
-def test_the_stamps_of_a_turn_come_in_order_on_the_short_path_and_add_up(monkeypatch):
+class BlockingLanding(MemoryStoragePlugin):
+    """The memory plug-in with the blocking entry the fs plug-in offers
+    (``read_blocking`` on ``read_executor``'s workers): the read pipeline
+    runs its reads on the slot hand-over, and each lands in place with its
+    digest.  ``read_s`` is how long a read takes."""
+
+    def __init__(self, root, read_s=0.0):
+        from concurrent.futures import ThreadPoolExecutor
+
+        super().__init__(root)
+        self.read_s = read_s
+        self.pool = ThreadPoolExecutor(max_workers=16, thread_name_prefix="blocking_io")
+
+    def read_executor(self):
+        return self.pool
+
+    def read_blocking(self, read_io):
+        time.sleep(self.read_s)
+        data = self._files[read_io.path]
+        if read_io.byte_range is not None:
+            data = data[read_io.byte_range[0] : read_io.byte_range[1]]
+        read_io.buf = bytearray(data)
+        land_in_place(read_io)
+
+
+@pytest.mark.parametrize("path", ["task", "hand_over"])
+def test_the_stamps_of_a_turn_come_in_order_on_the_short_path_and_add_up(monkeypatch, path):
     """A hand-driven pool behind the real pipeline, under a clock that moves
     one second a reading: two leaves of group 0 consumed inline in the turn
     they are taken off, one of group 1 parked until group 0 is loaded.  Every
     stamp is made, in the order of ``_STAMPS`` (``consume_began`` only for the
-    parked one), and the eight ``_bs`` are ``turn_bs``."""
+    parked one), and the eight ``_bs`` are ``turn_bs``.  On the slot
+    hand-over (a plug-in that reads blocking) the range is adopted on the
+    pipeline's thread, at the dispatch or in the pool's grant, and
+    ``read_began`` is stamped by the worker that starts the read: ``slot``
+    runs from the one to the other."""
     from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer, HostBufferPool
     from torchsnapshot_tpu.manifest import TensorEntry
 
     page = array_mod._PAGE
     ticks = iter(range(5000, 10**6))
     monkeypatch.setattr(array_mod, "_now", lambda: float(next(ticks)))
+    stamped = []  # (stamp, thread)
+    real_stamp = array_mod.ArrayAssembly.stamp
+
+    def recording_stamp(self, stamp):
+        stamped.append((stamp, threading.current_thread().name))
+        real_stamp(self, stamp)
+
+    monkeypatch.setattr(array_mod.ArrayAssembly, "stamp", recording_stamp)
     on_a_chip = types.SimpleNamespace(
         devices=lambda: [types.SimpleNamespace(platform="tpu")]
     )
@@ -808,7 +850,7 @@ def test_the_stamps_of_a_turn_come_in_order_on_the_short_path_and_add_up(monkeyp
             await super().read(read_io)
             land_in_place(read_io)
 
-    storage = Landing(root="turn_in_order")
+    storage = (Landing if path == "task" else BlockingLanding)(root="turn_in_order")
     groups, wanted = [], {}
     for names in (["a", "b"], ["c"]):
         pool.begin_group()
@@ -865,6 +907,18 @@ def test_the_stamps_of_a_turn_come_in_order_on_the_short_path_and_add_up(monkeyp
         pipeline.close()
     loop = phase_stats.delta(before)["read_loop"]
     assert (loop["inline"], loop["handed"], loop["taken"]) == (3, 0, 3)
+    assert loop["direct"] == (3 if path == "hand_over" else 0)
+    threads = {}
+    for stamp, thread in stamped:
+        threads.setdefault(stamp, set()).add(thread)
+    assert threads["adopted"] == threads["read_back"] == {"tpusnap-read-pipeline"}
+    if path == "hand_over":
+        assert threads["read_began"] and all(
+            name.startswith("blocking_io") for name in threads["read_began"]
+        )
+        storage.pool.shutdown()
+    else:
+        assert threads["read_began"] == {"tpusnap-read-pipeline"}
     land(b)
     land(batcher.submitted[2])
     assert len(parked) == 1
@@ -874,3 +928,31 @@ def test_the_stamps_of_a_turn_come_in_order_on_the_short_path_and_add_up(monkeyp
     assert stats["parked_s"] > 0 and stats["consume_s"] > 0 and stats["read_s"] > 0
     assert stats["grant_s"] > 0  # c's range was fitted by a's give, adopted by the loop
     pool.close()
+
+
+def test_on_the_slot_hand_over_a_read_that_waits_a_millisecond_records_io_slot_wait():
+    """One io slot and six reads of 20 ms each on the slot hand-over: every
+    read after the first waits for the slot, from its memory in hand to its
+    start on the worker that finished the one before, and each such wait is
+    an ``io_slot_wait`` of the worker's; with sixteen slots none waits a
+    millisecond and none is recorded."""
+    MemoryStoragePlugin.reset()
+    sink = []
+    payloads = {f"p{i}": bytes([i]) * 100 for i in range(6)}
+    for cap, read_s in ((1, 0.02), (16, 0.0)):
+        storage = BlockingLanding(root=f"slot_wait_{cap}", read_s=read_s)
+        storage._files.update(payloads)
+        reqs = [ReadReq(path=p, buffer_consumer=_Consumer(sink, p, 100)) for p in payloads]
+        before = phase_stats.snapshot()
+        with knobs.override_max_per_rank_io_concurrency(cap):
+            scheduler_mod.sync_execute_read_reqs(reqs, storage, 1 << 20, rank=0)
+        delta = phase_stats.delta(before)
+        assert delta["read_loop"]["direct"] == len(payloads)
+        waited = delta.get("io_slot_wait")
+        if cap == 1:
+            assert waited["n"] == len(payloads) - 1 and waited["s"] >= 0.02 * (len(payloads) - 1)
+            assert waited["wall"] > 0.02 * 4
+        else:
+            assert waited is None
+        storage.pool.shutdown()
+    assert len(sink) == 2 * len(payloads)

@@ -2,9 +2,12 @@
 scheduler.py:222-447)."""
 
 import asyncio
+import threading
+import time
 
 import pytest
 
+from torchsnapshot_tpu import phase_stats
 from torchsnapshot_tpu.io_types import (
     BufferConsumer,
     BufferStager,
@@ -775,3 +778,372 @@ def test_a_read_with_work_left_is_handed_on_and_verified_as_before(case, spy_exe
     storage, read_reqs, _ = plan(corrupt=1)
     with pytest.raises(ChecksumError, match="Checksum mismatch for"):
         sync_execute_read_reqs(read_reqs, storage, 1 << 30, 0)
+
+
+# ------------------------------------------------------ the slot hand-over
+#
+# A plug-in that reads blocking (``read_blocking`` on ``read_executor``'s
+# workers, as the fs plug-in offers) has its reads run by the slot hand-over
+# (``scheduler._DirectReads``): no read is a task, the workers hand the io
+# slots on themselves, and a finished read is posted with one coalesced
+# wake-up of the loop.
+
+
+class _BlockingStorage(MemoryStoragePlugin):
+    """The memory plug-in with the blocking entry the fs plug-in offers: a
+    read lands in the view it brings, with its digest, as fs does.  ``hook``
+    is called with the path as each read starts, on the worker (it may sleep
+    or raise); what ran when, and on which thread, is recorded."""
+
+    def __init__(self, root, hook=None, workers=16):
+        from concurrent.futures import ThreadPoolExecutor
+
+        super().__init__(root)
+        self.hook = hook
+        self.pool = ThreadPoolExecutor(max_workers=workers, thread_name_prefix="blocking_io")
+        self.lock = threading.Lock()
+        self.running = self.peak = 0
+        self.started, self.ended = [], []  # (path, thread name, monotonic)
+
+    def read_executor(self):
+        return self.pool
+
+    def read_blocking(self, read_io):
+        from torchsnapshot_tpu import integrity
+
+        me = threading.current_thread().name
+        with self.lock:
+            self.running += 1
+            self.peak = max(self.peak, self.running)
+            self.started.append((read_io.path, me, time.monotonic()))
+        try:
+            if self.hook is not None:
+                self.hook(read_io.path)
+            data = self._files[read_io.path]
+            if read_io.byte_range is not None:
+                data = data[read_io.byte_range[0] : read_io.byte_range[1]]
+            if read_io.into is not None:
+                read_io.into[:] = data
+                read_io.buf = read_io.into
+            else:
+                read_io.buf = bytearray(data)
+            if read_io.want_hash:
+                read_io.hash64 = integrity._hash64(read_io.buf, read_io.hash_algo)
+        finally:
+            with self.lock:
+                self.running -= 1
+                self.ended.append((read_io.path, me, time.monotonic()))
+
+
+def _groups_of(storage, sink, groups=3, per_group=8, nbytes=64):
+    """``groups`` groups of small payloads (consumed as tasks into ``sink``),
+    each group in the order it will be dispatched (its costs ascending)."""
+    out = []
+    for g in range(groups):
+        group = []
+        for i in range(per_group):
+            path = f"g{g}/p{i:02d}"
+            storage._files[path] = bytes([g * 16 + i]) * nbytes
+            group.append(ReadReq(path=path, buffer_consumer=_CollectConsumer(sink, path, cost=1 + i)))
+        out.append(group)
+    return out
+
+
+def _load_in_turn(pipeline, n_groups, load_s=0.03):
+    """The loader's side of a ``ReadAhead``: each group waited for, held a
+    while, and marked loaded; when each was."""
+    loaded_at = []
+    try:
+        for group in range(n_groups):
+            pipeline.wait_consumed(group)
+            time.sleep(load_s)
+            loaded_at.append(time.monotonic())
+            pipeline.mark_loaded(group)
+    finally:
+        pipeline.close()
+    return loaded_at
+
+
+def test_the_slot_hand_over_keeps_io_cap_the_order_and_the_look_ahead():
+    """Three groups of eight reads behind three io slots: never more than
+    three run at once; within a group the reads start in dispatch order (a
+    read can pass only those fewer than three places in front of it, which
+    started beside it); a group is read once the last read of the one before
+    it has finished, and not before the one before that is loaded."""
+    from torchsnapshot_tpu import knobs
+    from torchsnapshot_tpu.scheduler import ReadAhead
+
+    MemoryStoragePlugin.reset()
+    storage = _BlockingStorage("hand_over_order", hook=lambda path: time.sleep(0.005))
+    sink: dict = {}
+    groups = _groups_of(storage, sink)
+    cap = 3
+    before = phase_stats.snapshot()
+    with knobs.override_max_per_rank_io_concurrency(cap):
+        pipeline = ReadAhead(groups, storage, 1 << 20, rank=0)
+        loaded_at = _load_in_turn(pipeline, len(groups))
+    assert sink == {path: data for path, data in storage._files.items()}
+    assert 1 < storage.peak <= cap
+    start = {path: t for path, _, t in storage.started}
+    end = {path: t for path, _, t in storage.ended}
+    for g, group in enumerate(groups):
+        order = [rr.path for rr in group]
+        for i, early in enumerate(order):
+            for late in order[i + cap :]:
+                assert start[early] <= start[late], (early, late)
+        if g:
+            assert min(start[p] for p in order) >= max(end[rr.path] for rr in groups[g - 1])
+        if g >= 2:
+            assert min(start[p] for p in order) >= loaded_at[g - 2]
+    # every read ran on the plug-in's workers, and none was a task
+    assert {name for _, name, _ in storage.started} <= {
+        f"blocking_io_{k}" for k in range(16)
+    }
+    loop = pipeline.read_loop
+    reqs = sum(len(group) for group in groups)
+    assert loop["direct"] == reqs and loop["handed"] == reqs
+    assert loop["taken"] == 2 * reqs and 1 <= loop["wakes"] <= reqs
+    counter = phase_stats.delta(before)["read_loop"]
+    assert counter["direct"] == reqs and counter["wakes"] == loop["wakes"]
+    storage.pool.shutdown()
+
+
+def test_queued_reads_start_on_the_worker_while_the_loop_thread_is_held():
+    """One io slot and thirty reads of 10 ms: while the pipeline's loop
+    thread sleeps inside a callback for 0.3 s, the worker that finished a read
+    starts the next queued one itself, read after read, on the same thread."""
+    from torchsnapshot_tpu import knobs
+    from torchsnapshot_tpu.scheduler import ReadAhead
+
+    held, made = [], []
+
+    def hold():
+        held.append(time.monotonic())
+        time.sleep(0.3)
+        held.append(time.monotonic())
+
+    def hook(path):
+        if path == "g0/p00":  # the first read: the loop thread is held now
+            while not made:
+                time.sleep(0.001)
+            made[0]._loop.call_soon_threadsafe(hold)
+        time.sleep(0.01)
+
+    MemoryStoragePlugin.reset()
+    storage = _BlockingStorage("hand_over_held", hook=hook)
+    sink: dict = {}
+    groups = _groups_of(storage, sink, groups=1, per_group=30)
+    with knobs.override_max_per_rank_io_concurrency(1):
+        made.append(ReadAhead(groups, storage, 1 << 20, rank=0))
+        _load_in_turn(made[0], 1, load_s=0)
+    assert len(sink) == 30 and len(held) == 2
+    assert storage.peak == 1
+    assert len({name for _, name, _ in storage.started}) == 1  # one job: the slot handed on
+    during = [t for _, _, t in storage.started if held[0] < t < held[1]]
+    assert len(during) >= 10, (len(during), held)
+    # the reads that finished while the loop was held posted one wake-up
+    loop = made[0].read_loop
+    assert loop["direct"] == 30 and loop["wakes"] <= 30 - len(during) + 2
+    storage.pool.shutdown()
+
+
+def test_a_transient_read_failure_on_the_hand_over_is_retried_after_its_backoff(
+    monkeypatch, caplog
+):
+    import logging
+
+    from torchsnapshot_tpu import retry
+    from torchsnapshot_tpu.retry import StorageTransientError
+
+    failed = []
+
+    def hook(path):
+        if path == "g0/p03" and not failed:
+            failed.append(time.monotonic())
+            raise StorageTransientError("injected 503")
+
+    monkeypatch.setattr(retry, "backoff_s", lambda attempt, **kwargs: 0.1)
+    MemoryStoragePlugin.reset()
+    storage = _BlockingStorage("hand_over_retry", hook=hook)
+    sink: dict = {}
+    (group,) = _groups_of(storage, sink, groups=1)
+    events = []
+    from torchsnapshot_tpu.event_handlers import (
+        register_event_handler,
+        unregister_event_handler,
+    )
+
+    register_event_handler(events.append)
+    try:
+        with caplog.at_level(logging.WARNING, logger="torchsnapshot_tpu.scheduler"):
+            stats = _read_loop_of(lambda: sync_execute_read_reqs(group, storage, 1 << 20, 0))
+    finally:
+        unregister_event_handler(events.append)
+    assert sink == dict(storage._files)
+    tries = [t for path, _, t in storage.started if path == "g0/p03"]
+    assert len(tries) == 2 and tries[1] - failed[0] >= 0.1  # its backoff, outside any slot
+    retries = [e for e in events if e.name == "scheduler.read_retry"]
+    assert len(retries) == 1 and retries[0].metadata["attempt"] == 1
+    assert any("transient read failure for g0/p03" in r.getMessage() for r in caplog.records)
+    assert stats["direct"] == 8 and stats["taken"] == 8 + 1 + 8  # the failed try is a completion
+    storage.pool.shutdown()
+
+
+def test_a_fatal_read_failure_on_the_hand_over_waits_for_the_running_reads(monkeypatch):
+    """The first read fails at once while three others run for 0.3 s: the
+    error is raised only once those three have returned (no range may be
+    given back while a worker writes into it), the queued reads never start,
+    and the budget is whole again."""
+    from torchsnapshot_tpu import knobs
+
+    def hook(path):
+        if path == "g0/p00":
+            raise ValueError("injected fatal read failure")
+        time.sleep(0.3)
+
+    MemoryStoragePlugin.reset()
+    storage = _BlockingStorage("hand_over_fatal", hook=hook)
+    sink: dict = {}
+    (group,) = _groups_of(storage, sink, groups=1, per_group=10)
+    budgets = _install_budget_probe(monkeypatch)
+    with knobs.override_max_per_rank_io_concurrency(4):
+        with pytest.raises(ValueError, match="injected fatal read failure"):
+            sync_execute_read_reqs(group, storage, 1 << 20, 0)
+    raised = time.monotonic()
+    assert len(storage.ended) == len(storage.started) < 10
+    assert all(t <= raised for _, _, t in storage.ended)
+    assert max(t for _, _, t in storage.ended) - storage.started[0][2] >= 0.3
+    (budget,) = budgets
+    assert budget.remaining == budget.initial and budget.inflight == 0
+    storage.pool.shutdown()
+
+
+TOY_LEAVES = 100  # a stateful, two statefuls
+
+
+@pytest.mark.parametrize("plugin", ["fs", "faults", "memory"])
+def test_a_read_on_the_hand_over_costs_the_loop_three_handles_or_fewer(
+    tmp_path, monkeypatch, plugin
+):
+    """A toy restore of two hundred leaves of four pages through an arena of
+    eight leaves (the CPU backend taken for an accelerator, whose copy a
+    range at 16 bytes past a 64-byte boundary is): the callbacks the
+    pipeline's loop is handed (``call_soon`` and ``call_soon_threadsafe``),
+    over the requests.  The fs plug-in reads blocking: its reads run on the
+    slot hand-over (``direct`` is the requests), each costs the pool's grant
+    where it waited, a share of one coalesced wake-up and of the main task's
+    step: three or fewer.  Under the fault injector's wrapper, which has no
+    blocking read, every read is a task of about seven (its start, the
+    grant's future and its wake, the executor's future and its wake, the
+    done-callback, the main task's step), and so on the memory plug-in:
+    ``direct`` 0.  The same bytes come back every way."""
+    import collections
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torchsnapshot_tpu import Snapshot, StateDict, knobs
+    from torchsnapshot_tpu import scheduler as sched_mod
+    from torchsnapshot_tpu import snapshot as snapshot_mod
+    from torchsnapshot_tpu.event_handlers import (
+        register_event_handler,
+        unregister_event_handler,
+    )
+    from torchsnapshot_tpu.io_preparers import array as array_mod
+
+    page = array_mod._PAGE
+    leaf = 4 * page
+
+    def unaligned(nbytes):
+        raw = np.empty(nbytes + 128, dtype=np.uint8)
+        offset = (16 - raw.ctypes.data) % 64
+        return raw[offset : offset + nbytes]
+
+    monkeypatch.setattr(array_mod, "_INTO_PLACE_MIN_BYTES", page)
+    monkeypatch.setattr(array_mod, "_keeps_host_memory", lambda target: False)
+    monkeypatch.setattr(array_mod, "_arena_memory", unaligned)
+    monkeypatch.setattr(
+        snapshot_mod,
+        "H2DBatcher",
+        lambda **kwargs: array_mod.H2DBatcher(
+            flush_bytes=2 * leaf, inflight_cap_bytes=8 * leaf, **kwargs
+        ),
+    )
+
+    def app(zero):
+        rng = np.random.RandomState(5)
+        return {
+            key: StateDict(
+                {
+                    f"w{i:03d}": jnp.zeros((leaf // 4,), jnp.float32)
+                    if zero
+                    else jnp.asarray(rng.rand(leaf // 4), jnp.float32)
+                    for i in range(TOY_LEAVES)
+                }
+            )
+            for key in ("a_params", "b_mu")
+        }
+
+    MemoryStoragePlugin.reset()
+    path = f"memory://toy_{time.monotonic_ns()}" if plugin == "memory" else str(tmp_path / "snap")
+    saved = app(zero=False)
+    Snapshot.take(path, saved)
+
+    handles = collections.Counter()
+    real_soon = asyncio.BaseEventLoop.call_soon
+    real_threadsafe = asyncio.BaseEventLoop.call_soon_threadsafe
+
+    def call_soon(self, *args, **kwargs):
+        handles[id(self)] += 1
+        return real_soon(self, *args, **kwargs)
+
+    def call_soon_threadsafe(self, *args, **kwargs):
+        handles[id(self)] += 1
+        return real_threadsafe(self, *args, **kwargs)
+
+    pipelines = []
+    real_init = sched_mod.ReadAhead.__init__
+
+    def init(self, *args, **kwargs):
+        pipelines.append(self)
+        real_init(self, *args, **kwargs)
+
+    monkeypatch.setattr(sched_mod.ReadAhead, "__init__", init)
+    ends = []
+
+    def on_event(event):
+        if event.name == "restore.end":
+            ends.append(dict(event.metadata))
+
+    target = app(zero=True)
+    register_event_handler(on_event)
+    monkeypatch.setattr(asyncio.BaseEventLoop, "call_soon", call_soon)
+    monkeypatch.setattr(asyncio.BaseEventLoop, "call_soon_threadsafe", call_soon_threadsafe)
+    try:
+        with knobs.override_faults("none" if plugin == "faults" else None):
+            Snapshot(path).restore(target)
+    finally:
+        monkeypatch.undo()
+        unregister_event_handler(on_event)
+    for key in saved:
+        for name, want in saved[key].state_dict().items():
+            np.testing.assert_array_equal(np.asarray(target[key][name]), np.asarray(want))
+    (end,) = ends
+    (pipeline,) = pipelines
+    loop = end["read_loop"]
+    reqs = loop["inline"] + loop["handed"]
+    assert reqs >= 2 * TOY_LEAVES
+    per_read = handles[id(pipeline._loop)] / reqs
+    arena = end["arena_turn"]
+    assert arena["ranges"] == 2 * TOY_LEAVES and arena["turn_bs"] == pytest.approx(
+        sum(arena[stage + "_bs"] for stage in array_mod._STAGES)
+    )
+    assert arena["grant_s"] > 0  # reads waited for room, and were granted
+    if plugin == "fs":
+        assert loop["direct"] == reqs and loop["inline"] == 2 * TOY_LEAVES
+        assert 1 <= loop["wakes"] <= reqs
+        assert per_read <= 3, per_read
+    else:
+        assert loop["direct"] == loop["wakes"] == 0
+        if plugin == "faults":
+            assert per_read >= 5, per_read

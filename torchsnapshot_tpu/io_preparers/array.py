@@ -515,7 +515,8 @@ class HostBufferPool:
     populated or not); a give
     frees the range and coalesces it with its neighbours.  **A read that
     finds no room waits** (``take`` with the pipeline's loop: a future of
-    that loop; the assembly's phase ``host_buffer_wait``), in the order of
+    that loop, or a callback its ``give`` hands that loop; the assembly's
+    phase ``host_buffer_wait``), in the order of
     dispatch, for a landing of ANY stateful, its own included, and the wait
     makes every attached batcher send what it holds to the device (a flush
     on pressure: ``attach``, ``waiting``), or a stateful would wait on its
@@ -576,8 +577,9 @@ class HostBufferPool:
         self._base = self._size = 0
         self._free: List[List[int]] = []  # [offset, size], by offset
         self._lent: Dict[int, _Turn] = {}  # offset -> each range out, its turn
-        # (loop, future, nbytes) of each read held for room, in dispatch order
-        self._waiters: "deque[Tuple[Any, Any, int]]" = deque()
+        # (loop, what grants the range, nbytes) of each read held for room, in
+        # dispatch order
+        self._waiters: "deque[Tuple[Any, Callable[[np.ndarray], None], int]]" = deque()
         self._plain_alive = 0  # bytes of plain buffers taken and not given back
         # The restore's dispatcher and lander: every attached batcher's.
         self.h2d_threads = H2DThreads()
@@ -687,12 +689,19 @@ class HostBufferPool:
         self._count(nbytes, nbytes)
         return _fresh_host_buffer(nbytes)
 
-    def take(self, nbytes: int, loop: Optional[Any] = None) -> Any:
+    def take(
+        self,
+        nbytes: int,
+        loop: Optional[Any] = None,
+        granted: Optional[Callable[[np.ndarray], None]] = None,
+    ) -> Any:
         """A flat uint8 buffer of exactly ``nbytes``: a range of the arena,
         or a plain buffer where there is no arena (or, with no ``loop``, no
         room in it).  With a ``loop`` and no room, or with reads already
         waiting: a future of ``loop`` that a ``give`` resolves to the buffer,
-        this read's turn having come; the batchers are flushed first."""
+        this read's turn having come; the batchers are flushed first.  Given
+        ``granted``, None in place of the future, and the ``give`` calls
+        ``granted(buffer)`` on ``loop``'s thread: one callback, no future."""
         with self._lock:
             if self._arena is None and not self._plain:
                 self._make_arena()
@@ -705,8 +714,11 @@ class HostBufferPool:
                     return buf
                 if loop is None:
                     return self._plain_buffer(nbytes)
-            coming = loop.create_future()
-            self._waiters.append((loop, coming, nbytes))
+            coming = None
+            if granted is None:
+                coming = loop.create_future()
+                granted = functools.partial(self._grant, coming)
+            self._waiters.append((loop, granted, nbytes))
             batchers = self._live_batchers()
         for batcher in batchers:
             batcher.flush()
@@ -725,7 +737,7 @@ class HostBufferPool:
         queue as far as it reaches.  Otherwise the range is never handed out
         again, and nor is any other: there is no arena from here on, and
         whoever waits gets a plain buffer."""
-        granted: List[Tuple[Any, Any, np.ndarray]] = []
+        granted: List[Tuple[Any, Callable[[np.ndarray], None], np.ndarray]] = []
         with self._lock:
             offset = buf.ctypes.data - self._base
             turn = self._lent.pop(offset, None)
@@ -739,17 +751,17 @@ class HostBufferPool:
             else:
                 self._plain = True
             while self._waiters:
-                loop, coming, nbytes = self._waiters[0]
+                loop, grant, nbytes = self._waiters[0]
                 lease = (
                     self._plain_buffer(nbytes) if self._plain else self._fit(nbytes)
                 )
                 if lease is None:
                     break
                 self._waiters.popleft()
-                granted.append((loop, coming, lease))
-        for loop, coming, lease in granted:
+                granted.append((loop, grant, lease))
+        for loop, grant, lease in granted:
             try:
-                loop.call_soon_threadsafe(self._grant, coming, lease)
+                loop.call_soon_threadsafe(grant, lease)
             except RuntimeError:  # the pipeline was aborted, its loop closed
                 self.give(lease, recycle=True)
 
@@ -1430,9 +1442,9 @@ class ArrayAssembly:
     (``host``: nothing is allocated at plan time) to ``finalize``, which
     hands it on and lets go.  A jax-array target of a megabyte and more that
     uploads through an ``H2DBatcher`` with a ``HostBufferPool`` takes a range
-    of the pool's arena (``buffer_ready``, which waits for room; every read
-    into the leaf shares the one range), and the batcher gives it back once
-    landed."""
+    of the pool's arena (``buffer_ready``, or ``buffer_ready_soon``, which
+    wait for room; every read into the leaf shares the one range), and the
+    batcher gives it back once landed."""
 
     def __init__(
         self,
@@ -1453,8 +1465,11 @@ class ArrayAssembly:
         self._lease: Optional[np.ndarray] = None  # the pool's buffer under _host
         self._turn: Optional[_Turn] = None  # the arena's record of that range
         self._pool: Optional[HostBufferPool] = None
-        # the pool's promise of a range, while the first read waits for room
+        # the pool's promise of a range, while the first read waits for room;
+        # or, for reads handed on by callbacks, what each waiting read does next
         self._coming: Optional["asyncio.Future[np.ndarray]"] = None
+        self._then: Optional[List[Callable[[], None]]] = None
+        self._waited: Optional[Any] = None  # their host_buffer_wait interval
         self._nbytes = serialization.array_nbytes(entry.shape, entry.dtype)
         if (
             h2d_batch is not None
@@ -1512,6 +1527,35 @@ class ArrayAssembly:
             if waited is not None:
                 waited.close()
         self._settle_on(lease)
+
+    def buffer_ready_soon(self, loop: Any, then: Callable[[], None]) -> bool:
+        """``buffer_ready`` for a read pipeline that hands reads on by
+        callbacks: True where the leaf's range is in hand now; otherwise
+        False, and ``then()`` is called on ``loop``'s thread once the pool has
+        granted the range (one callback, ``_granted``) and the leaf has
+        adopted it.  The leaf's other reads wait with the first."""
+        from .. import phase_stats
+
+        if self._pool is None or self._host is not None:
+            return True
+        if self._then is not None:
+            self._then.append(then)
+            return False
+        taken = self._pool.take(self._nbytes, loop, granted=self._granted)
+        if taken is not None:
+            self._settle_on(taken)
+            return True
+        self._then = [then]
+        self._waited = phase_stats.open_interval("host_buffer_wait")
+        return False
+
+    def _granted(self, lease: np.ndarray) -> None:
+        # On the loop's thread: the pool's grant to the first waiting read.
+        waiting, self._then = self._then, None
+        self._waited.close()
+        self._settle_on(lease)
+        for then in waiting:
+            then()
 
     def _settle_on(self, lease: np.ndarray) -> None:
         """``lease`` is this leaf's, unless a piece copied in on an executor
@@ -1603,6 +1647,22 @@ class IntoPlace:
         the first try did."""
         if self._view is None:
             await self._assembly.buffer_ready()
+        return self._viewed()
+
+    def acquire_soon(
+        self, loop: Any, then: Callable[[memoryview], None]
+    ) -> Optional[memoryview]:
+        """``acquire()`` for a read pipeline that hands reads on by
+        callbacks: the view where the leaf's range is in hand now; otherwise
+        None, and ``then(view)`` is called on ``loop``'s thread once it is."""
+        if self._view is None and not self._assembly.buffer_ready_soon(
+            loop, lambda: then(self._viewed())
+        ):
+            return None
+        return self._viewed()
+
+    def _viewed(self) -> memoryview:
+        if self._view is None:
             flat = memoryview(self._assembly.flat_u8())
             self._view = flat[self._offset : self._offset + self._nbytes]
         return self._view

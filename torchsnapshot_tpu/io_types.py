@@ -168,9 +168,10 @@ class ReadReq:
     no_merge: bool = False
     # Read-into-place: the consumer's destination, forwarded to the storage
     # plugin via ReadIO.into once the read is dispatched and no sooner: the
-    # read pipeline awaits its ``acquire()`` for the memoryview (IntoView:
-    # memory that is there already; io_preparers/array.IntoPlace: memory
-    # taken then, from the restore's pool of host buffers).  Requests
+    # read pipeline awaits its ``acquire()`` for the memoryview, or calls its
+    # ``acquire_soon()`` (IntoView: memory that is there already;
+    # io_preparers/array.IntoPlace: memory taken then, from the restore's
+    # pool of host buffers).  Requests
     # carrying one are never merged (their destinations are not contiguous
     # in host memory).
     into: Optional[Any] = None
@@ -183,6 +184,11 @@ class IntoView:
         self._view = view
 
     async def acquire(self) -> memoryview:
+        return self._view
+
+    def acquire_soon(self, loop: Any, then: Any) -> memoryview:
+        """``acquire()`` without a coroutine (``io_preparers/array.IntoPlace
+        .acquire_soon``): the memory is there, so ``then`` is never called."""
         return self._view
 
     def stamp(self, stamp: str) -> None:

@@ -817,6 +817,8 @@ class _ReadPipeline:
         self.hash64: Optional[int] = None
         self.into: Optional[memoryview] = None  # where the read lands in place
         self.read_began = 0.0  # when storage was asked, its memory acquired
+        self.in_hand = 0.0  # when its memory was in hand (the slot hand-over's)
+        self.attempt = 0  # transient failures retried (the slot hand-over's)
 
     async def take_memory(self) -> None:
         """The read is being dispatched: only now is the memory it lands in
@@ -828,6 +830,22 @@ class _ReadPipeline:
         if self.read_req.into is not None:
             self.into = await self.read_req.into.acquire()
 
+    def take_memory_soon(
+        self, loop: asyncio.AbstractEventLoop, then: Callable[[], None]
+    ) -> bool:
+        """``take_memory`` for the slot hand-over: True where the memory is in
+        hand now; otherwise False, and ``then()`` is called on ``loop``'s
+        thread once it is (the pool's grant, one callback)."""
+        if self.read_req.into is None:
+            return True
+
+        def granted(view: memoryview) -> None:
+            self.into = view
+            then()
+
+        self.into = self.read_req.into.acquire_soon(loop, granted)
+        return self.into is not None
+
     def stamp(self, stamp: str) -> None:
         """A hand-over of this read (``read_began``, ``read_back``,
         ``consume_began``), for the record of its leaf's turn through the
@@ -837,10 +855,23 @@ class _ReadPipeline:
             self.read_req.into.stamp(stamp)
 
     async def read_buffer(self) -> "_ReadPipeline":
+        read_io = self._read_io()
+        await self.storage.read(read_io)
+        self._landed(read_io)
+        return self
+
+    def read_blocking(self, read: Callable[[ReadIO], None]) -> None:
+        """``read_buffer`` on the calling thread, through the plugin's
+        ``read_blocking``: a worker of the slot hand-over."""
+        read_io = self._read_io()
+        read(read_io)
+        self._landed(read_io)
+
+    def _read_io(self) -> ReadIO:
         consumer = self.read_req.buffer_consumer
         self.read_began = time.monotonic()
         self.stamp("read_began")
-        read_io = ReadIO(
+        return ReadIO(
             path=self.read_req.path,
             byte_range=(
                 list(self.read_req.byte_range)
@@ -859,11 +890,11 @@ class _ReadPipeline:
             # it read+hash stripes in parallel.
             hash_algo=getattr(consumer, "hash_algo", None),
         )
-        await self.storage.read(read_io)
+
+    def _landed(self, read_io: ReadIO) -> None:
         self.buf = read_io.buf
         self.hash64 = read_io.hash64
         self.into = None
-        return self
 
     def _hand_digest(self) -> BufferConsumer:
         consumer = self.read_req.buffer_consumer
@@ -895,6 +926,125 @@ class _ReadPipeline:
         await self._hand_digest().consume_buffer(self.buf, executor)
         self.buf = None
         return self
+
+
+# What a read on the slot hand-over comes back as: the read, its error or
+# None, and when it began and ended.
+_Completion = Tuple[_ReadPipeline, Optional[BaseException], float, float]
+
+
+class _DirectReads:
+    """The io slots of a read pipeline whose storage plugin reads blocking
+    (``read_blocking`` and ``read_executor``, as ``storage_plugins/fs``
+    offers): a read with its memory in hand runs on the plugin's own workers
+    and is never a task.
+
+    ``start``, on the loop's thread, takes a free slot and submits one job to
+    the plugin's executor, or queues the read (first in, first out).  A job
+    runs its read and posts the completion, then, under the same lock, takes
+    the next queued read and runs it on the same thread, or frees its slot:
+    the loop thread never hands a slot on, and at most ``io_cap`` reads run
+    at once.  A completion goes on a list, and the loop is woken (``wake``,
+    through ``call_soon_threadsafe``) only where no wake-up is pending
+    already: one flag under the list's lock, which ``take`` clears as it
+    takes every completion off.  ``close`` drops the queue and returns
+    once no read runs, so no range is given back while a worker still writes
+    into it.
+
+    A read's ``io_slot_wait`` is the time from its memory in hand to its
+    start, recorded by the worker that starts it when it is 1 ms or more;
+    ``wakes`` counts the wake-ups posted."""
+
+    def __init__(
+        self,
+        storage: StoragePlugin,
+        io_cap: int,
+        loop: asyncio.AbstractEventLoop,
+        wake: Callable[[], None],
+    ) -> None:
+        self._read: Callable[[ReadIO], None] = storage.read_blocking  # type: ignore[attr-defined]
+        self._executor: Executor = storage.read_executor()  # type: ignore[attr-defined]
+        self._loop, self._wake = loop, wake
+        self._lock = threading.Lock()
+        self._idle = threading.Condition(self._lock)
+        self._cap = self._free = io_cap
+        self._queue: "deque[_ReadPipeline]" = deque()
+        self._done: List[_Completion] = []
+        self._woken = False  # a wake-up is posted and its completions not taken
+        self._closed = False
+        self.wakes = 0
+
+    def start(self, pipeline: _ReadPipeline) -> None:
+        """On the loop's thread: ``pipeline``'s memory is in hand, and its
+        read runs once a slot is free."""
+        pipeline.in_hand = time.monotonic()
+        with self._lock:
+            if self._closed:
+                return
+            if not self._free:
+                self._queue.append(pipeline)
+                return
+            self._free -= 1
+        try:
+            self._executor.submit(self._run, pipeline)
+        except BaseException as e:  # noqa: BLE001 -- the plugin is closed
+            self._finished(pipeline, e, pipeline.in_hand, time.monotonic())
+
+    def _run(self, pipeline: Optional[_ReadPipeline]) -> None:
+        while pipeline is not None:
+            began = time.monotonic()
+            error: Optional[BaseException] = None
+            if not self._closed:
+                try:
+                    waited = began - pipeline.in_hand
+                    if waited >= 0.001:
+                        phase_stats.add("io_slot_wait", waited, end=began)
+                    pipeline.read_blocking(self._read)
+                except BaseException as e:  # noqa: BLE001 -- the loop's to raise
+                    error = e
+            pipeline = self._finished(pipeline, error, began, time.monotonic())
+
+    def _finished(
+        self,
+        pipeline: _ReadPipeline,
+        error: Optional[BaseException],
+        began: float,
+        ended: float,
+    ) -> Optional[_ReadPipeline]:
+        """Post the completion; return the next queued read, the slot handed
+        on with it, or None and the slot freed."""
+        with self._lock:
+            self._done.append((pipeline, error, began, ended))
+            wake = not (self._woken or self._closed)
+            if wake:
+                self._woken = True
+                self.wakes += 1
+            following = self._queue.popleft() if self._queue else None
+            if following is None:
+                self._free += 1
+                self._idle.notify_all()
+        if wake:
+            try:
+                self._loop.call_soon_threadsafe(self._wake)
+            except RuntimeError:  # the loop has closed: nobody waits
+                pass
+        return following
+
+    def take(self) -> List[_Completion]:
+        """On the loop's thread: every completion posted, in the order they
+        came; the next one posted wakes the loop again."""
+        with self._lock:
+            done, self._done = self._done, []
+            self._woken = False
+        return done
+
+    def close(self) -> None:
+        """No read starts from here on; return once none runs."""
+        with self._lock:
+            self._closed = True
+            self._queue.clear()
+            while self._free < self._cap:
+                self._idle.wait()
 
 
 class ReadAhead:
@@ -1088,7 +1238,8 @@ async def execute_read_reqs(
     - no read of group k+2 starts before group k is loaded, all of its
       host ranges landed from.
     - a range's turn through that arena is stamped where this loop hands it
-      on (``_ReadPipeline.stamp``): ``read_began`` when storage is asked,
+      on (``_ReadPipeline.stamp``): ``read_began`` when storage is asked (on
+      the slot hand-over, by the worker that starts the read),
       ``read_back`` when this thread takes the finished read off (so a
       finished read that waits for this thread counts with its read), and
       ``consume_began`` when a read parked behind the loader is let go; the
@@ -1109,12 +1260,27 @@ async def execute_read_reqs(
     the digests are compared, the leaf's piece counted and, if it was the
     last, the leaf submitted, all on this thread: no task, no executor.
     Everything else (a checksum to hash, a frame to decode, a merged slab
-    read, objects, sharded pieces) is consumed as a task as before.  The
-    counter ``read_loop`` (and ``ReadAhead.read_loop``, the ``restore.end``
-    entry) says what the loop did: ``turns``, completions ``taken`` off,
-    reads consumed ``inline``, consumes ``handed`` on as a task (``inline +
-    handed`` is the requests), the most tasks alive at once
-    (``max_pending``), ``n`` pipelines.
+    read, objects, sharded pieces) is consumed as a task as before.
+
+    **Where the plugin reads blocking** (``read_blocking`` and
+    ``read_executor``: ``storage_plugins/fs``) **no read is a task**: its
+    memory is taken in ``dispatch_io`` (``_ReadPipeline.take_memory_soon``;
+    a read that waits for room is handed on by the pool's grant, one
+    callback on this loop, that adopts the range), and then it goes to the
+    slot hand-over (``_DirectReads``), whose workers, the plugin's own, hand
+    the io slots on themselves and post each finished read with one
+    coalesced wake-up of this loop.  A transient failure comes back as a
+    completion and is started again after its backoff, outside any slot.
+    Other plugins (the wrappers, the object stores, ``memory``) have their
+    reads run as tasks behind the ``io_semaphore``.
+
+    The counter ``read_loop`` (and ``ReadAhead.read_loop``, the
+    ``restore.end`` entry) says what the loop did: ``turns``, completions
+    ``taken`` off, reads consumed ``inline``, consumes ``handed`` on as a
+    task (``inline + handed`` is the requests), the most reads and consumes
+    pending at once (``max_pending``), reads run by the slot hand-over
+    (``direct``: the requests, or 0 on the task path), the wake-ups its
+    workers posted (``wakes``), ``n`` pipelines.
 
     With no loader a group counts as loaded once it is consumed.  An error
     in any read or consume cancels everything in flight and is raised."""
@@ -1152,8 +1318,12 @@ async def execute_read_reqs(
     consume_tasks: set = set()
     # task -> pipeline, for re-crediting un-consumed pipelines on failure
     pipelines: dict = {}
+    # Dispatched to the slot hand-over and not yet taken off: the same record.
+    direct_reads: set = set()
     # The counter ``read_loop``: what this loop did, for every request.
-    loop_stats = {"turns": 0, "taken": 0, "inline": 0, "handed": 0, "max_pending": 0}
+    loop_stats = dict.fromkeys(
+        ("turns", "taken", "inline", "handed", "max_pending", "direct", "wakes"), 0
+    )
     reporter = _ProgressReporter(
         rank=rank, total=n_reqs, verb="read", budget=budget
     )
@@ -1165,10 +1335,33 @@ async def execute_read_reqs(
             p.read_req.path
             for t, p in list(pipelines.items())
             if not t.done()
-        ],
+        ]
+        + [p.read_req.path for p in list(direct_reads)],
     }
 
     max_read_retries = knobs.get_io_retries()
+
+    def retrying(pipeline: _ReadPipeline, e: Exception, attempt: int) -> None:
+        tmetrics.record_pipeline_retry("read")
+        log_event(
+            Event(
+                name="scheduler.read_retry",
+                metadata={
+                    "path": pipeline.read_req.path,
+                    "attempt": attempt,
+                    "error": repr(e),
+                },
+            )
+        )
+        logger.warning(
+            "[rank %d] transient read failure for %s "
+            "(attempt %d/%d): %r; retrying",
+            rank,
+            pipeline.read_req.path,
+            attempt,
+            max_read_retries,
+            e,
+        )
 
     async def _read(pipeline: _ReadPipeline) -> _ReadPipeline:
         # Bounded retry of TRANSIENT read failures — the write path's
@@ -1202,26 +1395,7 @@ async def execute_read_reqs(
                 ):
                     raise
                 attempt += 1
-                tmetrics.record_pipeline_retry("read")
-                log_event(
-                    Event(
-                        name="scheduler.read_retry",
-                        metadata={
-                            "path": pipeline.read_req.path,
-                            "attempt": attempt,
-                            "error": repr(e),
-                        },
-                    )
-                )
-                logger.warning(
-                    "[rank %d] transient read failure for %s "
-                    "(attempt %d/%d): %r; retrying",
-                    rank,
-                    pipeline.read_req.path,
-                    attempt,
-                    max_read_retries,
-                    e,
-                )
+                retrying(pipeline, e, attempt)
                 await asyncio.sleep(retry_policy.backoff_s(attempt))
 
     def next_for_io() -> Optional["deque[_ReadPipeline]"]:
@@ -1244,6 +1418,15 @@ async def execute_read_reqs(
     # loader's ``mark_loaded`` sets the same one).
     finished_tasks: "deque[asyncio.Task]" = deque()
     wake = loader._attach() if loader is not None else asyncio.Event()
+    loop = asyncio.get_running_loop()
+    # The plugin that reads blocking has its reads run by the slot hand-over,
+    # on its own workers, which wake the loop through the same event.
+    direct = (
+        _DirectReads(storage, io_cap, loop, wake.set)
+        if callable(getattr(storage, "read_blocking", None))
+        and callable(getattr(storage, "read_executor", None))
+        else None
+    )
 
     def finished(task: "asyncio.Task") -> None:
         finished_tasks.append(task)
@@ -1255,21 +1438,65 @@ async def execute_read_reqs(
         tasks.add(task)
         pipelines[task] = pipeline
 
+    def hand(pipeline: _ReadPipeline) -> None:
+        # To the slot hand-over, with the read's memory now or once granted.
+        loop_stats["direct"] += 1
+        direct_reads.add(pipeline)
+        if pipeline.take_memory_soon(loop, lambda: direct.start(pipeline)):
+            direct.start(pipeline)
+
+    def reading() -> int:
+        """Reads dispatched and not yet taken off."""
+        return len(io_tasks) + len(direct_reads)
+
     def dispatch_io() -> None:
         while (queue := next_for_io()) is not None:
             pipeline = queue[0]
             if pipeline.consuming_cost <= budget.remaining or (
-                budget.inflight == 0 and not io_tasks and not consume_tasks
+                budget.inflight == 0 and not reading() and not consume_tasks
             ):
                 queue.popleft()
                 budget.remaining -= pipeline.consuming_cost
                 budget.inflight += 1
-                start(_read(pipeline), io_tasks, pipeline)
+                if direct is None:
+                    start(_read(pipeline), io_tasks, pipeline)
+                else:
+                    hand(pipeline)
             else:
                 break
         loop_stats["max_pending"] = max(
-            loop_stats["max_pending"], len(io_tasks) + len(consume_tasks)
+            loop_stats["max_pending"], reading() + len(consume_tasks)
         )
+
+    def took_off(pipeline: _ReadPipeline) -> None:
+        unread[pipeline.group] -= 1
+        # Taken off: until here a finished read waited for this thread, which
+        # its range's turn counts with the read.
+        pipeline.stamp("read_back")
+        if pipeline.group <= loaded:
+            consume(pipeline)
+        else:
+            parked.append(pipeline)
+
+    def take_direct() -> None:
+        for pipeline, error, began, ended in direct.take():
+            loop_stats["taken"] += 1
+            if error is not None:
+                if pipeline.attempt >= max_read_retries or not (
+                    isinstance(error, Exception) and retry_policy.is_transient(error)
+                ):
+                    raise error
+                # Started again after its backoff, its memory still in hand
+                # and no slot held meanwhile.
+                pipeline.attempt += 1
+                retrying(pipeline, error, pipeline.attempt)
+                call_later(
+                    retry_policy.backoff_s(pipeline.attempt), direct.start, pipeline
+                )
+                continue
+            direct_reads.discard(pipeline)
+            reads[pipeline.group].append((began, ended, _buf_nbytes(pipeline.buf)))
+            took_off(pipeline)
 
     def consumed_one(pipeline: _ReadPipeline) -> None:
         budget.remaining += pipeline.consuming_cost
@@ -1330,7 +1557,7 @@ async def execute_read_reqs(
 
     def track_starved() -> None:
         nonlocal starved
-        if (consume_tasks or parked) and not io_tasks:
+        if (consume_tasks or parked) and not reading():
             if starved is None:
                 starved = phase_stats.open_interval("read_starved")
         elif starved is not None:
@@ -1343,7 +1570,7 @@ async def execute_read_reqs(
     read_span.__enter__()
     # The reporter's interval: the event is set at least that often.
     ticker: Optional[asyncio.TimerHandle] = None
-    call_later = asyncio.get_running_loop().call_later
+    call_later = loop.call_later
 
     def tick() -> None:
         nonlocal ticker
@@ -1360,7 +1587,7 @@ async def execute_read_reqs(
             # consuming budget is binding only when the queue head is
             # inadmissible WHILE read slots sit idle — a head queued
             # behind saturated storage is storage-bound, not budget-bound.
-            budget_bound = next_for_io() is not None and len(io_tasks) < io_cap
+            budget_bound = next_for_io() is not None and reading() < io_cap
             blocked = (
                 phase_stats.open_interval("budget_wait") if budget_bound else None
             )
@@ -1369,6 +1596,8 @@ async def execute_read_reqs(
             if blocked is not None:
                 blocked.close()
             loop_stats["turns"] += 1
+            if direct is not None:
+                take_direct()
             while finished_tasks:
                 task = finished_tasks.popleft()
                 loop_stats["taken"] += 1
@@ -1376,14 +1605,7 @@ async def execute_read_reqs(
                     io_tasks.discard(task)
                     pipeline = task.result()  # raises on storage failure
                     pipelines.pop(task)
-                    unread[pipeline.group] -= 1
-                    # Taken off: until here a finished read waited for this
-                    # thread, which its range's turn counts with the read.
-                    pipeline.stamp("read_back")
-                    if pipeline.group <= loaded:
-                        consume(pipeline)
-                    else:
-                        parked.append(pipeline)
+                    took_off(pipeline)
                 else:
                     consume_tasks.discard(task)
                     pipeline = task.result()  # raises on consume failure
@@ -1398,9 +1620,11 @@ async def execute_read_reqs(
             reporter.maybe_report(
                 budget,
                 pending=sum(len(queue) for queue in ready_for_io),
-                staging=len(io_tasks),
+                staging=reading(),
                 inflight_io=len(consume_tasks) + len(parked),
             )
+        if direct is not None:
+            loop_stats["wakes"] = direct.wakes
         phase_stats.add_counter("read_loop", 0.0, 0, **loop_stats)
         if loader is not None:
             loader.read_loop = loop_stats
@@ -1416,6 +1640,10 @@ async def execute_read_reqs(
         read_span.__exit__(*sys.exc_info())
         # Mirror the write path: cancel-and-drain outstanding reads/consumes
         # before re-raising, releasing buffers and re-crediting the budget.
+        # The slot hand-over starts no read from here on, and every read that
+        # runs returns before anything is given back.
+        if direct is not None:
+            direct.close()
         for t in io_tasks | consume_tasks:
             if not t.done():
                 t.cancel()
@@ -1423,7 +1651,7 @@ async def execute_read_reqs(
             await asyncio.gather(
                 *io_tasks, *consume_tasks, return_exceptions=True
             )
-        for pipeline in [*pipelines.values(), *parked]:
+        for pipeline in [*pipelines.values(), *direct_reads, *parked]:
             pipeline.buf = None
             budget.remaining += pipeline.consuming_cost
             budget.inflight -= 1

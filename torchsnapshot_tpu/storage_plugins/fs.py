@@ -574,12 +574,21 @@ class FSStoragePlugin(StoragePlugin):
         )
 
     async def read(self, read_io: ReadIO) -> None:
-        path = os.path.join(self.root, read_io.path)
-        loop = asyncio.get_running_loop()
-        read_io.buf, read_io.hash64 = await loop.run_in_executor(
-            self._get_executor(),
-            self._blocking_read,
-            path,
+        await asyncio.get_running_loop().run_in_executor(
+            self._get_executor(), self.read_blocking, read_io
+        )
+
+    def read_executor(self) -> ThreadPoolExecutor:
+        """The plugin's own workers, on which ``read`` runs ``read_blocking``.
+        A read pipeline that has the range of a read in hand runs
+        ``read_blocking`` there itself (``scheduler._DirectReads``): its
+        workers hand the io slots on, and no read is a task."""
+        return self._get_executor()
+
+    def read_blocking(self, read_io: ReadIO) -> None:
+        """``read`` on the calling thread, one of ``read_executor``'s."""
+        read_io.buf, read_io.hash64 = self._blocking_read(
+            os.path.join(self.root, read_io.path),
             read_io.byte_range,
             read_io.into,
             read_io.want_hash,
