@@ -43,6 +43,9 @@ NEED_A_TURN = ("arena_turn_s", "arena_wait_pct.resume", "arena_h2d_side_pct.resu
 # counter's ``starved_s`` and ``stranded_bs``, held to a pool driven by hand
 # in which a take waits.
 NEEDS_A_WAIT = "arena_stranded_pct.resume"
+# Nor does the toy Codestral state hold an RNGState: this one reads the
+# ``rng_restore`` counter, held to a restore made by hand with one.
+NEEDS_AN_RNG_STATE = "rng_restore_s"
 # No toy leaf reaches the chunk size of 512 MiB: these two are read from the
 # third rehearsal, where it is 100,000 B and the toy's 131,072 B leaves chunk.
 NEED_A_CHUNKED_LEAF = ("chunked_bytes_pct.resume", "chunk_assemble_s")
@@ -129,6 +132,10 @@ def test_per_layer_metric_finds_what_it_reads(metric, lines):
     if metric["name"] == NEEDS_A_WAIT:
         assert metric["name"] not in lines[1]["metrics"]  # no arena, no wait
         the_stranded_reader_reads_a_pool_in_which_a_take_waited(metric)
+        return
+    if metric["name"] == NEEDS_AN_RNG_STATE:
+        assert metric["name"] not in lines[1]["metrics"]  # no RNGState, no counter
+        the_rng_reader_reads_a_restore_that_held_an_rng_state(metric)
         return
     line = lines[2 if metric["name"] in NEED_A_CHUNKED_LEAF else 1]
     assert line["correct"] is True and line["failed"] == 0
@@ -283,6 +290,35 @@ def the_stranded_reader_reads_a_pool_in_which_a_take_waited(metric):
     assert read(recorded(old)) is None
 
 
+def the_rng_reader_reads_a_restore_that_held_an_rng_state(metric):
+    """A restore on the memory plug-in of a state saved with an RNGState
+    records the counter once, through the reader ``run.py`` loads, as its
+    ``plan_s`` and ``tail_s``; a restore of a state without one records
+    nothing and the reader reads nothing."""
+    import jax.numpy as jnp
+
+    from chipbench import job as chipbench_job
+    from torchsnapshot_tpu import RNGState, SnapshotManager, StateDict
+
+    read = chipbench_job.load_module("metrics", metric["name"], "metric").read
+    restores = [{"op": "kill_resume", "ok": True}]
+    manager = SnapshotManager(f"memory://rng_reader_{time.monotonic_ns()}")
+    manager.save(1, {"params": StateDict(w=jnp.ones((8,))), "rng": RNGState()})
+    manager.save(2, {"params": StateDict(w=jnp.ones((8,)))})
+
+    def restored(step, app_state):
+        before = phase_stats.snapshot()
+        manager.restore_at(step, app_state)
+        return {"account": types.SimpleNamespace(window_operations=lambda op: restores),
+                "phases": phase_stats.delta(before), "counters": {}}
+
+    run = restored(1, {"params": StateDict(w=jnp.zeros((8,))), "rng": RNGState()})
+    p = run["phases"]["rng_restore"]
+    assert p["n"] == 1 and read(run) == p["plan_s"] + p["tail_s"] > 0
+    run = restored(2, {"params": StateDict(w=jnp.zeros((8,)))})
+    assert "rng_restore" not in run["phases"] and read(run) is None
+
+
 def test_the_turn_brought_the_benchmark_five_metrics_and_five_readers():
     resume_cells = next(m for m in REAL["end_to_end"] if m["name"] == "resume_s")["workloads"]
     names = [m["name"] for m in REAL["per_layer"]]
@@ -304,16 +340,35 @@ def test_the_turn_brought_the_benchmark_five_metrics_and_five_readers():
 
 
 def test_the_trinity_cell_brought_the_benchmark_one_metric_last():
-    """ISSUE 39: the free share of the arena while reads waited, appended
-    behind PR 35's five and reported by every resume cell, the new one too."""
+    """The free share of the arena while reads waited, appended behind the
+    arena's five and reported by every resume cell, the trinity cell's too,
+    which came with it: each stays where it was appended."""
     resume_cells = next(m for m in REAL["end_to_end"] if m["name"] == "resume_s")["workloads"]
-    assert resume_cells[-1] == "trinity-large.kill-resume" and len(resume_cells) == 6
-    assert REAL["per_layer"][-1] == {
+    assert resume_cells.index("trinity-large.kill-resume") == 5
+    assert REAL["per_layer"][25] == {
         "name": NEEDS_A_WAIT, "unit": "%", "better": "lower", "source": "program_span",
         "layer": "Scheduler", "moves": "resume_s", "workloads": resume_cells,
     }
     assert [m["name"] for m in REAL["per_layer"]].index(NEEDS_A_WAIT) == 25
     assert os.path.exists(os.path.join(ROOT, "chipbench", "metrics", NEEDS_A_WAIT + ".py"))
+
+
+def test_the_sdar_cell_brought_the_benchmark_one_metric_last():
+    """The seventh resume cell, reported by every metric that named all six
+    but the chunked path's two, and the seconds its RNGState adds to a
+    restore, appended last and read in that cell alone."""
+    cell = "sdar30b.kill-resume"
+    resume_cells = next(m for m in REAL["end_to_end"] if m["name"] == "resume_s")["workloads"]
+    assert resume_cells.index(cell) == 6 == len(resume_cells) - 1
+    assert [w["name"] for w in REAL["workloads"]][-1] == cell
+    assert REAL["workloads"][-1]["chips"] == 1 and REAL["configs"][-1]["name"] == "sdar-30b-a3b-chat"
+    assert REAL["per_layer"][-1] == {
+        "name": NEEDS_AN_RNG_STATE, "unit": "s", "better": "lower", "source": "program_span",
+        "layer": "Orchestration", "moves": "resume_s", "workloads": [cell],
+    }
+    listed = [m["name"] for m in REAL["per_layer"] if cell in m.get("workloads", [cell])]
+    assert len(listed) == 25 and not {"chunked_bytes_pct.resume", "chunk_assemble_s"} & set(listed)
+    assert os.path.exists(os.path.join(ROOT, "chipbench", "metrics", NEEDS_AN_RNG_STATE + ".py"))
 
 
 def test_a_toy_restore_holds_no_stall_and_says_so(lines):

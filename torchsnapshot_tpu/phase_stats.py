@@ -337,7 +337,11 @@ def add_counter(name: str, seconds: float, nbytes: int = 0, **more: int) -> None
     ``taken=`` off, reads consumed ``inline=`` on the loop thread, consumes
     ``handed=`` on as a task, ``max_pending=`` the most tasks alive at once
     (summed like the rest: over ``n`` it is the mean of the maxima),
-    scheduler.execute_read_reqs).  The
+    scheduler.execute_read_reqs; ``rng_restore``: one occurrence a restore
+    that restored an ``RNGState``, the payload bytes its plan reads,
+    ``entries=`` its manifest entries, ``plan_s=`` its ``plan_read`` wall,
+    ``tail_s=`` from the previous stateful's load to its own, and ``s``
+    their sum, snapshot.Snapshot.restore).  The
     entry has ``s``, ``bytes``, ``n`` and whatever ``more`` names, and no
     ``wall``, and reaches neither hook, so it can name no gap of a trace;
     ``delta()`` differences it like any other."""

@@ -626,6 +626,11 @@ class Snapshot:
         consumed ``inline`` in the turn they were taken off, consumes
         ``handed`` on as a task, ``max_pending`` tasks alive at once;
         ``scheduler.execute_read_reqs`` records the counter of that name).
+        Where an ``RNGState`` was restored (planned and loaded last), the
+        ``rng_restore`` counter and the ``restore.end`` entry of that name
+        say what it added: its manifest ``entries``, the payload ``bytes``
+        its reads took, its ``plan_s`` and the ``tail_s`` from the previous
+        stateful's load to its own.
 
         On-device contract: dense and chunked array uploads are drained
         before return (H2DBatcher.drain — their bytes are ON DEVICE, with
@@ -711,14 +716,21 @@ class Snapshot:
                     keyed.append(rng_state_item)
                 plans: List[Optional[_StatefulPlan]] = []
                 host_pool = HostBufferPool()
+                rng_restore: Optional[Dict[str, Any]] = None
                 try:
                     for key, stateful in keyed:
+                        planned_at = time.monotonic()
                         with phase_stats.timed("plan_read"):
                             plans.append(
                                 self._plan_stateful_reads(
                                     key, stateful, metadata, rank, host_pool
                                 )
                             )
+                    if rng_state_item is not None and plans[-1] is not None:
+                        # The RNG state is planned last: what it adds.
+                        rng_restore = _rng_restore_plan(
+                            plans[-1], time.monotonic() - planned_at
+                        )
                     leaves = sum(len(plan.futures) for plan in plans if plan)
                     # The H2D dispatcher and lander, before the first read
                     # is issued: never started under read load.
@@ -730,6 +742,7 @@ class Snapshot:
                         rank,
                     )
                     try:
+                        loaded_at = time.monotonic()
                         for group, plan in enumerate(plans):
                             if plan is None:
                                 pipeline.mark_loaded(group)
@@ -739,7 +752,10 @@ class Snapshot:
                                         group, plan, pipeline, strict
                                     )
                             if group < len(global_keys):
+                                loaded_at = time.monotonic()
                                 pg.barrier()
+                            elif rng_restore is not None:
+                                rng_restore["tail_s"] = time.monotonic() - loaded_at
                     finally:
                         pipeline.close()
                 finally:
@@ -807,6 +823,11 @@ class Snapshot:
             # bytes the arena lends, and for how long.
             turned = host_pool.turn_stats()
             _add_counter_of("arena_turn", turned)
+            # What restoring an RNGState, planned and loaded last, added.
+            if rng_restore is not None:
+                rng_restore["s"] = rng_restore["plan_s"] + rng_restore["tail_s"]
+                _add_counter_of("rng_restore", rng_restore)
+                event_metadata["rng_restore"] = rng_restore
             slow = phases_delta.get("h2d_land_slow", {})
             event_metadata["duration_s"] = end - begin
             event_metadata["phases"] = {
@@ -1804,6 +1825,25 @@ def _add_counter_of(name: str, stats: Dict[str, Any]) -> None:
     ``s`` and ``bytes`` (0 where it has none) and every other key as is."""
     more = dict(stats)
     phase_stats.add_counter(name, more.pop("s", 0.0), more.pop("bytes", 0), **more)
+
+
+def _rng_restore_plan(plan: "_StatefulPlan", plan_s: float) -> Dict[str, Any]:
+    """The ``rng_restore`` account of the RNG state's plan: the manifest
+    entries planned for it (containers and primitives included), the
+    payload bytes its reads will take, and ``plan_s`` its ``plan_read``
+    wall; ``Snapshot.restore`` adds ``tail_s`` once it is loaded."""
+    nbytes = sum(
+        rr.byte_range[1] - rr.byte_range[0]
+        if rr.byte_range is not None
+        else rr.buffer_consumer.get_consuming_cost_bytes()
+        for rr in plan.read_reqs
+    )
+    return {
+        "bytes": nbytes,
+        "entries": len(plan.futures) + len(plan.container_entries),
+        "plan_s": plan_s,
+        "tail_s": 0.0,
+    }
 
 
 def _restore_overlap(
