@@ -356,19 +356,56 @@ def test_the_trinity_cell_brought_the_benchmark_one_metric_last():
 def test_the_sdar_cell_brought_the_benchmark_one_metric_last():
     """The seventh resume cell, reported by every metric that named all six
     but the chunked path's two, and the seconds its RNGState adds to a
-    restore, appended last and read in that cell alone."""
+    restore, appended last then (the 27th) and read in that cell alone;
+    the metrics appended since name it too."""
     cell = "sdar30b.kill-resume"
     resume_cells = next(m for m in REAL["end_to_end"] if m["name"] == "resume_s")["workloads"]
     assert resume_cells.index(cell) == 6 == len(resume_cells) - 1
     assert [w["name"] for w in REAL["workloads"]][-1] == cell
     assert REAL["workloads"][-1]["chips"] == 1 and REAL["configs"][-1]["name"] == "sdar-30b-a3b-chat"
-    assert REAL["per_layer"][-1] == {
+    assert REAL["per_layer"][26] == {
         "name": NEEDS_AN_RNG_STATE, "unit": "s", "better": "lower", "source": "program_span",
         "layer": "Orchestration", "moves": "resume_s", "workloads": [cell],
     }
-    listed = [m["name"] for m in REAL["per_layer"] if cell in m.get("workloads", [cell])]
+    listed = [m["name"] for m in REAL["per_layer"][:27] if cell in m.get("workloads", [cell])]
     assert len(listed) == 25 and not {"chunked_bytes_pct.resume", "chunk_assemble_s"} & set(listed)
+    assert all(cell in m["workloads"] for m in REAL["per_layer"][27:])
     assert os.path.exists(os.path.join(ROOT, "chipbench", "metrics", NEEDS_AN_RNG_STATE + ".py"))
+
+
+# What the restore's threads spent on the CPU, beside their wall: appended
+# behind rng_restore_s, read in every resume cell and on the toy's line.
+CPU_ACCOUNT = (
+    ("h2d_dispatch_leaf_ms", "ms", "program_span", "H2D"),
+    ("h2d_dispatch_wait_pct.resume", "%", "program_span", "H2D"),
+    ("loop_cpu_pct.resume", "%", "program_counter", "Scheduler"),
+    ("restore_cpu_s_per_gb", "CPU-s/GB", "program_counter", "Orchestration"),
+)
+
+
+def test_the_cpu_account_brought_the_benchmark_four_metrics_last():
+    resume_cells = next(m for m in REAL["end_to_end"] if m["name"] == "resume_s")["workloads"]
+    assert [m["name"] for m in REAL["per_layer"]].index(NEEDS_AN_RNG_STATE) == 26
+    assert REAL["per_layer"][27:] == [
+        {"name": name, "unit": unit, "better": "lower", "source": source,
+         "layer": layer, "moves": "resume_s", "workloads": resume_cells}
+        for name, unit, source, layer in CPU_ACCOUNT
+    ]
+    for name, *_ in CPU_ACCOUNT:
+        assert os.path.exists(os.path.join(ROOT, "chipbench", "metrics", name + ".py"))
+
+
+def test_a_toy_restore_reads_its_cpu_account_in_range(lines):
+    """On the toy's traced line each of the four reads a number, and the
+    shares lie where a thread's CPU against its wall can: no thread is on
+    the CPU longer than it runs (the clock's tick aside: a toy restore's
+    calls last milliseconds, so the shares are held only to the range a
+    tick-charged clock can read)."""
+    metrics = lines[1]["metrics"]
+    assert metrics["h2d_dispatch_leaf_ms"]["value"] > 0
+    assert metrics["restore_cpu_s_per_gb"]["value"] > 0
+    assert metrics["loop_cpu_pct.resume"]["value"] >= 0
+    assert metrics["h2d_dispatch_wait_pct.resume"]["value"] <= 100
 
 
 def test_a_toy_restore_holds_no_stall_and_says_so(lines):

@@ -550,8 +550,10 @@ def test_h2d_flush_returns_without_a_dispatch_on_the_calling_thread(monkeypatch)
     assert [name for name, _, _ in held.calls] == ["tpusnap-h2d-dispatcher"]
     assert threading.current_thread().name != "tpusnap-h2d-dispatcher"
     np.testing.assert_array_equal(np.asarray(f.obj), np.full(16, 7.0))
-    assert b.threads.route() == {
-        "bytes": 64, "off_caller": 64, "on_caller": 0, "batches": 1
+    route = b.threads.route()
+    assert 0.0 <= route.pop("cpu_s")
+    assert route == {
+        "bytes": 64, "off_caller": 64, "on_caller": 0, "batches": 1, "leaves": 1
     }
     assert _no_h2d_thread_alive()
 
@@ -670,6 +672,7 @@ def test_h2d_drain_waits_for_queued_batches_and_leaves_no_thread(monkeypatch):
     route = b.threads.route()
     assert route["on_caller"] == 64 and route["off_caller"] == 4 * 64
     assert route["bytes"] == 5 * 64 and route["batches"] == 4
+    assert route["leaves"] == 5  # the queued three went as one call
 
 
 def test_h2d_batcher_bad_item_fails_alone(caplog):

@@ -524,13 +524,17 @@ def test_the_device_put_runs_on_the_dispatcher_and_the_account_says_so(world):
     }
     state_bytes = len(KEYS) * LEAVES * int(np.prod(SHAPE)) * 4
     route = end["h2d_dispatch_route"]
-    assert sorted(route) == ["batches", "bytes", "off_caller", "on_caller"]
+    assert sorted(route) == [
+        "batches", "bytes", "cpu_s", "leaves", "off_caller", "on_caller"
+    ]
+    assert route["leaves"] == len(KEYS) * LEAVES
     assert route["bytes"] == route["off_caller"] == state_bytes
     assert route["on_caller"] == 0 and route["batches"] >= 1
     assert route["batches"] == len(world.log.times("h2d_dispatch"))
     counter = delta["h2d_dispatch_route"]
     assert counter["n"] == 1 and "wall" not in counter
-    assert {k: counter[k] for k in route} == route
+    # (cpu_s, a float, comes back from the counter's delta rounded)
+    assert {k: counter[k] for k in route} == pytest.approx(route, rel=1e-9)
     assert_equal_bits(target, world.saved)
 
 

@@ -882,7 +882,9 @@ class _Worker:
     """A daemon thread that runs, in the order given, the jobs it is handed.
     Started by ``start`` or by the first ``hand``.  Once closed it starts no
     more: a job handed then runs on the thread that hands it, so that nothing
-    handed is ever left undone."""
+    handed is ever left undone.  Once joined, ``cpu_s`` is the CPU seconds
+    the thread spent from its start to its end (its own ``time.thread_time``
+    read twice), 0 where it never started."""
 
     def __init__(self, name: str) -> None:
         self._name = name
@@ -890,6 +892,7 @@ class _Worker:
         self._jobs: "SimpleQueue[Optional[Callable[[], None]]]" = SimpleQueue()
         self._thread: Optional[threading.Thread] = None
         self._closed = False
+        self.cpu_s = 0.0
 
     def _start(self) -> None:
         # Under the lock.
@@ -916,11 +919,15 @@ class _Worker:
         return threading.current_thread() is self._thread
 
     def _run(self) -> None:
-        for job in iter(self._jobs.get, None):
-            try:
-                job()
-            except BaseException:  # noqa: BLE001 -- a job keeps its own errors
-                logger.exception("a job of %s raised", self._name)
+        began = time.thread_time()
+        try:
+            for job in iter(self._jobs.get, None):
+                try:
+                    job()
+                except BaseException:  # noqa: BLE001 -- a job keeps its own errors
+                    logger.exception("a job of %s raised", self._name)
+        finally:
+            self.cpu_s = time.thread_time() - began
 
     def close(self) -> None:
         """Run what was handed, then end and join the thread (idempotent)."""
@@ -956,13 +963,20 @@ class H2DThreads:
     ``off_caller`` on the dispatcher and ``on_caller`` on the thread that
     called ``flush`` or ``drain`` (only once the threads are closed: the
     fall-back that leaves nothing handed over undone), in ``batches``
-    calls."""
+    calls that sent ``leaves`` arrays, and ``cpu_s`` the CPU seconds of the
+    calling thread inside those calls (its ``time.thread_time`` before and
+    after each), whose ``h2d_dispatch`` wall less this is the calls' time
+    off the CPU.  Each thread's own CPU seconds, once joined, are its
+    ``_Worker.cpu_s``."""
 
     def __init__(self) -> None:
         self.dispatcher = _Worker("tpusnap-h2d-dispatcher")
         self.lander = _Worker("tpusnap-h2d-lander")
         self._lock = threading.Lock()
-        self._route = dict.fromkeys(("bytes", "off_caller", "on_caller", "batches"), 0)
+        self._route: Dict[str, float] = dict.fromkeys(
+            ("bytes", "off_caller", "on_caller", "batches", "leaves"), 0
+        )
+        self._route["cpu_s"] = 0.0
 
     def start(self) -> None:
         self.dispatcher.start()
@@ -973,16 +987,19 @@ class H2DThreads:
         self.dispatcher.close()
         self.lander.close()
 
-    def count_put(self, nbytes: int) -> None:
-        """One ``device_put`` call of ``nbytes`` has returned on this thread
+    def count_put(self, nbytes: int, leaves: int, cpu_s: float) -> None:
+        """One ``device_put`` call of ``nbytes`` in ``leaves`` arrays has
+        returned on this thread, which spent ``cpu_s`` on the CPU inside it
         (a call that raised sent nothing and is not counted)."""
         side = "off_caller" if self.dispatcher.is_current() else "on_caller"
         with self._lock:
             self._route["bytes"] += nbytes
             self._route[side] += nbytes
             self._route["batches"] += 1
+            self._route["leaves"] += leaves
+            self._route["cpu_s"] += cpu_s
 
-    def route(self) -> Dict[str, int]:
+    def route(self) -> Dict[str, float]:
         with self._lock:
             return dict(self._route)
 
@@ -1376,9 +1393,10 @@ class H2DBatcher:
         # bytes to h2d_dispatch and the per-item retry would charge again.
         dispatch = phase_stats.open_interval("h2d_dispatch")
         try:
+            cpu = time.thread_time()
             for i, out in zip(idx, jax.device_put(bufs, shardings)):
                 outs[i] = out
-            self.threads.count_put(nbytes)
+            self.threads.count_put(nbytes, len(bufs), time.thread_time() - cpu)
         except Exception:
             dispatch.drop()
             # An HBM OOM looks like this: the per-item retry may well
@@ -1408,8 +1426,9 @@ class H2DBatcher:
         for host, like, fut, lease in items:
             out = None
             try:
+                cpu = time.thread_time()
                 fut.obj = out = _device_put_like(host, like)
-                self.threads.count_put(host.nbytes)
+                self.threads.count_put(host.nbytes, 1, time.thread_time() - cpu)
                 outs.append(out)
                 nbytes += host.nbytes
             except Exception as e:

@@ -323,7 +323,15 @@ def add_counter(name: str, seconds: float, nbytes: int = 0, **more: int) -> None
     restore, the bytes its H2D batchers sent to the device, and
     ``off_caller=`` those whose ``device_put`` ran on the restore's
     dispatcher thread, ``on_caller=`` those on the thread that flushed,
-    ``batches=`` the calls, io_preparers/array.H2DThreads; ``arena_turn``:
+    ``batches=`` the calls, ``leaves=`` the arrays they sent, ``cpu_s=``
+    the calling thread's CPU seconds inside them,
+    io_preparers/array.H2DThreads; ``restore_cpu``: one occurrence a
+    restore, the payload bytes its plans read, ``s`` the process's CPU
+    seconds over the call, and ``driver_s=``, ``loop_s=``, ``fs_s=``,
+    ``dispatcher_s=``, ``lander_s=`` those of the calling thread, the read
+    pipeline's thread, the storage plug-in's read jobs and the two H2D
+    threads, ``call_s=`` the call's wall, snapshot.Snapshot.restore;
+    ``arena_turn``:
     one occurrence a restore, the bytes of the ranges of its host arena
     that completed a turn, and ``ranges=``, ``dropped=``, ``turn_bs=``,
     ``<stage>_s=`` and ``<stage>_bs=`` for each of the eight stages of a

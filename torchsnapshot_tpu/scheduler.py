@@ -953,7 +953,10 @@ class _DirectReads:
 
     A read's ``io_slot_wait`` is the time from its memory in hand to its
     start, recorded by the worker that starts it when it is 1 ms or more;
-    ``wakes`` counts the wake-ups posted."""
+    ``wakes`` counts the wake-ups posted; ``cpu_s`` is the CPU seconds the
+    jobs spent, one ``time.thread_time`` delta a job (the reads it ran),
+    added under the lock with the job's last completion, so that every job
+    whose completions have all been taken off is in it."""
 
     def __init__(
         self,
@@ -973,6 +976,7 @@ class _DirectReads:
         self._woken = False  # a wake-up is posted and its completions not taken
         self._closed = False
         self.wakes = 0
+        self.cpu_s = 0.0
 
     def start(self, pipeline: _ReadPipeline) -> None:
         """On the loop's thread: ``pipeline``'s memory is in hand, and its
@@ -991,6 +995,7 @@ class _DirectReads:
             self._finished(pipeline, e, pipeline.in_hand, time.monotonic())
 
     def _run(self, pipeline: Optional[_ReadPipeline]) -> None:
+        cpu = time.thread_time()
         while pipeline is not None:
             began = time.monotonic()
             error: Optional[BaseException] = None
@@ -1002,7 +1007,7 @@ class _DirectReads:
                     pipeline.read_blocking(self._read)
                 except BaseException as e:  # noqa: BLE001 -- the loop's to raise
                     error = e
-            pipeline = self._finished(pipeline, error, began, time.monotonic())
+            pipeline = self._finished(pipeline, error, began, time.monotonic(), cpu)
 
     def _finished(
         self,
@@ -1010,9 +1015,12 @@ class _DirectReads:
         error: Optional[BaseException],
         began: float,
         ended: float,
+        cpu: Optional[float] = None,
     ) -> Optional[_ReadPipeline]:
         """Post the completion; return the next queued read, the slot handed
-        on with it, or None and the slot freed."""
+        on with it, or None and the slot freed (and, given ``cpu``, the
+        worker's ``thread_time`` at the job's start, the job's CPU seconds
+        counted)."""
         with self._lock:
             self._done.append((pipeline, error, began, ended))
             wake = not (self._woken or self._closed)
@@ -1023,6 +1031,8 @@ class _DirectReads:
             if following is None:
                 self._free += 1
                 self._idle.notify_all()
+                if cpu is not None:
+                    self.cpu_s += time.thread_time() - cpu
         if wake:
             try:
                 self._loop.call_soon_threadsafe(self._wake)
@@ -1062,8 +1072,11 @@ class ReadAhead:
     flight and joins the thread.
 
     After ``close()``, ``read_ahead_s`` and ``read_ahead_bytes`` say how
-    much was read ahead (see ``_read_ahead``) and ``read_loop`` what the
-    pipeline's loop did (the counter of that name, ``execute_read_reqs``)."""
+    much was read ahead (see ``_read_ahead``), ``read_loop`` what the
+    pipeline's loop did (the counter of that name, ``execute_read_reqs``),
+    ``loop_cpu_s`` the CPU seconds of the pipeline's thread from its start
+    to its end, and ``fs_cpu_s`` those of the jobs the plug-in's workers ran
+    for it (``_DirectReads``; 0 on the task path)."""
 
     def __init__(
         self,
@@ -1087,6 +1100,7 @@ class ReadAhead:
         self.read_ahead_s = 0.0
         self.read_ahead_bytes = 0
         self.read_loop: Dict[str, int] = {}
+        self.loop_cpu_s = self.fs_cpu_s = 0.0
         # The coroutine, not the groups, goes to the thread: it drops its
         # own reference to the requests once they are queued, so nothing
         # here keeps a loaded group's host buffers alive.
@@ -1142,6 +1156,7 @@ class ReadAhead:
     # ----------------------------------------------- the pipeline's thread
 
     def _run(self, coro: Awaitable[None], tag: Optional[str]) -> None:
+        began = time.thread_time()
         try:
             with phase_stats.tagged(tag) if tag else contextlib.nullcontext():
                 self._loop.run_until_complete(coro)
@@ -1156,6 +1171,8 @@ class ReadAhead:
                 self._finished = True
                 self._cond.notify_all()
             self._loop.close()
+            # Read by the loader once close() has joined this thread.
+            self.loop_cpu_s = time.thread_time() - began
 
     def _attach(self) -> asyncio.Event:
         with self._cond:
@@ -1628,6 +1645,8 @@ async def execute_read_reqs(
         phase_stats.add_counter("read_loop", 0.0, 0, **loop_stats)
         if loader is not None:
             loader.read_loop = loop_stats
+            # Every read is taken off, so every job's CPU seconds are in.
+            loader.fs_cpu_s = direct.cpu_s if direct is not None else 0.0
             loader.read_ahead_s, loader.read_ahead_bytes = _read_ahead(
                 reads, loader._loaded()
             )

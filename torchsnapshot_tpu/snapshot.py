@@ -611,7 +611,19 @@ class Snapshot:
         for it to land, both started before the first read is issued and
         joined when the call ends; the ``h2d_dispatch_route`` counter and
         the ``restore.end`` entry of that name say how many bytes went that
-        way (``off_caller``).
+        way (``off_caller``), in how many calls and ``leaves``, and the CPU
+        seconds the calling thread spent inside the calls (``cpu_s``).
+
+        **The call's own threads account their CPU against its wall**: the
+        ``restore_cpu`` counter and the ``restore.end`` entry of that name,
+        one occurrence a call, hold the process's CPU seconds over the call
+        (``s``: the native pool, the runtime's threads and the arena's
+        population too), this thread's (``driver_s``), the read pipeline's
+        (``loop_s``), the storage plug-in's read jobs summed (``fs_s``), the
+        H2D dispatcher's and lander's (``dispatcher_s``, ``lander_s``; 0
+        where they never started), the call's wall (``call_s``) and the
+        payload bytes its plans read (``bytes``).  Each thread reads its own
+        clock when it starts and when it ends, so nothing is read per read.
 
         **The call accounts for its arena's turn and its own overlap**: the
         ``arena_turn`` counter (each range of the arena stamped from its
@@ -647,6 +659,9 @@ class Snapshot:
         # takes; the next stateful's reads run under them by design.
         opening = phase_stats.open_interval("restore_open")
         begin = opening.begin
+        # The CPU the call costs, the process's and this thread's (the
+        # restore_cpu counter): two clock reads here, two at the end.
+        cpu_began, driver_began = time.process_time(), time.thread_time()
         self._validate_app_state(app_state)
         pg = self._pg
         rank = pg.get_rank()
@@ -732,6 +747,7 @@ class Snapshot:
                             plans[-1], time.monotonic() - planned_at
                         )
                     leaves = sum(len(plan.futures) for plan in plans if plan)
+                    restored_bytes = sum(_plan_bytes(plan) for plan in plans if plan)
                     # The H2D dispatcher and lander, before the first read
                     # is issued: never started under read load.
                     host_pool.start_threads()
@@ -790,6 +806,20 @@ class Snapshot:
             finally:
                 storage.sync_close()
             end = time.monotonic()
+            # Each of the call's own threads against the call's wall: this
+            # one's and the whole process's CPU over the call, the read
+            # pipeline's thread, the plug-in's read jobs, and the H2D
+            # dispatcher and lander (each joined by now).
+            restore_cpu = {
+                "s": time.process_time() - cpu_began,
+                "bytes": restored_bytes,
+                "driver_s": time.thread_time() - driver_began,
+                "loop_s": pipeline.loop_cpu_s,
+                "fs_s": pipeline.fs_cpu_s,
+                "dispatcher_s": host_pool.h2d_threads.dispatcher.cpu_s,
+                "lander_s": host_pool.h2d_threads.lander.cpu_s,
+                "call_s": end - begin,
+            }
             # This one call's account: each phase's wall inside it, and what
             # no phase covers, as a number of its own (a counter: it has no
             # interval, so it can name no gap of a trace).
@@ -823,6 +853,8 @@ class Snapshot:
             # bytes the arena lends, and for how long.
             turned = host_pool.turn_stats()
             _add_counter_of("arena_turn", turned)
+            # What the call's threads spent on the CPU, beside its wall.
+            _add_counter_of("restore_cpu", restore_cpu)
             # What restoring an RNGState, planned and loaded last, added.
             if rng_restore is not None:
                 rng_restore["s"] = rng_restore["plan_s"] + rng_restore["tail_s"]
@@ -836,6 +868,7 @@ class Snapshot:
             event_metadata["unattributed_s"] = unattributed_s
             event_metadata["restore_overlap"] = overlap
             event_metadata["arena_turn"] = turned
+            event_metadata["restore_cpu"] = restore_cpu
             event_metadata["h2d_land_slow"] = {
                 "s": slow.get("s", 0.0),
                 "n": int(slow.get("n", 0)),
@@ -1827,19 +1860,23 @@ def _add_counter_of(name: str, stats: Dict[str, Any]) -> None:
     phase_stats.add_counter(name, more.pop("s", 0.0), more.pop("bytes", 0), **more)
 
 
-def _rng_restore_plan(plan: "_StatefulPlan", plan_s: float) -> Dict[str, Any]:
-    """The ``rng_restore`` account of the RNG state's plan: the manifest
-    entries planned for it (containers and primitives included), the
-    payload bytes its reads will take, and ``plan_s`` its ``plan_read``
-    wall; ``Snapshot.restore`` adds ``tail_s`` once it is loaded."""
-    nbytes = sum(
+def _plan_bytes(plan: "_StatefulPlan") -> int:
+    """The payload bytes a stateful's planned reads will take."""
+    return sum(
         rr.byte_range[1] - rr.byte_range[0]
         if rr.byte_range is not None
         else rr.buffer_consumer.get_consuming_cost_bytes()
         for rr in plan.read_reqs
     )
+
+
+def _rng_restore_plan(plan: "_StatefulPlan", plan_s: float) -> Dict[str, Any]:
+    """The ``rng_restore`` account of the RNG state's plan: the manifest
+    entries planned for it (containers and primitives included), the
+    payload bytes its reads will take, and ``plan_s`` its ``plan_read``
+    wall; ``Snapshot.restore`` adds ``tail_s`` once it is loaded."""
     return {
-        "bytes": nbytes,
+        "bytes": _plan_bytes(plan),
         "entries": len(plan.futures) + len(plan.container_entries),
         "plan_s": plan_s,
         "tail_s": 0.0,
