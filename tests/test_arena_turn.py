@@ -1,5 +1,5 @@
 """A range's turn through the restore's host arena, measured where it happens
-(``io_preparers.array.HostBufferPool``: the counter ``arena_turn``), the call
+(``h2d.HostBufferPool``: the counter ``arena_turn``), the call
 split by which of reads and H2D were under way (``Snapshot.restore``'s
 ``restore_overlap``), and a landing that stalls counted (``h2d_land_slow``).
 
@@ -26,13 +26,14 @@ from torchsnapshot_tpu.event_handlers import (
     register_event_handler,
     unregister_event_handler,
 )
+from torchsnapshot_tpu import h2d as h2d_mod
+from torchsnapshot_tpu.h2d import H2DBatcher, HostBufferPool
 from torchsnapshot_tpu.io_preparers import array as array_mod
-from torchsnapshot_tpu.io_preparers.array import H2DBatcher, HostBufferPool
 from torchsnapshot_tpu.io_types import Future
 
-PAGE = array_mod._PAGE
-STAGES = array_mod._STAGES
-STAMPS = array_mod._STAMPS  # the stamp that ends each stage but the last: the give's
+PAGE = h2d_mod._PAGE
+STAGES = h2d_mod._STAGES
+STAMPS = h2d_mod._STAMPS  # the stamp that ends each stage but the last: the give's
 ON_A_CHIP = types.SimpleNamespace(devices=lambda: [types.SimpleNamespace(platform="tpu")])
 ARENA_READERS = ("arena_turn_s", "arena_wait_pct.resume", "arena_h2d_side_pct.resume",
                  "arena_stranded_pct.resume")
@@ -56,30 +57,20 @@ class Clock:
 @pytest.fixture
 def clock(monkeypatch):
     clock = Clock()
-    monkeypatch.setattr(array_mod, "_now", clock)
+    monkeypatch.setattr(h2d_mod, "_now", clock)
     return clock
 
 
-class Batcher:  # what the pool knows of an H2DBatcher
-    expects_uploads = True
-
-    def __init__(self, cap):
-        self.inflight_cap_bytes = cap
-
-    def flush(self):
-        pass
-
-
 def pool_of(*groups, window):
-    """A pool with ``groups`` of leaves reserved, in pages, behind a batcher
-    (to be kept alive) whose in-flight cap, ``window`` pages, is the arena."""
-    pool, batcher = HostBufferPool(), Batcher(window * PAGE)
-    pool.attach(batcher)
+    """A pool with ``groups`` of leaves reserved, in pages, whose one batcher's
+    in-flight cap, ``window`` pages, is the arena."""
+    pool = HostBufferPool()
+    pool.batcher.inflight_cap_bytes = window * PAGE
     for group in groups:
         pool.begin_group()
         for pages in group:
             pool.reserve(pages * PAGE, ON_A_CHIP)
-    return pool, batcher
+    return pool, pool.batcher
 
 
 def memory_at(nbytes, offset_from_64):
@@ -304,7 +295,7 @@ def test_the_first_read_began_stands_and_the_last_of_every_other_stamp(clock, mo
     from torchsnapshot_tpu.manifest import TensorEntry
 
     pool, _batcher = pool_of([2, 2], [2, 2], window=2)
-    batcher = types.SimpleNamespace(host_pool=pool, expects_uploads=False, submitted=[])
+    batcher = types.SimpleNamespace(host_pool=pool, submitted=[])
     batcher.submit = lambda *item: batcher.submitted.append(item)
     monkeypatch.setattr(array_mod.staging, "is_jax_array", lambda obj: obj is ON_A_CHIP)
     monkeypatch.setattr(array_mod, "_INTO_PLACE_MIN_BYTES", PAGE)
@@ -346,14 +337,16 @@ def test_the_batcher_stamps_sent_and_put_on_the_dispatcher(clock, monkeypatch):
     """Through a real batcher with the pool's threads: ``gather`` ends once the
     dispatcher has the batch and window room, ``dispatch`` when the
     ``device_put`` has returned, ``land`` at the lander's give."""
-    monkeypatch.setattr(array_mod, "_keeps_host_memory", lambda target: False)
+    monkeypatch.setattr(h2d_mod, "_keeps_host_memory", lambda target: False)
     # (the CPU backend copies a range that begins 16 bytes past a 64-byte
     # boundary, as an accelerator copies any: the landed array is its own)
-    monkeypatch.setattr(array_mod, "_arena_memory", lambda nbytes: memory_at(nbytes, 16))
+    monkeypatch.setattr(h2d_mod, "_arena_memory", lambda nbytes: memory_at(nbytes, 16))
     import jax
 
     pool = HostBufferPool()
-    batcher = H2DBatcher(flush_bytes=1 << 30, inflight_cap_bytes=2 * PAGE, host_pool=pool)
+    pool.batcher = batcher = H2DBatcher(
+        pool, flush_bytes=1 << 30, inflight_cap_bytes=2 * PAGE
+    )
     like = jnp.zeros(2 * PAGE // 4, jnp.float32)
     for _ in range(2):
         pool.begin_group()
@@ -571,17 +564,19 @@ def test_a_landing_that_stalls_is_counted_once_and_a_fast_one_never(monkeypatch,
         monkeypatch.setattr(jax, "device_put", put)
     like = jnp.zeros(16, jnp.float32)
     before = phase_stats.snapshot()
-    with caplog.at_level(logging.INFO, logger=array_mod.logger.name):
-        for stall["s"] in (0.0, array_mod._SLOW_LANDING_S + 0.05, 0.0):
-            batcher = H2DBatcher(flush_bytes=64)
+    with caplog.at_level(logging.INFO, logger=h2d_mod.logger.name):
+        for stall["s"] in (0.0, h2d_mod._SLOW_LANDING_S + 0.05, 0.0):
+            pool = HostBufferPool()
+            pool.batcher = batcher = H2DBatcher(pool, flush_bytes=64)
             futs = [Future(), Future()]
             for i, fut in enumerate(futs):
                 batcher.submit(np.full(16, float(i), dtype=np.float32), like, fut)
             batcher.drain()
+            pool.close()
             assert [float(np.asarray(f.obj)[0]) for f in futs] == [0.0, 1.0]
     delta = phase_stats.delta(before)
     slow = delta["h2d_land_slow"]
-    assert slow["n"] == 1 and slow["s"] >= array_mod._SLOW_LANDING_S and "wall" not in slow
+    assert slow["n"] == 1 and slow["s"] >= h2d_mod._SLOW_LANDING_S and "wall" not in slow
     assert 0 < slow["bytes"] <= 128 and delta["h2d_land"]["n"] >= 3
     said = [r for r in caplog.records if "slow H2D landing" in r.getMessage()]
     assert len(said) == 1 and said[0].levelno == logging.INFO
@@ -594,8 +589,8 @@ def test_a_landing_that_stalls_is_counted_once_and_a_fast_one_never(monkeypatch,
 def test_a_large_landing_that_takes_long_at_a_healthy_rate_is_not_slow():
     """Half a second and more is slow only under half a gigabyte a second."""
     before = phase_stats.snapshot()
-    array_mod._note_slow_landing(0.6, int(0.6 * array_mod._SLOW_LANDING_BYTES_PER_S), 1)
-    array_mod._note_slow_landing(0.49, 8, 1)
+    h2d_mod._note_slow_landing(0.6, int(0.6 * h2d_mod._SLOW_LANDING_BYTES_PER_S), 1)
+    h2d_mod._note_slow_landing(0.49, 8, 1)
     assert "h2d_land_slow" not in phase_stats.delta(before)
-    array_mod._note_slow_landing(0.6, int(0.6 * array_mod._SLOW_LANDING_BYTES_PER_S) - 1, 1)
+    h2d_mod._note_slow_landing(0.6, int(0.6 * h2d_mod._SLOW_LANDING_BYTES_PER_S) - 1, 1)
     assert phase_stats.delta(before)["h2d_land_slow"]["n"] == 1

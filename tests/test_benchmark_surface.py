@@ -22,8 +22,8 @@ import types
 import pytest
 
 from torchsnapshot_tpu import knobs, phase_stats
-from torchsnapshot_tpu.io_preparers import array as array_mod
-from torchsnapshot_tpu.io_preparers.array import HostBufferPool
+from torchsnapshot_tpu import h2d as h2d_mod
+from torchsnapshot_tpu.h2d import HostBufferPool
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -152,20 +152,16 @@ def test_per_layer_metric_finds_what_it_reads(metric, lines):
 
 def a_pool_that_makes_an_arena():
     """Two statefuls of two leaves of a page behind a window of two pages
-    (the arena); the batcher is to be kept alive."""
-    page = array_mod._PAGE
-
-    class Batcher:  # what the pool knows of an H2DBatcher
-        inflight_cap_bytes = 2 * page
-
-    pool, batcher = HostBufferPool(), Batcher()
-    pool.attach(batcher)
+    (the arena), the in-flight cap of the pool's one batcher."""
+    page = h2d_mod._PAGE
+    pool = HostBufferPool()
+    pool.batcher.inflight_cap_bytes = 2 * page
     on_a_chip = types.SimpleNamespace(devices=lambda: [types.SimpleNamespace(platform="tpu")])
     for _ in range(2):
         pool.begin_group()
         pool.reserve(page, on_a_chip)
         pool.reserve(page, on_a_chip)
-    return pool, batcher
+    return pool, pool.batcher
 
 
 def arena_populate_s_reads_the_pools_phase(metric):
@@ -176,14 +172,14 @@ def arena_populate_s_reads_the_pools_phase(metric):
     read = importlib.import_module("chipbench.metrics." + metric["name"]).read
     restores = [{"op": "kill_resume", "ok": True}] * 2
     account = types.SimpleNamespace(window_operations=lambda op: restores)
-    page = array_mod._PAGE
+    page = h2d_mod._PAGE
     pool, _batcher = a_pool_that_makes_an_arena()
     before = phase_stats.snapshot()
     assert read({"account": account, "phases": phase_stats.delta(before)}) is None
     pool.take(page)
     pool.take(page)
     phases = phase_stats.delta(before)
-    if array_mod._page_toucher() is None:  # no native library here: nothing populates
+    if h2d_mod._page_toucher() is None:  # no native library here: nothing populates
         assert "arena_populate" not in phases and pool.stats()["populated"] == 0
         return
     assert phases["arena_populate"]["n"] == 1 and pool.stats()["populated"] == 2 * page
@@ -201,7 +197,7 @@ def an_arena_reader_reads_the_pools_turns(metric):
     from chipbench import job as chipbench_job
 
     read = chipbench_job.load_module("metrics", metric["name"], "metric").read
-    page = array_mod._PAGE
+    page = h2d_mod._PAGE
     pool, _batcher = a_pool_that_makes_an_arena()
 
     def recorded(stats):
@@ -214,9 +210,9 @@ def an_arena_reader_reads_the_pools_turns(metric):
     assert read({"phases": {}, "counters": {}}) is None
     assert read(recorded(pool.turn_stats())) is None
     for buf in (pool.take(page), pool.take(page)):
-        for stamp in array_mod._STAMPS:
+        for stamp in h2d_mod._STAMPS:
             time.sleep(0.001)
-            setattr(pool.turn_of(buf), stamp, array_mod._now())
+            setattr(pool.turn_of(buf), stamp, h2d_mod._now())
         time.sleep(0.001)
         pool.give(buf, recycle=True)
     stats = pool.turn_stats()
@@ -245,17 +241,9 @@ def the_stranded_reader_reads_a_pool_in_which_a_take_waited(metric):
     from chipbench import job as chipbench_job
 
     read = chipbench_job.load_module("metrics", metric["name"], "metric").read
-    page = array_mod._PAGE
+    page = h2d_mod._PAGE
     pool = HostBufferPool()
-
-    class Batcher:  # what the pool knows of an H2DBatcher
-        inflight_cap_bytes = 3 * page
-
-        def flush(self):
-            pass
-
-    batcher = Batcher()
-    pool.attach(batcher)
+    pool.batcher.inflight_cap_bytes = 3 * page
     on_a_chip = types.SimpleNamespace(devices=lambda: [types.SimpleNamespace(platform="tpu")])
     for _ in range(2):
         pool.begin_group()

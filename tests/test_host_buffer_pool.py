@@ -1,8 +1,8 @@
-"""The host arena of a restore (``io_preparers.array.HostBufferPool``, one a
+"""The host arena of a restore (``h2d.HostBufferPool``, one a
 ``Snapshot.restore``): a leaf takes a page-aligned range of it when its read is
 dispatched and the lander gives the range back when the H2D has landed, to
 whichever read comes next, of any size and any stateful, which waits for room
-where there is none and makes the batchers flush; the arena is populated in
+where there is none and makes the batcher flush; the arena is populated in
 bulk, once, before its first range is handed out.  Restores of train-state-
 shaped trees (three statefuls, one tree) through the fs plug-in, the reads one
 at a time so that each has its number, as tasks and on the slot hand-over (the
@@ -31,13 +31,13 @@ from torchsnapshot_tpu.event_handlers import (
     unregister_event_handler,
 )
 from torchsnapshot_tpu.integrity import ChecksumError
-from torchsnapshot_tpu.io_preparers import array as array_mod
-from torchsnapshot_tpu.io_preparers.array import H2DBatcher, HostBufferPool
+from torchsnapshot_tpu import h2d as h2d_mod
+from torchsnapshot_tpu.h2d import H2DBatcher, HostBufferPool
 from torchsnapshot_tpu.io_types import Future, StoragePlugin
 
 KEYS = ("a_params", "b_mu", "c_nu")  # loaded in the order of their names
 MIB = 1 << 20
-PAGE = array_mod._PAGE
+PAGE = h2d_mod._PAGE
 # float32 leaves of 1, 2 and 3 MiB: from the size a read lands in place
 SHAPES = ((256, 1024), (512, 1024), (768, 1024))
 LEAVES = len(SHAPES)
@@ -181,17 +181,13 @@ def world(monkeypatch, tmp_path, request):
     class RecordedPool(HostBufferPool):
         def __init__(self):
             super().__init__()
+            self.batcher = H2DBatcher(self, **w.batcher_args)
             w.pools.append(weakref.ref(self))
 
     monkeypatch.setattr(snapshot_mod, "HostBufferPool", RecordedPool)
+    really_keeps = h2d_mod._keeps_host_memory
     monkeypatch.setattr(
-        snapshot_mod,
-        "H2DBatcher",
-        lambda **kwargs: H2DBatcher(**w.batcher_args, **kwargs),
-    )
-    really_keeps = array_mod._keeps_host_memory
-    monkeypatch.setattr(
-        array_mod,
+        h2d_mod,
         "_keeps_host_memory",
         lambda target: not w.accelerator and really_keeps(target),
     )
@@ -203,9 +199,9 @@ def world(monkeypatch, tmp_path, request):
         w.addresses.append((buf.ctypes.data, nbytes))
         return buf
 
-    monkeypatch.setattr(array_mod, "_arena_memory", recorded_arena)
+    monkeypatch.setattr(h2d_mod, "_arena_memory", recorded_arena)
 
-    really_touches = array_mod._page_toucher()
+    really_touches = h2d_mod._page_toucher()
 
     def recorded_touch(buf):
         w.touches.append(
@@ -215,7 +211,7 @@ def world(monkeypatch, tmp_path, request):
             really_touches(buf)
 
     monkeypatch.setattr(
-        array_mod, "_page_toucher", lambda: recorded_touch if w.populates else None
+        h2d_mod, "_page_toucher", lambda: recorded_touch if w.populates else None
     )
 
     def recorded_buffer(nbytes):
@@ -224,7 +220,7 @@ def world(monkeypatch, tmp_path, request):
         w.addresses.append((buf.ctypes.data, nbytes))
         return buf
 
-    monkeypatch.setattr(array_mod, "_fresh_host_buffer", recorded_buffer)
+    monkeypatch.setattr(h2d_mod, "_fresh_host_buffer", recorded_buffer)
 
     real_plan = Snapshot._plan_stateful_reads
 
@@ -519,7 +515,7 @@ def test_a_failure_returns_its_range_and_leaves_no_arena_behind(
         # lands there, and its range is given back all the same
         real_put = jax.device_put
         # (the warning's traceback, kept by the log capture, would hold the pool)
-        monkeypatch.setattr(array_mod.logger, "warning", lambda *args, **kwargs: None)
+        monkeypatch.setattr(h2d_mod.logger, "warning", lambda *args, **kwargs: None)
 
         def no_batches(x, *args, **kwargs):
             if isinstance(x, list):
@@ -559,7 +555,7 @@ def test_chunked_leaves_go_through_the_pool(world):
     assert nothing_left(world)
 
 
-STAGES = array_mod._STAGES
+STAGES = h2d_mod._STAGES
 
 
 def assert_turns_add_up(end):
@@ -645,7 +641,7 @@ def test_a_range_whose_landing_failed_is_dropped_and_the_rest_still_add_up(
     saved = make_app(13)
     path = take(world, "snap", saved)
     target = make_app(0, zero=True)
-    monkeypatch.setattr(array_mod, "_may_alias", lambda out, buf: True)
+    monkeypatch.setattr(h2d_mod, "_may_alias", lambda out, buf: True)
     _, end = restore(world, path, target)
     assert_equal_bits(target, saved)
     turn = assert_turns_add_up(end)
@@ -734,10 +730,10 @@ def test_a_library_without_the_symbol_restores_as_before_and_says_nothing(world,
 def test_a_restore_through_a_populated_arena_is_bit_exact(world, monkeypatch):
     """The arena arrives full of ones, so the byte that the population
     writes to each page is there to be seen; every leaf is read over it."""
-    if array_mod._page_toucher() is None:
+    if h2d_mod._page_toucher() is None:
         pytest.skip("the native library has no tpusnap_touch_pages")
     arenas = []
-    recorded = array_mod._arena_memory
+    recorded = h2d_mod._arena_memory
 
     def full_of_ones(nbytes):
         buf = recorded(nbytes)
@@ -745,15 +741,15 @@ def test_a_restore_through_a_populated_arena_is_bit_exact(world, monkeypatch):
         arenas.append(buf)
         return buf
 
-    monkeypatch.setattr(array_mod, "_arena_memory", full_of_ones)
+    monkeypatch.setattr(h2d_mod, "_arena_memory", full_of_ones)
     seen = []
-    touch = array_mod._page_toucher()
+    touch = h2d_mod._page_toucher()
 
     def touched_and_looked_at(buf):
         touch(buf)
         seen.append(int((buf == 0).sum()))
 
-    monkeypatch.setattr(array_mod, "_page_toucher", lambda: touched_and_looked_at)
+    monkeypatch.setattr(h2d_mod, "_page_toucher", lambda: touched_and_looked_at)
     shapes = ((256, 1024), (513, 1024), (770, 1024))  # leaves that end inside a page
     saved = make_app(12, shapes=shapes)
     path = take(world, "snap", saved)
@@ -775,23 +771,20 @@ ON_A_CHIP = types.SimpleNamespace(devices=lambda: [types.SimpleNamespace(platfor
 ON_THE_HOST = types.SimpleNamespace(devices=lambda: [types.SimpleNamespace(platform="cpu")])
 
 
-class Batcher:
-    """What the pool knows of an ``H2DBatcher``."""
-
-    def __init__(self, inflight_cap_bytes):
-        self.inflight_cap_bytes = inflight_cap_bytes
-        self.flushes = 0
-
-    def flush(self):
-        self.flushes += 1
-
-
 def pool_of(*groups, window, target=ON_A_CHIP):
-    """A pool with ``groups`` of leaves reserved, in pages, behind one
-    batcher (to be kept alive) with an in-flight cap of ``window`` pages: the
-    arena's size, where no leaf is larger and a stateful is."""
-    pool, batcher = HostBufferPool(), Batcher(window * PAGE)
-    pool.attach(batcher)
+    """A pool with ``groups`` of leaves reserved, in pages, whose one batcher
+    has an in-flight cap of ``window`` pages (the arena's size, where no leaf
+    is larger and a stateful is) and counts its flushes (``flushes``)."""
+    pool = HostBufferPool()
+    batcher = pool.batcher
+    batcher.inflight_cap_bytes = window * PAGE
+    real_flush, batcher.flushes = batcher.flush, 0
+
+    def flush():
+        batcher.flushes += 1
+        real_flush()
+
+    batcher.flush = flush
     for group in groups:
         pool.begin_group()
         for pages in group:
@@ -832,7 +825,7 @@ def test_a_take_is_a_range_a_give_frees_it_and_neighbours_coalesce():
         "hits": 2,
         "misses": 5,
         "high_water": 8 * PAGE + 5 * PAGE,
-        "populated": 8 * PAGE if array_mod._page_toucher() else 0,
+        "populated": 8 * PAGE if h2d_mod._page_toucher() else 0,
     }
 
 
@@ -851,7 +844,7 @@ def test_a_take_with_no_room_flushes_the_batchers_and_waits_its_turn():
         a, b, c = (pool.take(4 * PAGE, loop) for _ in range(3))
         base = a.ctypes.data
         assert not pool.waiting() and batcher.flushes == 0
-        first = pool.take(6 * PAGE, loop)  # no room: what the batchers hold goes
+        first = pool.take(6 * PAGE, loop)  # no room: what the batcher holds goes
         assert isinstance(first, asyncio.Future) and not first.done()
         assert pool.waiting() and batcher.flushes == 1
         landed(b)  # four pages free in the middle: not six
@@ -876,17 +869,19 @@ def test_a_read_with_no_room_is_served_by_a_batch_that_was_only_queued(
     monkeypatch, dispatcher
 ):
     """The liveness argument with the flush a hand-off: a read that finds no
-    room flushes the batchers, which now only queues, and still the leaf
+    room flushes the batcher, which now only queues, and still the leaf
     gathered there is sent, lands and frees its range, whether the dispatcher
     was idle (nothing had reached ``flush_bytes``) or busy with the batch
     before (then the queued one goes when it comes free: a waiter never waits
     on a batch that nobody will dispatch).  While a batch is only queued it
     holds its range of the arena and nothing on the device."""
     monkeypatch.setattr(
-        array_mod, "_arena_memory", lambda nbytes: memory_at(nbytes, 16)[1]
+        h2d_mod, "_arena_memory", lambda nbytes: memory_at(nbytes, 16)[1]
     )
     pool = HostBufferPool()
-    batcher = H2DBatcher(host_pool=pool, flush_bytes=1 << 30, inflight_cap_bytes=8 * PAGE)
+    pool.batcher = batcher = H2DBatcher(
+        pool, flush_bytes=1 << 30, inflight_cap_bytes=8 * PAGE
+    )
     pool.begin_group()
     for _ in range(3):
         pool.reserve(4 * PAGE, ON_A_CHIP)
@@ -937,7 +932,7 @@ def test_a_read_with_no_room_is_served_by_a_batch_that_was_only_queued(
         for fut, fill in zip(futures, (1.0, 2.0)):
             np.testing.assert_array_equal(np.asarray(fut.obj), np.full(PAGE, fill))
         assert set(puts) == {"tpusnap-h2d-dispatcher"}
-        assert pool.h2d_threads.route()["off_caller"] == 8 * PAGE
+        assert pool.batcher.route()["bytes"] == 8 * PAGE
     finally:
         gate.set()
         pool.close()
@@ -992,7 +987,7 @@ def test_a_range_given_back_unfit_ends_the_arena():
     [
         # every leaf fits
         pytest.param([[1, 2, 4, 8, 8, 16, 16]] * 3, 10, ON_A_CHIP, 16, id="the_largest_leaf"),
-        # many small leaves: what the batchers may have in flight
+        # many small leaves: what the batcher may have in flight
         pytest.param([[1] * 30 + [6] * 5] * 3, 20, ON_A_CHIP, 20, id="the_window"),
         pytest.param([[2, 3], [2, 3], [1]], 1 << 17, ON_A_CHIP, 5, id="the_largest_stateful"),
         pytest.param([[8]], 4, ON_A_CHIP, None, id="one_leaf"),
@@ -1004,9 +999,9 @@ def test_the_arena_is_sized_from_what_the_plan_reserved(
     monkeypatch, groups, window, target, pages
 ):
     made = []
-    real = array_mod._arena_memory
+    real = h2d_mod._arena_memory
     monkeypatch.setattr(
-        array_mod, "_arena_memory", lambda nbytes: made.append(nbytes) or real(nbytes)
+        h2d_mod, "_arena_memory", lambda nbytes: made.append(nbytes) or real(nbytes)
     )
     pool, _batcher = pool_of(*groups, window=window, target=target)
     assert not made  # nothing before the first take
@@ -1021,7 +1016,7 @@ def touches(monkeypatch):
     nothing really written."""
     seen = []
     monkeypatch.setattr(
-        array_mod, "_page_toucher", lambda: lambda buf: seen.append((buf.ctypes.data, buf.nbytes))
+        h2d_mod, "_page_toucher", lambda: lambda buf: seen.append((buf.ctypes.data, buf.nbytes))
     )
     return seen
 
@@ -1080,7 +1075,7 @@ def test_the_arena_goes_when_the_restore_ends_and_its_last_range_is_back(monkeyp
         arenas.append(weakref.ref(raw))
         return buf
 
-    monkeypatch.setattr(array_mod, "_arena_memory", recorded)
+    monkeypatch.setattr(h2d_mod, "_arena_memory", recorded)
     pool, _batcher = pool_of([4, 4], [4, 4], window=8)
     a, b = pool.take(4 * PAGE), pool.take(PAGE)
     assert len(arenas) == 1 and pool.stats()["high_water"] == 5 * PAGE

@@ -2,8 +2,8 @@
 ``restore_cpu`` counter (``Snapshot.restore``: the process over the call, the
 calling thread, the read pipeline's thread, the fs plug-in's read jobs, the H2D
 dispatcher and lander), and the ``leaves`` and ``cpu_s`` of
-``h2d_dispatch_route`` (``H2DThreads.count_put``: the dispatcher's calls).  Each
-thread reads its own ``time.thread_time`` when it starts and when it ends; no
+``h2d_dispatch_route`` (``h2d.H2DBatcher._count_put``: the dispatcher's calls).
+Each thread reads its own ``time.thread_time`` when it starts and when it ends; no
 reading is per read.
 
 One restore through an arena (the CPU backend taken for an accelerator, as
@@ -25,8 +25,8 @@ from torchsnapshot_tpu.event_handlers import (
     register_event_handler,
     unregister_event_handler,
 )
-from torchsnapshot_tpu.io_preparers import array as array_mod
-from torchsnapshot_tpu.io_preparers.array import H2DBatcher
+from torchsnapshot_tpu import h2d as h2d_mod
+from torchsnapshot_tpu.h2d import H2DBatcher, HostBufferPool
 from torchsnapshot_tpu.io_types import Future
 
 KEYS = ("a_params", "b_mu", "c_nu")  # loaded in the order of their names
@@ -117,8 +117,8 @@ def through_the_arena(tmp_path_factory):
     Snapshot.take(path, saved)
     target = make_app(seed=7, zero=True)
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(array_mod, "_keeps_host_memory", lambda target: False)
-        mp.setattr(array_mod, "_arena_memory", lambda nbytes: memory_at(nbytes, 16))
+        mp.setattr(h2d_mod, "_keeps_host_memory", lambda target: False)
+        mp.setattr(h2d_mod, "_arena_memory", lambda nbytes: memory_at(nbytes, 16))
         with knobs.override_max_per_rank_io_concurrency(2):
             delta, end = restore(path, target)
     for key in KEYS:
@@ -174,7 +174,7 @@ def test_no_reading_is_more_cpu_than_the_wall_it_lies_in(through_the_arena, held
 def test_a_threads_cpu_is_its_time_on_the_cpu_and_not_its_wall():
     """A worker that spins 30 ms of its own CPU and sleeps 60 ms reads the
     spin, not the sleep: the clock is the thread's."""
-    worker = array_mod._Worker("tpusnap-test-worker")
+    worker = h2d_mod._Worker("tpusnap-test-worker")
 
     def spin():
         until = time.thread_time() + 0.03
@@ -187,7 +187,7 @@ def test_a_threads_cpu_is_its_time_on_the_cpu_and_not_its_wall():
     worker.close()
     wall = time.monotonic() - began
     assert 0.03 <= worker.cpu_s <= wall - 0.06 + TICK
-    never = array_mod._Worker("tpusnap-test-idle")
+    never = h2d_mod._Worker("tpusnap-test-idle")
     never.close()
     assert never.cpu_s == 0.0
 
@@ -204,22 +204,25 @@ def batcher_handed(n, retried, monkeypatch):
             return real(x, *args, **kwargs)
 
         monkeypatch.setattr(jax, "device_put", batched_fails)
-    b = H2DBatcher(flush_bytes=1 << 30)
+    pool = HostBufferPool()
+    pool.batcher = b = H2DBatcher(pool, flush_bytes=1 << 30)
     futs = [Future() for _ in range(n)]
+    before = phase_stats.snapshot()
     for i, fut in enumerate(futs):
         b.submit(np.full(256, float(i), np.float32), jnp.zeros(256, jnp.float32), fut)
     b.drain()
+    pool.close()
     for i, fut in enumerate(futs):
         np.testing.assert_array_equal(np.asarray(fut.obj), np.full(256, float(i)))
-    return b.threads.route()
+    return b.route(), phase_stats.delta(before)["h2d_dispatch"]["n"]
 
 
 @pytest.mark.parametrize("retried", [False, True], ids=["one_call", "per_item_retry"])
 def test_leaves_are_what_the_batcher_was_handed_counted_once(retried, monkeypatch):
-    route = batcher_handed(5, retried, monkeypatch)
+    route, calls = batcher_handed(5, retried, monkeypatch)
     assert route["leaves"] == 5 and route["bytes"] == 5 * 256 * 4
-    assert route["batches"] == (5 if retried else 1)
-    assert route["off_caller"] == route["bytes"] and route["on_caller"] == 0
+    assert calls == (5 if retried else 1)
+    assert sorted(route) == ["bytes", "cpu_s", "leaves"]
     assert 0 <= route["cpu_s"]
 
 

@@ -191,14 +191,15 @@ def test_a_second_restore_accounts_for_itself_alone(tmp_path):
 
 
 def test_a_small_h2d_window_records_h2d_window_wait(tmp_path, monkeypatch):
-    from torchsnapshot_tpu.io_preparers import array as array_mod
+    from torchsnapshot_tpu import h2d as h2d_mod
 
-    real_init = array_mod.H2DBatcher.__init__
-    monkeypatch.setattr(
-        array_mod.H2DBatcher,
-        "__init__",
-        lambda self, *a, **k: real_init(self, flush_bytes=1, inflight_cap_bytes=1),
-    )
+    real_init = h2d_mod.HostBufferPool.__init__
+
+    def pool_with_a_small_window(self):
+        real_init(self)
+        self.batcher = h2d_mod.H2DBatcher(self, flush_bytes=1, inflight_cap_bytes=1)
+
+    monkeypatch.setattr(h2d_mod.HostBufferPool, "__init__", pool_with_a_small_window)
     real_block = jax.block_until_ready
 
     def slow_landing(x):

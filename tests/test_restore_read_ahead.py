@@ -9,6 +9,7 @@ stateful in front; user code stays on the calling thread, in key order; a
 failure ahead leaves what was loaded loaded and no thread behind."""
 
 import asyncio
+import collections
 import gc
 import threading
 import time
@@ -26,8 +27,9 @@ from torchsnapshot_tpu.event_handlers import (
     register_event_handler,
     unregister_event_handler,
 )
+from torchsnapshot_tpu import h2d as h2d_mod
+from torchsnapshot_tpu.h2d import H2DBatcher
 from torchsnapshot_tpu.io_preparers import array as array_mod
-from torchsnapshot_tpu.io_preparers.array import H2DBatcher
 from torchsnapshot_tpu.io_types import BufferConsumer, ReadReq, StoragePlugin
 from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
 
@@ -188,7 +190,7 @@ def world(monkeypatch, request):
     (the CPU backend taken for an accelerator; a weak reference to each arena
     made and its size, when a read has its range, and when one is given
     back), consumes, H2D submits and dispatches, each with its stateful.  A
-    test may give every batcher other arguments (``batcher_args``)."""
+    test may give the restore's batcher other arguments (``batcher_args``)."""
     MemoryStoragePlugin.reset()
     log = Log()
     url = f"memory://read_ahead_{time.monotonic_ns()}"
@@ -207,7 +209,7 @@ def world(monkeypatch, request):
         ),
     )
 
-    plans = {}  # key -> id of its batcher
+    leaves = {}  # id of a leaf's future -> its stateful
     pools = []  # the pool of each restore
     real_plan = Snapshot._plan_stateful_reads
 
@@ -215,7 +217,7 @@ def world(monkeypatch, request):
         plan = real_plan(key, stateful, metadata, rank, host_pool)
         into_place = [rr for rr in plan.read_reqs if rr.into is not None]
         assert len(into_place) == (LEAVES if key in KEYS else 0)
-        plans[key] = id(plan.h2d_batch)
+        leaves.update((id(fut), key) for fut in plan.futures.values())
         if host_pool not in pools:
             pools.append(host_pool)
         return plan
@@ -230,32 +232,37 @@ def world(monkeypatch, request):
         arena_sizes.append(nbytes)
         return buf
 
-    monkeypatch.setattr(array_mod, "_arena_memory", recording_arena)
-    monkeypatch.setattr(array_mod, "_keeps_host_memory", lambda target: False)
+    monkeypatch.setattr(h2d_mod, "_arena_memory", recording_arena)
+    monkeypatch.setattr(h2d_mod, "_keeps_host_memory", lambda target: False)
     plain = []  # the size of every plain buffer made beside an arena
     monkeypatch.setattr(
-        array_mod,
+        h2d_mod,
         "_fresh_host_buffer",
         lambda nbytes: plain.append(nbytes) or np.empty(nbytes, dtype=np.uint8),
     )
     batcher_args = {}
-    monkeypatch.setattr(
-        snapshot_mod,
-        "H2DBatcher",
-        lambda **kwargs: H2DBatcher(**batcher_args, **kwargs),
-    )
+    real_pool_init = h2d_mod.HostBufferPool.__init__
 
-    def key_of_batcher(batcher):
-        return next(k for k, ident in plans.items() if ident == id(batcher))
+    def pool_with_batcher_args(self):
+        real_pool_init(self)
+        self.batcher = H2DBatcher(self, **batcher_args)
 
-    real_give = array_mod.HostBufferPool.give
+    monkeypatch.setattr(h2d_mod.HostBufferPool, "__init__", pool_with_batcher_args)
+
+    def key_of_items(items):
+        """The stateful of the leaves ``items``, or all of theirs where they
+        are not one stateful's."""
+        keys = sorted({leaves[id(fut)] for _, _, fut, _ in items})
+        return keys[0] if len(keys) == 1 else tuple(keys)
+
+    real_give = h2d_mod.HostBufferPool.give
     lent_to = {}  # where a range begins -> the stateful whose leaf is in it
 
     def recording_give(self, buf, recycle):
         log.add("buffer_back", lent_to.pop(buf.ctypes.data))
         return real_give(self, buf, recycle)
 
-    monkeypatch.setattr(array_mod.HostBufferPool, "give", recording_give)
+    monkeypatch.setattr(h2d_mod.HostBufferPool, "give", recording_give)
     real_take = scheduler_mod._ReadPipeline.take_memory
     real_consume = scheduler_mod._ReadPipeline.consume_buffer
 
@@ -286,13 +293,13 @@ def world(monkeypatch, request):
     real_submit, real_dispatch = H2DBatcher.submit, H2DBatcher._dispatch
 
     def recording_submit(self, host, like, fut, lease=None):
-        log.add("h2d_submit", key_of_batcher(self))
+        log.add("h2d_submit", leaves[id(fut)])
         if lease is not None:
-            lent_to[lease.ctypes.data] = key_of_batcher(self)
+            lent_to[lease.ctypes.data] = leaves[id(fut)]
         return real_submit(self, host, like, fut, lease)
 
     def recording_dispatch(self, items):
-        log.add("h2d_dispatch", key_of_batcher(self))
+        log.add("h2d_dispatch", key_of_items(items))
         return real_dispatch(self, items)
 
     monkeypatch.setattr(H2DBatcher, "submit", recording_submit)
@@ -303,7 +310,6 @@ def world(monkeypatch, request):
         url=url,
         tree=tree,
         saved=saved,
-        plans=plans,
         pools=pools,
         arenas=arenas,
         arena_sizes=arena_sizes,
@@ -470,6 +476,39 @@ def test_nothing_is_consumed_or_sent_to_the_device_ahead_of_the_load(world):
     }
 
 
+def test_at_the_drain_of_a_stateful_nothing_of_another_has_been_submitted(world, monkeypatch):
+    """The restore's one H2D batcher serves every stateful (``h2d.py``): it
+    holds at stateful k's drain what a batcher of k's own would hold, because
+    every leaf submitted between the drain of k-1 and that of k is k's, and
+    none of another.  Each drain is held for longer than a stateful takes to
+    read, so that k+1's reads are back while k drains: a pipeline that
+    consumed them then would submit them into k's drain."""
+    real_drain = H2DBatcher.drain
+
+    def held_drain(self):
+        time.sleep(3 * LEAVES * READ_S)
+        real_drain(self)
+        world.log.add("drain_end", None)
+
+    monkeypatch.setattr(H2DBatcher, "drain", held_drain)
+    target = make_app(world.log, zero=True)
+    restore(world, target)
+    drained, submitted = 0, {key: 0 for key in KEYS}
+    for what, key, _, _ in world.log.rows:
+        if what == "drain_end":
+            assert submitted[KEYS[drained]] == LEAVES, (drained, submitted)
+            drained += 1
+        elif what == "h2d_submit":
+            assert key == KEYS[drained], (key, KEYS[drained])
+            submitted[key] += 1
+    assert drained == len(KEYS)
+    # k+1 was read, and its reads were back, while k drained
+    ends = world.log.times("drain_end")
+    for k, ahead in enumerate(KEYS[1:]):
+        assert world.log.first("read_end", ahead) < ends[k], ahead
+    assert_equal_bits(target, world.saved)
+
+
 def test_a_read_landed_in_place_ahead_of_the_load_is_parked_then_consumed_inline(world):
     """The short path changes nothing of the gate: a read of k+1 that landed
     in place with its digest and is back before k is loaded is parked, its
@@ -502,7 +541,7 @@ def test_a_read_landed_in_place_ahead_of_the_load_is_parked_then_consumed_inline
     turned = end["arena_turn"]
     assert turned["parked_s"] > 0 and turned["ranges"] == len(KEYS) * LEAVES
     assert turned["turn_bs"] == pytest.approx(
-        sum(turned[stage + "_bs"] for stage in array_mod._STAGES)
+        sum(turned[stage + "_bs"] for stage in h2d_mod._STAGES)
     )
     assert_equal_bits(target, world.saved)
     assert no_pipeline_thread_alive()
@@ -524,13 +563,11 @@ def test_the_device_put_runs_on_the_dispatcher_and_the_account_says_so(world):
     }
     state_bytes = len(KEYS) * LEAVES * int(np.prod(SHAPE)) * 4
     route = end["h2d_dispatch_route"]
-    assert sorted(route) == [
-        "batches", "bytes", "cpu_s", "leaves", "off_caller", "on_caller"
-    ]
+    assert sorted(route) == ["bytes", "cpu_s", "leaves"]
     assert route["leaves"] == len(KEYS) * LEAVES
-    assert route["bytes"] == route["off_caller"] == state_bytes
-    assert route["on_caller"] == 0 and route["batches"] >= 1
-    assert route["batches"] == len(world.log.times("h2d_dispatch"))
+    assert route["bytes"] == state_bytes
+    calls = delta["h2d_dispatch"]["n"]
+    assert calls >= 1 and calls == len(world.log.times("h2d_dispatch"))
     counter = delta["h2d_dispatch_route"]
     assert counter["n"] == 1 and "wall" not in counter
     # (cpu_s, a float, comes back from the counter's delta rounded)
@@ -816,12 +853,13 @@ def test_the_stamps_of_a_turn_come_in_order_on_the_short_path_and_add_up(monkeyp
     pipeline's thread, at the dispatch or in the pool's grant, and
     ``read_began`` is stamped by the worker that starts the read: ``slot``
     runs from the one to the other."""
-    from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer, HostBufferPool
+    from torchsnapshot_tpu.h2d import HostBufferPool
+    from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer
     from torchsnapshot_tpu.manifest import TensorEntry
 
-    page = array_mod._PAGE
+    page = h2d_mod._PAGE
     ticks = iter(range(5000, 10**6))
-    monkeypatch.setattr(array_mod, "_now", lambda: float(next(ticks)))
+    monkeypatch.setattr(h2d_mod, "_now", lambda: float(next(ticks)))
     stamped = []  # (stamp, thread)
     real_stamp = array_mod.ArrayAssembly.stamp
 
@@ -836,16 +874,8 @@ def test_the_stamps_of_a_turn_come_in_order_on_the_short_path_and_add_up(monkeyp
     monkeypatch.setattr(array_mod.staging, "is_jax_array", lambda obj: obj is on_a_chip)
     monkeypatch.setattr(array_mod, "_INTO_PLACE_MIN_BYTES", page)
     pool = HostBufferPool()
-
-    class Window:  # what the pool knows of an H2DBatcher
-        expects_uploads, inflight_cap_bytes = True, 8 * page
-
-        def flush(self):
-            pass
-
-    window = Window()
-    pool.attach(window)
-    batcher = types.SimpleNamespace(host_pool=pool, expects_uploads=False, submitted=[])
+    pool.batcher.inflight_cap_bytes = 8 * page  # the arena's window term
+    batcher = types.SimpleNamespace(host_pool=pool, submitted=[])
     batcher.submit = lambda *item: batcher.submitted.append(item)
     MemoryStoragePlugin.reset()
 
@@ -878,14 +908,14 @@ def test_the_stamps_of_a_turn_come_in_order_on_the_short_path_and_add_up(monkeyp
         host, _like, _fut, lease = item
         assert host.tobytes() in wanted.values()
         turn = pool.turn_of(lease)
-        stamps = [getattr(turn, stamp) for stamp in array_mod._STAMPS[:5]]
+        stamps = [getattr(turn, stamp) for stamp in h2d_mod._STAMPS[:5]]
         if stamps[3] is None:  # consume_began: only a parked read's
             del stamps[3]
         else:
             parked.append(lease)
         assert None not in stamps
         assert [turn.granted, *stamps] == sorted([turn.granted, *stamps])
-        turn.sent = turn.put = array_mod._now()
+        turn.sent = turn.put = h2d_mod._now()
         pool.give(lease, recycle=True)
 
     before = phase_stats.snapshot()
@@ -928,18 +958,48 @@ def test_the_stamps_of_a_turn_come_in_order_on_the_short_path_and_add_up(monkeyp
     assert len(parked) == 1
     stats = pool.turn_stats()
     assert stats["ranges"] == 3 and stats["dropped"] == 0 and stats["bytes"] == 6 * page
-    assert stats["turn_bs"] == sum(stats[stage + "_bs"] for stage in array_mod._STAGES)
+    assert stats["turn_bs"] == sum(stats[stage + "_bs"] for stage in h2d_mod._STAGES)
     assert stats["parked_s"] > 0 and stats["consume_s"] > 0 and stats["read_s"] > 0
     assert stats["grant_s"] > 0  # c's range was fitted by a's give, adopted by the loop
     pool.close()
 
 
-def test_on_the_slot_hand_over_a_read_that_waits_a_millisecond_records_io_slot_wait():
+def test_on_the_slot_hand_over_a_read_that_waits_a_millisecond_records_io_slot_wait(
+    monkeypatch,
+):
     """One io slot and six reads of 20 ms each on the slot hand-over: every
-    read after the first waits for the slot, from its memory in hand to its
-    start on the worker that finished the one before, and each such wait is
-    an ``io_slot_wait`` of the worker's; with sixteen slots none waits a
-    millisecond and none is recorded."""
+    read after the first is queued for the slot (the hand-over's own queue
+    records it) and waits, from its memory in hand to its start on the
+    worker that finished the one before, and each such wait is an
+    ``io_slot_wait`` of the worker's; with sixteen slots no read is queued.
+    A wait under a millisecond is never recorded.  What a read that is not
+    queued may still record is its hop onto a worker, which a loaded host
+    can stretch past a millisecond: the test decides from the queue, not
+    from how fast the host hops, so the first read of the one slot may add
+    one record and no other may."""
+    queued = []  # of each hand-over, the reads it queued for a slot
+    waits = []  # the seconds of each io_slot_wait recorded
+
+    def recording_add(phase, seconds, *args, **kwargs):
+        if phase == "io_slot_wait":
+            waits.append(seconds)
+        return real_add(phase, seconds, *args, **kwargs)
+
+    real_add = phase_stats.add
+    monkeypatch.setattr(phase_stats, "add", recording_add)
+
+    class CountedQueue(collections.deque):
+        def append(self, pipeline):
+            queued[-1] += 1
+            super().append(pipeline)
+
+    class RecordedHandOver(scheduler_mod._DirectReads):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            queued.append(0)
+            self._queue = CountedQueue()
+
+    monkeypatch.setattr(scheduler_mod, "_DirectReads", RecordedHandOver)
     MemoryStoragePlugin.reset()
     sink = []
     payloads = {f"p{i}": bytes([i]) * 100 for i in range(6)}
@@ -948,15 +1008,19 @@ def test_on_the_slot_hand_over_a_read_that_waits_a_millisecond_records_io_slot_w
         storage._files.update(payloads)
         reqs = [ReadReq(path=p, buffer_consumer=_Consumer(sink, p, 100)) for p in payloads]
         before = phase_stats.snapshot()
+        del waits[:]
         with knobs.override_max_per_rank_io_concurrency(cap):
             scheduler_mod.sync_execute_read_reqs(reqs, storage, 1 << 20, rank=0)
         delta = phase_stats.delta(before)
         assert delta["read_loop"]["direct"] == len(payloads)
         waited = delta.get("io_slot_wait")
+        assert all(w >= 0.001 for w in waits)
         if cap == 1:
-            assert waited["n"] == len(payloads) - 1 and waited["s"] >= 0.02 * (len(payloads) - 1)
+            assert queued[-1] == len(payloads) - 1
+            assert waited["n"] - queued[-1] in (0, 1)
+            assert waited["s"] >= 0.02 * (len(payloads) - 1)
             assert waited["wall"] > 0.02 * 4
         else:
-            assert waited is None
+            assert queued[-1] == 0
         storage.pool.shutdown()
-    assert len(sink) == 2 * len(payloads)
+    assert len(queued) == 2 and len(sink) == 2 * len(payloads)

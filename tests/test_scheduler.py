@@ -1043,15 +1043,15 @@ def test_a_read_on_the_hand_over_costs_the_loop_three_handles_or_fewer(
     import numpy as np
 
     from torchsnapshot_tpu import Snapshot, StateDict, knobs
+    from torchsnapshot_tpu import h2d as h2d_mod
     from torchsnapshot_tpu import scheduler as sched_mod
-    from torchsnapshot_tpu import snapshot as snapshot_mod
     from torchsnapshot_tpu.event_handlers import (
         register_event_handler,
         unregister_event_handler,
     )
     from torchsnapshot_tpu.io_preparers import array as array_mod
 
-    page = array_mod._PAGE
+    page = h2d_mod._PAGE
     leaf = 4 * page
 
     def unaligned(nbytes):
@@ -1060,14 +1060,18 @@ def test_a_read_on_the_hand_over_costs_the_loop_three_handles_or_fewer(
         return raw[offset : offset + nbytes]
 
     monkeypatch.setattr(array_mod, "_INTO_PLACE_MIN_BYTES", page)
-    monkeypatch.setattr(array_mod, "_keeps_host_memory", lambda target: False)
-    monkeypatch.setattr(array_mod, "_arena_memory", unaligned)
+    monkeypatch.setattr(h2d_mod, "_keeps_host_memory", lambda target: False)
+    monkeypatch.setattr(h2d_mod, "_arena_memory", unaligned)
+    real_pool_init = h2d_mod.HostBufferPool.__init__
+
+    def pool_with_a_window_of_eight_leaves(self):
+        real_pool_init(self)
+        self.batcher = h2d_mod.H2DBatcher(
+            self, flush_bytes=2 * leaf, inflight_cap_bytes=8 * leaf
+        )
+
     monkeypatch.setattr(
-        snapshot_mod,
-        "H2DBatcher",
-        lambda **kwargs: array_mod.H2DBatcher(
-            flush_bytes=2 * leaf, inflight_cap_bytes=8 * leaf, **kwargs
-        ),
+        h2d_mod.HostBufferPool, "__init__", pool_with_a_window_of_eight_leaves
     )
 
     def app(zero):
@@ -1136,7 +1140,7 @@ def test_a_read_on_the_hand_over_costs_the_loop_three_handles_or_fewer(
     per_read = handles[id(pipeline._loop)] / reqs
     arena = end["arena_turn"]
     assert arena["ranges"] == 2 * TOY_LEAVES and arena["turn_bs"] == pytest.approx(
-        sum(arena[stage + "_bs"] for stage in array_mod._STAGES)
+        sum(arena[stage + "_bs"] for stage in h2d_mod._STAGES)
     )
     assert arena["grant_s"] > 0  # reads waited for room, and were granted
     if plugin == "fs":

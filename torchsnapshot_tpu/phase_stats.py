@@ -313,21 +313,18 @@ def add_counter(name: str, seconds: float, nbytes: int = 0, **more: int) -> None
     ``host_pool``: one occurrence a restore, the bytes read into pages of
     the restore's host arena that had been handed out before, and ``fresh=``,
     ``hits=``, ``misses=``, ``high_water=``, ``populated=``,
-    io_preparers/array.HostBufferPool; ``chunked_read``: one occurrence a
+    h2d.HostBufferPool; ``chunked_read``: one occurrence a
     stateful's read plan, the bytes of its chunked leaves, and ``leaves=``,
     ``chunks=``; ``chunked_write``: the same, one occurrence a take's write
     plan, io_preparers/chunked_array.count_chunked; ``read_route``: one
     occurrence a stateful's read plan, its bytes, and ``sequential=``,
     ``striped=``, ``merged=`` by the route each read will take,
     batcher.count_read_routes; ``h2d_dispatch_route``: one occurrence a
-    restore, the bytes its H2D batchers sent to the device, and
-    ``off_caller=`` those whose ``device_put`` ran on the restore's
-    dispatcher thread, ``on_caller=`` those on the thread that flushed,
-    ``batches=`` the calls, ``leaves=`` the arrays they sent, ``cpu_s=``
-    the calling thread's CPU seconds inside them,
-    io_preparers/array.H2DThreads; ``restore_cpu``: one occurrence a
-    restore, the payload bytes its plans read, ``s`` the process's CPU
-    seconds over the call, and ``driver_s=``, ``loop_s=``, ``fs_s=``,
+    restore, the bytes its H2D batcher's ``device_put`` calls sent to the
+    device, ``leaves=`` the arrays they sent and ``cpu_s=`` the calling
+    thread's CPU seconds inside them, h2d.H2DBatcher; ``restore_cpu``: one
+    occurrence a restore, the payload bytes its plans read, ``s`` the
+    process's CPU seconds over the call, and ``driver_s=``, ``loop_s=``, ``fs_s=``,
     ``dispatcher_s=``, ``lander_s=`` those of the calling thread, the read
     pipeline's thread, the storage plug-in's read jobs and the two H2D
     threads, ``call_s=`` the call's wall, snapshot.Snapshot.restore;
@@ -335,12 +332,12 @@ def add_counter(name: str, seconds: float, nbytes: int = 0, **more: int) -> None
     one occurrence a restore, the bytes of the ranges of its host arena
     that completed a turn, and ``ranges=``, ``dropped=``, ``turn_bs=``,
     ``<stage>_s=`` and ``<stage>_bs=`` for each of the eight stages of a
-    turn, ``arena=``, ``lent_s=``, io_preparers/array.HostBufferPool;
+    turn, ``arena=``, ``lent_s=``, h2d.HostBufferPool;
     ``restore_overlap``: one occurrence a restore, the seconds in which a
     storage read and an H2D dispatch or landing were both under way, and
     ``reads_s=``, ``h2d_s=``, ``neither_s=``, snapshot._restore_overlap;
     ``h2d_land_slow``: one occurrence a landing that stalled, its seconds
-    and bytes, io_preparers/array._note_slow_landing; ``read_loop``: one
+    and bytes, h2d._note_slow_landing; ``read_loop``: one
     occurrence a read pipeline, ``turns=`` of its loop, completions
     ``taken=`` off, reads consumed ``inline=`` on the loop thread, consumes
     ``handed=`` on as a task, ``max_pending=`` the most tasks alive at once

@@ -824,9 +824,9 @@ class _ReadPipeline:
         """The read is being dispatched: only now is the memory it lands in
         taken (a restored leaf's range of the restore's host arena lives
         from here to its landing; ``acquire`` holds the read while the arena
-        has no room, until a landing has freed some).  Before the io slot: a
-        read that waits for room holds no slot, so whatever holds a range
-        gets its slot without the waiter.  The same memory on a retry."""
+        has no room, until a landing has freed some).  Before the io slot,
+        in dispatch order: ``h2d.py``'s liveness condition 1.  The same
+        memory on a retry."""
         if self.read_req.into is not None:
             self.into = await self.read_req.into.acquire()
 
@@ -849,7 +849,7 @@ class _ReadPipeline:
     def stamp(self, stamp: str) -> None:
         """A hand-over of this read (``read_began``, ``read_back``,
         ``consume_began``), for the record of its leaf's turn through the
-        restore's host arena (``io_preparers/array.HostBufferPool``): told
+        restore's host arena (``h2d.HostBufferPool``): told
         through the handle the read lands by, nothing for a read with none."""
         if self.read_req.into is not None:
             self.read_req.into.stamp(stamp)
@@ -1237,21 +1237,15 @@ async def execute_read_reqs(
       ``device_put``) starts before the loader has loaded group k.  Until
       then k's restore target is alive on the device, and k+1's arrays
       landing beside it would raise the restore's HBM peak.  A read that
-      finishes early is parked with its bytes debited from the budget.
-    - in a restore (``HostBufferPool``) a leaf's host memory is a range of
-      the restore's one bounded arena, taken when its first read is
-      dispatched, BEFORE the read's io slot and in dispatch order (within a
-      group: smallest first), and given back by the H2D lander once the
-      leaf is on the device, to whichever read comes next.  A read that
-      finds no room is HELD there, its bytes debited and no slot taken,
-      until a landing of any group, its own included, has freed some
-      (``host_buffer_wait``; the wait makes the H2D batchers flush): it
-      gives up reads in flight beside each other to read into pages already
-      faulted in.  Whatever holds a range gets on without the waiter: a
-      read under way has its slot, a landing needs the lander alone, and a
-      read of k+1 parked behind the load holds a range only once every read
-      of k has one (k+1's first is dispatched when k's last has finished),
-      so it can starve no read of k of room.
+      finishes early is parked with its bytes debited from the budget.  The
+      restore's one H2D batcher counts on this (``h2d.py``).
+    - in a restore a leaf's host memory is a range of the restore's arena
+      (``h2d.HostBufferPool``), taken when its first read is dispatched,
+      BEFORE the read's io slot and in dispatch order (within a group:
+      smallest first).  A read that finds no room is HELD there, its bytes
+      debited and no slot taken, until a landing has freed some
+      (``host_buffer_wait``).  Why the restore stays live meanwhile, and
+      what this loop keeps for it: the head of ``h2d.py``.
     - no read of group k+2 starts before group k is loaded, all of its
       host ranges landed from.
     - a range's turn through that arena is stamped where this loop hands it

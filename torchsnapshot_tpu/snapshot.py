@@ -52,7 +52,7 @@ from .dist_store import (
 from .event import Event
 from .event_handlers import log_event
 from .flatten import flatten, inflate
-from .io_preparers.array import H2DBatcher, HostBufferPool
+from .h2d import HostBufferPool
 from .io_preparers.chunked_array import count_chunked
 from .io_types import Future, ReadReq, StoragePlugin, WriteReq
 from .manifest import (
@@ -578,74 +578,21 @@ class Snapshot:
         the most.  An in-place numpy target of k+1 is filled as its reads
         arrive, as within a stateful; no user code runs ahead.
 
-        **Host reads land in one bounded arena** (``HostBufferPool``, this
-        call's own): a leaf uploaded through the H2D batcher takes a
-        page-aligned range of it when its first read is dispatched and the
-        batcher gives the range back once the transfer has landed, to
-        whichever read comes next, of any size and any stateful.  The arena
-        is one allocation, sized from what the plan reserved (``max(the
-        largest leaf, the batchers' in-flight cap)``, no more than the
-        largest stateful; no knob), and every page of it is written once, in
-        parallel on the native pool the reads run on, before the first range
-        is handed out (the phase ``arena_populate``, inside the first take;
-        skipped, silently, where the native library lacks the symbol), so no
-        read of the call, the first stateful's included, faults a fresh
-        page, and nothing is unmapped beside the reads.  A read that finds
-        no room waits for a landing of any stateful, its own included
-        (``host_buffer_wait``), and the wait makes the batchers send what
-        they hold to the device.  Targets on a
-        backend whose ``device_put`` keeps the host memory (the CPU's) get
-        plain buffers and no arena, so no restored array pins one.  The
-        arena is dropped when the last stateful is loaded (or the call
-        fails; the phase ``host_pool_free``) and the pool dies with the
-        call; its account is the ``host_pool`` counter and the
-        ``host_pool`` entry of the ``restore.end`` event (``fresh``: bytes
-        handed out from pages never handed out before; ``high_water``: the
-        arena's bytes ever handed out; ``populated``: the arena's bytes
-        written in bulk before any were handed out).  What it buys: PERF.md
-        section 5.
-
-        **Uploads run on two threads of the call's own** (``H2DThreads``,
-        the pool's): a flush on the pipeline's thread only queues, the
-        dispatcher makes each batch's ``device_put`` and the lander waits
-        for it to land, both started before the first read is issued and
-        joined when the call ends; the ``h2d_dispatch_route`` counter and
-        the ``restore.end`` entry of that name say how many bytes went that
-        way (``off_caller``), in how many calls and ``leaves``, and the CPU
-        seconds the calling thread spent inside the calls (``cpu_s``).
-
-        **The call's own threads account their CPU against its wall**: the
-        ``restore_cpu`` counter and the ``restore.end`` entry of that name,
-        one occurrence a call, hold the process's CPU seconds over the call
-        (``s``: the native pool, the runtime's threads and the arena's
-        population too), this thread's (``driver_s``), the read pipeline's
-        (``loop_s``), the storage plug-in's read jobs summed (``fs_s``), the
-        H2D dispatcher's and lander's (``dispatcher_s``, ``lander_s``; 0
-        where they never started), the call's wall (``call_s``) and the
-        payload bytes its plans read (``bytes``).  Each thread reads its own
-        clock when it starts and when it ends, so nothing is read per read.
-
-        **The call accounts for its arena's turn and its own overlap**: the
-        ``arena_turn`` counter (each range of the arena stamped from its
-        grant to its give, by stage, in seconds and byte-seconds:
-        ``HostBufferPool.turn_stats``), ``restore_overlap`` (the seconds in
-        which storage reads and H2D were both under way, each one's wall and
-        what neither covers: ``_restore_overlap``, over the intervals the
-        call holds) and ``h2d_land_slow`` (a landing that stalled, counted
-        and logged where it happens) are each an entry of ``restore.end``,
-        and so is ``read_loop``: what the read pipeline's loop thread did
-        for the call's requests (``turns``, completions ``taken`` off, reads
-        consumed ``inline`` in the turn they were taken off, consumes
-        ``handed`` on as a task, ``max_pending`` tasks alive at once;
-        ``scheduler.execute_read_reqs`` records the counter of that name).
-        Where an ``RNGState`` was restored (planned and loaded last), the
-        ``rng_restore`` counter and the ``restore.end`` entry of that name
-        say what it added: its manifest ``entries``, the payload ``bytes``
-        its reads took, its ``plan_s`` and the ``tail_s`` from the previous
-        stateful's load to its own.
+        **Host reads land in one bounded arena, and uploads run through one
+        batcher on two threads**, all of the call's own: the
+        ``HostBufferPool`` made here and closed when the call ends, abort or
+        not (the phase ``host_pool_free``).  How the arena is sized and lent,
+        and why the call stays live while a read waits for room in it: the
+        head of ``h2d.py``.  What the call accounts for when it ends (the
+        ``restore.end`` event, and the counters ``host_pool``,
+        ``h2d_dispatch_route``, ``arena_turn``, ``restore_cpu``,
+        ``restore_overlap``, ``read_loop``, ``h2d_land_slow`` and, where an
+        ``RNGState`` was restored, ``rng_restore``): PERF.md section 3, and
+        each field in docs/observability.md.
 
         On-device contract: dense and chunked array uploads are drained
-        before return (H2DBatcher.drain — their bytes are ON DEVICE, with
+        before return (``H2DBatcher.drain`` once a stateful, before its
+        ``load_state_dict`` — their bytes are ON DEVICE, with
         the landing wall attributed to ``h2d_land``).  **Sharded-array
         entries are excluded**: their per-device uploads are dispatched and
         deliberately left in flight so a multichip restore overlaps the
@@ -765,7 +712,7 @@ class Snapshot:
                             else:
                                 with ttrace.span("load_stateful", key=plan.key):
                                     self._load_stateful(
-                                        group, plan, pipeline, strict
+                                        group, plan, pipeline, strict, host_pool
                                     )
                             if group < len(global_keys):
                                 loaded_at = time.monotonic()
@@ -806,18 +753,21 @@ class Snapshot:
             finally:
                 storage.sync_close()
             end = time.monotonic()
+            # The H2D stage's account: the arena, the dispatcher's calls, the
+            # arena's turn, and the CPU of its two threads (joined by now).
+            stage = host_pool.account()
             # Each of the call's own threads against the call's wall: this
             # one's and the whole process's CPU over the call, the read
             # pipeline's thread, the plug-in's read jobs, and the H2D
-            # dispatcher and lander (each joined by now).
+            # dispatcher and lander.
             restore_cpu = {
                 "s": time.process_time() - cpu_began,
                 "bytes": restored_bytes,
                 "driver_s": time.thread_time() - driver_began,
                 "loop_s": pipeline.loop_cpu_s,
                 "fs_s": pipeline.fs_cpu_s,
-                "dispatcher_s": host_pool.h2d_threads.dispatcher.cpu_s,
-                "lander_s": host_pool.h2d_threads.lander.cpu_s,
+                "dispatcher_s": stage["dispatcher_s"],
+                "lander_s": stage["lander_s"],
                 "call_s": end - begin,
             }
             # This one call's account: each phase's wall inside it, and what
@@ -841,18 +791,8 @@ class Snapshot:
             phase_stats.add_counter(
                 "read_ahead", pipeline.read_ahead_s, pipeline.read_ahead_bytes
             )
-            # How much was read into pages of the host arena that an earlier
-            # leaf had landed from, and how much into pages never touched.
-            pooled = host_pool.stats()
-            _add_counter_of("host_pool", pooled)
-            # Where the batches' device_put calls ran: on the dispatcher
-            # (off_caller) or on the thread that flushed (on_caller).
-            routed = host_pool.h2d_threads.route()
-            _add_counter_of("h2d_dispatch_route", routed)
-            # Each range's turn through the arena, by stage: what holds the
-            # bytes the arena lends, and for how long.
-            turned = host_pool.turn_stats()
-            _add_counter_of("arena_turn", turned)
+            for counter in ("host_pool", "h2d_dispatch_route", "arena_turn"):
+                _add_counter_of(counter, stage[counter])
             # What the call's threads spent on the CPU, beside its wall.
             _add_counter_of("restore_cpu", restore_cpu)
             # What restoring an RNGState, planned and loaded last, added.
@@ -867,7 +807,7 @@ class Snapshot:
             }
             event_metadata["unattributed_s"] = unattributed_s
             event_metadata["restore_overlap"] = overlap
-            event_metadata["arena_turn"] = turned
+            event_metadata["arena_turn"] = stage["arena_turn"]
             event_metadata["restore_cpu"] = restore_cpu
             event_metadata["h2d_land_slow"] = {
                 "s": slow.get("s", 0.0),
@@ -876,8 +816,8 @@ class Snapshot:
             event_metadata["read_loop"] = dict(pipeline.read_loop)
             event_metadata["read_ahead_s"] = pipeline.read_ahead_s
             event_metadata["read_ahead_bytes"] = pipeline.read_ahead_bytes
-            event_metadata["host_pool"] = pooled
-            event_metadata["h2d_dispatch_route"] = routed
+            event_metadata["host_pool"] = stage["host_pool"]
+            event_metadata["h2d_dispatch_route"] = stage["h2d_dispatch_route"]
             event_metadata["leaves"] = leaves
             event_metadata["slab_read_bytes"] = int(
                 phases_delta.get("slab_read", {}).get("bytes", 0)
@@ -913,11 +853,16 @@ class Snapshot:
 
     @staticmethod
     def _load_stateful(
-        group: int, plan: "_StatefulPlan", pipeline: ReadAhead, strict: bool
+        group: int,
+        plan: "_StatefulPlan",
+        pipeline: ReadAhead,
+        strict: bool,
+        host_pool: HostBufferPool,
     ) -> None:
         """The loader's part of one stateful, on the thread that called
-        ``restore``: everything after its last consume (the H2D drain, then
-        ``inflate`` and the stateful's own ``load_state_dict``)."""
+        ``restore``: everything after its last consume (the drain of the
+        restore's H2D batcher, then ``inflate`` and the stateful's own
+        ``load_state_dict``)."""
         pipeline.wait_consumed(group)
         # Flush the tail AND wait for every H2D transfer to land:
         # restore's contract is "dense/chunked state is on device when
@@ -930,7 +875,7 @@ class Snapshot:
         # this thread's wait for that tail; the next stateful's reads run
         # under it.
         with phase_stats.timed("h2d_drain"):
-            plan.h2d_batch.drain()
+            host_pool.batcher.drain()
         with phase_stats.timed("load_state"):
             resolved = {path: fut.obj for path, fut in plan.futures.items()}
             restored_state_dict = inflate(
@@ -997,11 +942,10 @@ class Snapshot:
             return None
 
         # Cross-array H2D batching: dense arrays' uploads collect into
-        # batched pjrt transfers (flushed incrementally and after the read
-        # pipeline drains) instead of one dispatch per array serialized
-        # behind its read.
+        # batched pjrt transfers in the restore's one batcher (flushed
+        # incrementally and at the drain) instead of one dispatch per array
+        # serialized behind its read.
         host_pool.begin_group()
-        h2d_batch = H2DBatcher(host_pool=host_pool)
         read_reqs: List[ReadReq] = []
         futures: Dict[str, Future] = {}
         container_entries: Manifest = {}
@@ -1011,7 +955,7 @@ class Snapshot:
                 continue
             obj_out = target_flattened.get(path)
             entry_read_reqs, fut = io_preparer.prepare_read(
-                entry, obj_out, h2d_batch=h2d_batch
+                entry, obj_out, h2d_batch=host_pool.batcher
             )
             read_reqs += entry_read_reqs
             futures[path] = fut
@@ -1020,7 +964,7 @@ class Snapshot:
         count_chunked("chunked_read", sub_manifest.values())
         tmetrics.record_entries("restore", len(sub_manifest))
         return _StatefulPlan(
-            stateful_key, stateful, read_reqs, futures, container_entries, h2d_batch
+            stateful_key, stateful, read_reqs, futures, container_entries
         )
 
     # ----------------------------------------------------------- read_object
@@ -1436,15 +1380,13 @@ class Snapshot:
 class _StatefulPlan:
     """What ``plan_read`` makes of one stateful: its read requests (one
     group of the restore's read pipeline; no host buffer yet), the future of
-    every entry, its container entries and the H2D batcher the requests
-    feed."""
+    every entry and its container entries."""
 
     key: str
     stateful: Stateful
     read_reqs: List[ReadReq]
     futures: Dict[str, Future]
     container_entries: Manifest
-    h2d_batch: Any
 
 
 class _ManifestFinalizer:

@@ -77,7 +77,7 @@ PHASE_GROUPS: Dict[str, frozenset] = {
     # freeing the restore's host arena once the last has loaded; and the
     # arena's population before the first read lands in it, which the read
     # pipeline's thread does at its first take of host memory
-    # (io_preparers/array.HostBufferPool): host work between reads all the
+    # (h2d.HostBufferPool): host work between reads all the
     # same.  Work, and leaves: none encloses a read.  The same group as the
     # profiler's <kind>_drive tags.  (plan_read would suffix-match _read;
     # the explicit entry comes first.)
